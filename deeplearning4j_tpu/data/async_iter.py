@@ -5,7 +5,9 @@ Reference parity: ``org.deeplearning4j.datasets.iterator.AsyncDataSetIterator``
 Backing store is the native SPSC ring (`native/dl4j_tpu_native.cpp`) when the
 lib is available — batches are serialized into fixed byte slots, so the
 producer thread never holds the GIL during the copy — with a pure-Python
-queue fallback. Either way the consumer API is a normal DataSetIterator.
+queue fallback. A batch larger than a ring slot (ImageNet b128 f32 is
+77 MB) rides the queue as it is, a marker holding its place in the ring.
+Either way the consumer API is a normal DataSetIterator.
 
 reset() swaps in a FRESH ring/queue generation before restarting the
 producer: an old producer blocked on a full buffer keeps writing (and
@@ -25,6 +27,7 @@ import numpy as np
 from .dataset import DataSet, MultiDataSet
 
 _SENTINEL = b"__END__"
+_OVERSIZE = b"__VIA_QUEUE__"   # ring marker: the batch itself is in the queue
 
 
 def _pack(ds) -> bytes:
@@ -73,6 +76,23 @@ def _unpack(raw: bytes):
             feats, labs,
             fmasks if any(m is not None for m in fmasks) else None,
             lmasks if any(m is not None for m in lmasks) else None)
+
+
+def _put(ring, q, stop, item):
+    """Blocking hand-over to the consumer — into the ring when there is
+    one, else the queue — that gives up once ``stop`` is set (reset/close
+    abandon a producer blocked on a full buffer)."""
+    while not stop.is_set():
+        if ring is not None:
+            if ring.push(item):
+                return
+            stop.wait(0.001)
+        else:
+            try:
+                q.put(item, timeout=0.1)
+                return
+            except queue.Full:
+                continue
 
 
 def maybe_wrap_async(iterator, queue_size: int = 2):
@@ -130,33 +150,18 @@ class AsyncDataSetIterator:
         try:
             for ds in self.inner:
                 payload = _pack(ds) if ring is not None else ds
-                while not stop.is_set():
-                    if ring is not None:
-                        if ring.push(payload):
-                            break
-                        stop.wait(0.001)
-                    else:
-                        try:
-                            q.put(payload, timeout=0.1)
-                            break
-                        except queue.Full:
-                            continue
+                if ring is not None and len(payload) > ring.slot_size:
+                    # queue first, marker second: a consumer that pops
+                    # the marker always finds the batch waiting
+                    _put(None, q, stop, ds)
+                    payload = _OVERSIZE
+                _put(ring, q, stop, payload)
                 if stop.is_set():
                     return
         except BaseException as e:  # noqa: BLE001 — handed to the consumer
             error.append(e)
         finally:
-            while not stop.is_set():
-                if ring is not None:
-                    if ring.push(_SENTINEL):
-                        break
-                    stop.wait(0.001)
-                else:
-                    try:
-                        q.put(_SENTINEL, timeout=0.1)
-                        break
-                    except queue.Full:
-                        continue
+            _put(ring, q, stop, _SENTINEL)
 
     # ------------------------------------------------------------- consumer
     def __iter__(self):
@@ -173,6 +178,8 @@ class AsyncDataSetIterator:
                 if raw == _SENTINEL:
                     self._raise_producer_error()
                     raise StopIteration
+                if raw == _OVERSIZE:
+                    return q.get()
                 return _unpack(raw)
             item = q.get()
             if isinstance(item, bytes) and item == _SENTINEL:

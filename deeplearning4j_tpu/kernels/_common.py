@@ -1,15 +1,10 @@
-"""Shared pallas-kernel plumbing: the jaxlib-compatibility pltpu import
-(CPU-only wheels ship pallas without the TPU backend) and backend
+"""Shared pallas-kernel plumbing: the Pallas-TPU import and backend
 detection — one copy for every kernel module."""
 
 from __future__ import annotations
 
 import jax
-
-try:
-    from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-except ImportError:  # pragma: no cover - depends on jaxlib build
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu  # noqa: F401
 
 
 def interpret_default() -> bool:

@@ -153,9 +153,6 @@ def fused_lstm_seq(xproj, rw, peep, h0, c0, interpret=None):
 
 
 def _fwd(xproj, rw, peep, h0, c0, interpret):
-    if pltpu is None:
-        return lstm_seq_reference(xproj, rw, peep, h0, c0), \
-            (xproj, rw, peep, h0, c0)
     if interpret is None:
         interpret = _interpret_default()
     out = _lstm_pallas(xproj, rw, peep, h0, c0, interpret)

@@ -27,7 +27,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 
 from ._common import interpret_default
-from ._common import pltpu
 
 _VMEM_BUDGET = 8 << 20  # row blocks stay comfortably inside VMEM
 
@@ -89,9 +88,6 @@ def fused_bn_act(x2d, scale, shift, activation: str = "identity",
 
 def _fwd(x2d, scale, shift, activation, interpret):
     res = (x2d, scale, shift)
-    if pltpu is None:
-        return bn_act_reference(x2d, scale, shift, activation
-                                ).astype(x2d.dtype), res
     if interpret is None:
         interpret = _interpret_default()
     n, c = x2d.shape
@@ -244,7 +240,7 @@ def _train_fwd(x2d, gamma, beta, center, eps, activation, interpret):
     n, c = x2d.shape
     if interpret is None:
         interpret = _interpret_default()
-    bn = None if pltpu is None else plan_blocks(n, c, x2d.dtype.itemsize)
+    bn = plan_blocks(n, c, x2d.dtype.itemsize)
     if bn is None:
         y, mean, var = bn_act_train_reference(x2d, gamma, beta, center, eps,
                                               activation)
@@ -287,8 +283,7 @@ def _train_bwd(eps, activation, interpret, res, cotangents):
     scale = gamma.astype(jnp.float32) * inv
     shift = beta.astype(jnp.float32) - mean * scale
     # 3 resident row blocks in the dx pass (x, g, dx)
-    bn = None if pltpu is None else plan_blocks(n, c, x2d.dtype.itemsize,
-                                                buffers=3)
+    bn = plan_blocks(n, c, x2d.dtype.itemsize, buffers=3)
     if bn is None:
         xf = x2d.astype(jnp.float32)
         z = xf * scale[None, :] + shift[None, :]

@@ -35,10 +35,7 @@ a unified-harness cost record (``paged_decode:...`` key) stamped with
 :func:`kernel_sha` — editing this kernel auto-invalidates every stale
 verdict and re-races (``kernels/autotune.py``).
 
-Off-TPU the kernel runs in pallas interpret mode (the CPU CI oracle);
-on jaxlib builds without pallas-TPU support entirely, it falls back to
-:func:`paged_attention_reference` — the same math the engine's gather
-path runs.
+Off-TPU the kernel runs in pallas interpret mode (the CPU CI oracle).
 """
 
 from __future__ import annotations
@@ -50,6 +47,7 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 
 from ._common import interpret_default as _interpret_default
 from ._common import pltpu
@@ -91,46 +89,38 @@ def _decode_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0]                                      # (H, Dh)
-        k = k_ref[0]                                      # (PL, H, Dh)
-        v = v_ref[0]
-        # per-head q·k over the page: operands stay in cache dtype, the
-        # MXU accumulates f32 (flash-kernel discipline)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (2,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32) * scale   # (H, PL)
+        # One query row per head: the products are matrix-VECTOR, so
+        # they run on the VPU in f32 with the head axis kept on the
+        # sublanes of every operand (k/v arrive (PL, H, Dh)). Mosaic
+        # accepts no batched dot whose batch axis sits at a different
+        # position in each operand, and an M=1 MXU pass would buy
+        # nothing over the multiply-reduce.
+        q = q_ref[0].astype(jnp.float32)                  # (H, Dh)
+        k = k_ref[0].astype(jnp.float32)                  # (PL, H, Dh)
+        v = v_ref[0].astype(jnp.float32)
+        s = jnp.sum(k * q[None], axis=-1,
+                    keepdims=True) * scale                # (PL, H, 1)
         # partial-fill tail: rows past the slot's cursor mask out
         t_idx = j * page_len + jax.lax.broadcasted_iota(jnp.int32,
-                                                        s.shape, 1)
+                                                        s.shape, 0)
         s = jnp.where(t_idx > pos, NEG_INF, s)
-        m = m_ref[:, 0]
-        l = l_ref[:, 0]
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
+        m = m_ref[...]                                    # (H, 1)
+        l = l_ref[...]
+        m_new = jnp.maximum(m, jnp.max(s, axis=0))
+        p = jnp.exp(s - m_new[None])                      # (PL, H, 1)
         corr = jnp.exp(m - m_new)
-        l_new = l * corr + jnp.sum(p, axis=-1)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32)           # (H, Dh)
-        m_ref[...] = jnp.broadcast_to(m_new[:, None], m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new[:, None], l_ref.shape)
+        acc_ref[...] = acc_ref[...] * corr + jnp.sum(p * v, axis=0)
+        m_ref[...] = m_new
+        l_ref[...] = l * corr + jnp.sum(p, axis=0)
 
     @pl.when(j == nj - 1)
     def _finalize():
-        l = l_ref[:, 0]
+        l = l_ref[...]
         # a slot with zero live rows (nothing mapped) emits zeros —
         # garbage-by-contract the scheduler never reads, same as the
         # gather path's clamped-garbage rows
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_ref[...] / l_safe[:, None]).astype(o_ref.dtype)
-
-
-# pl imported late so the module stays importable (reference path +
-# promotion bookkeeping) even where jax.experimental.pallas is absent
-try:
-    from jax.experimental import pallas as pl
-except ImportError:  # pragma: no cover - depends on jaxlib build
-    pl = None
+        o_ref[0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
 
 
 def paged_attention(q, k_pages, v_pages, table, pos,
@@ -143,12 +133,7 @@ def paged_attention(q, k_pages, v_pages, table, pos,
     ``pos[b]`` is the row just written — valid rows are
     ``<= pos[b]``, the `_cached_attention` mask contract). Returns
     (B, H, Dh) in q's dtype.
-
-    On jaxlib builds without pallas (or pallas-TPU) support this
-    transparently falls back to :func:`paged_attention_reference`.
     """
-    if pl is None or pltpu is None:
-        return paged_attention_reference(q, k_pages, v_pages, table, pos)
     if interpret is None:
         interpret = _interpret_default()
     b, h, dh = q.shape
@@ -174,8 +159,8 @@ def paged_attention(q, k_pages, v_pages, table, pos,
         ],
         out_specs=pl.BlockSpec((1, h, dh), q_map),
         scratch_shapes=[pltpu.VMEM((h, dh), jnp.float32),
-                        pltpu.VMEM((h, 8), jnp.float32),
-                        pltpu.VMEM((h, 8), jnp.float32)],
+                        pltpu.VMEM((h, 1), jnp.float32),
+                        pltpu.VMEM((h, 1), jnp.float32)],
     )
     return pl.pallas_call(
         functools.partial(_decode_kernel, scale=scale, page_len=plen,

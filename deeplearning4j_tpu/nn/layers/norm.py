@@ -36,7 +36,8 @@ class BatchNormalization(Layer):
     use_log_std: bool = False
     # DL4J BatchNormalization inherits activation from FeedForwardLayer;
     # at inference the whole BN+act collapses into the fused pallas
-    # scale-shift-act kernel ("auto": on TPU; True forces interpret mode)
+    # scale-shift-act kernel ("auto": on TPU only; True: everywhere —
+    # compiled on a TPU, pallas interpret mode off it)
     activation: Any = "identity"
     fused: Any = "auto"
 
@@ -96,8 +97,7 @@ class BatchNormalization(Layer):
                         if self.lock_gamma_beta else params["beta"])
                 y, mean, var = fused_bn_act_train(
                     x.reshape(-1, ch), gamma, beta, c, self.eps,
-                    self.activation,
-                    True if self.fused is True else None)
+                    self.activation)
                 new_state = {
                     "mean": self.decay * state["mean"]
                             + (1 - self.decay) * lax.stop_gradient(mean),
@@ -140,8 +140,7 @@ class BatchNormalization(Layer):
                     shift = params["beta"].astype(jnp.float32) - mean * scale
                 c = x.shape[-1]
                 y = fused_bn_act(x.reshape(-1, c), scale, shift,
-                                 self.activation,
-                                 True if self.fused is True else None)
+                                 self.activation)
                 return y.reshape(x.shape), new_state
         # normalize as one fused multiply-add: fold mean/gamma/beta into
         # per-channel scale/shift vectors (C-sized math) instead of two

@@ -36,9 +36,8 @@ never per-request identity.
 
 No jax import at module load — and no package-relative import either:
 like the registry, the census must be importable from the UI process
-and bench subprocesses, and this file is additionally loaded STANDALONE
-by file path (``scripts/refresh_readme_table.py`` borrows
-:func:`format_bytes` without paying the package's jax import chain).
+and bench subprocesses, and stay loadable STANDALONE by file path
+(without paying the package's jax import chain).
 """
 
 from __future__ import annotations

@@ -23,9 +23,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from .._jax_compat import shard_map
 
 from .mesh import data_parallel_mesh
 

@@ -44,9 +44,8 @@ from typing import List
 import jax
 import jax.numpy as jnp
 import optax
-from jax import lax
+from jax import lax, shard_map
 
-from .._jax_compat import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..nn.layers.base import Ctx

@@ -30,9 +30,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from .._jax_compat import shard_map
 
 
 def _xla_attn_lse(q, k, v, causal):
@@ -74,9 +73,6 @@ def _merge(acc, new):
 
 
 def _use_flash(use_flash, t_local):
-    from ..kernels._common import pltpu
-    if pltpu is None:     # CPU-only pallas wheel: no kernel to run
-        return False
     if use_flash == "auto":
         return jax.default_backend() == "tpu" and t_local >= 1024
     return bool(use_flash)
@@ -87,8 +83,7 @@ def ring_attention_sharded(q, k, v, axis_name: str = "sp",
                            interpret=None):
     """Runs INSIDE shard_map: q/k/v are the local sequence shard
     (B, T_local, H, D). Exact causal attention across the full sequence."""
-    from .._jax_compat import axis_size
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     t_local = q.shape[1]
     flash = _use_flash(use_flash, t_local)

@@ -701,25 +701,31 @@ class GenerationEngine:
             self._paged_plan[key] = got
         return got
 
+    def decode_path(self, cache) -> str:
+        """Which compiled decode ``decode_step`` dispatches for this
+        cache: ``"dense"`` | ``"paged_gather"`` | ``"paged_kernel"``.
+        One ladder — ``decode_step`` runs on it and ``chip_smoke.py``
+        prints it. A quantized pool (ISSUE 19) always takes the gather
+        path: dequant lives in its attend closure, which the pallas
+        kernel has no analogue for."""
+        if not kvcache.is_paged(cache):
+            return "dense"
+        if kvcache.is_quantized(cache):
+            return "paged_gather"
+        return ("paged_kernel" if self._paged_kernel_choice(cache) == "kernel"
+                else "paged_gather")
+
     def decode_step(self, cache, tokens):
         """One token for every slot: tokens (B,) → (logits (B, V), cache).
         Dispatches on the cache layout — dense slots, or the block-paged
         pool (ISSUE 14) via either the XLA gather path or the promoted
-        pallas kernel (ISSUE 17, ``_paged_kernel_choice``) — behind one
-        call site; the passed cache is DONATED either way, keep only
-        the returned one. A quantized pool (ISSUE 19) always takes the
-        gather path — dequant lives in its attend closure, which the
-        pallas kernel has no analogue for — and the weights the matvecs
-        load come from ``_decode_params`` (int8 when promoted)."""
-        if kvcache.is_paged(cache):
-            if kvcache.is_quantized(cache):
-                fn = self._decode_paged
-            else:
-                fn = (self._decode_paged_kernel
-                      if self._paged_kernel_choice(cache) == "kernel"
-                      else self._decode_paged)
-        else:
-            fn = self._decode
+        pallas kernel (ISSUE 17) — behind one call site
+        (:meth:`decode_path`); the passed cache is DONATED either way,
+        keep only the returned one. The weights the matvecs load come
+        from ``_decode_params`` (int8 when promoted)."""
+        fn = {"dense": self._decode, "paged_gather": self._decode_paged,
+              "paged_kernel": self._decode_paged_kernel
+              }[self.decode_path(cache)]
         return fn(self._decode_params(), cache,
                   jnp.asarray(tokens, jnp.int32).reshape(-1))
 

@@ -221,18 +221,34 @@ def flash_engages(cfg, t) -> bool:
             and jax.default_backend() == "tpu")
 
 
+def attention_path(cfg, t, dtype) -> str:
+    """The implementation :func:`_attention` runs for a length-``t``
+    sequence of ``dtype`` activations under ``cfg``: ``"ring"`` |
+    ``"flash"`` | ``"xla_bf16_scores"`` | ``"xla_sdpa"``. One ladder —
+    ``_attention`` dispatches on it, and ``chip_smoke.py`` prints it, so
+    what ran on the chip is named rather than inferred."""
+    if cfg.use_ring_attention:
+        return "ring"
+    if flash_engages(cfg, t):
+        return "flash"
+    if cfg.attn_scores_bf16 and jnp.dtype(dtype) == jnp.bfloat16:
+        return "xla_bf16_scores"
+    return "xla_sdpa"
+
+
 def _attention(cfg, q, k, v, mask_bias=None):
     b, t = q.shape[0], q.shape[1]
     q = q.reshape(b, t, cfg.n_heads, cfg.head_dim)
     k = k.reshape(b, t, cfg.n_heads, cfg.head_dim)
     v = v.reshape(b, t, cfg.n_heads, cfg.head_dim)
-    if cfg.use_ring_attention:
+    path = attention_path(cfg, t, q.dtype)
+    if path == "ring":
         from ..parallel.ring_attention import ring_attention_inner
         out = ring_attention_inner(q, k, v, causal=True)
-    elif flash_engages(cfg, t):
+    elif path == "flash":
         from ..kernels.flash_attention import flash_attention_ntc
         out = flash_attention_ntc(q, k, v, causal=True)
-    elif cfg.attn_scores_bf16 and q.dtype == jnp.bfloat16:
+    elif path == "xla_bf16_scores":
         out = _xla_attention_bf16_scores(q, k, v)
     else:
         out = jax.nn.dot_product_attention(q, k, v, is_causal=True)
@@ -573,7 +589,6 @@ def make_ring_train_step(cfg: TransformerConfig, optimizer, mesh: Mesh):
         raise NotImplementedError(
             "ring step is dense-only; MoE routes through the GSPMD path "
             "(make_train_step under jit with shardings_for)")
-    from .._jax_compat import shard_map
     import optax as _optax
 
     def local_step(params, opt_state, ids, targets):
@@ -601,7 +616,7 @@ def make_ring_train_step(cfg: TransformerConfig, optimizer, mesh: Mesh):
                 "table would clamp, not error")
         rep = jax.tree_util.tree_map(lambda _: P(), params)
         rep_opt = jax.tree_util.tree_map(lambda _: P(), opt_state)
-        return shard_map(
+        return jax.shard_map(
             local_step, mesh=mesh,
             in_specs=(rep, rep_opt, P("dp", "sp"), P("dp", "sp")),
             out_specs=(rep, rep_opt, P()),

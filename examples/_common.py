@@ -1,9 +1,9 @@
 """Shared example boilerplate.
 
-Forces the CPU backend by default so examples run anywhere (set
-EXAMPLES_ON_TPU=1 to use the real chip), and provides the --smoke flag
-every example supports (tiny sizes, a few seconds on CPU — the mode CI
-runs)."""
+Examples run on whatever device JAX finds (`JAX_PLATFORMS=cpu` in the
+environment selects the CPU, where the multi-device examples get 8
+virtual devices), and provides the --smoke flag every example supports
+(tiny sizes, a few seconds on CPU — the mode CI runs)."""
 
 import argparse
 import os
@@ -15,11 +15,9 @@ sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent.parent))
 
 
 def setup(description: str):
-    if not os.environ.get("EXAMPLES_ON_TPU"):
-        os.environ.setdefault("XLA_FLAGS",
-                              "--xla_force_host_platform_device_count=8")
-        import jax
-        jax.config.update("jax_platforms", "cpu")
+    # only the host platform reads this flag; it is inert on an accelerator
+    os.environ.setdefault("XLA_FLAGS",
+                          "--xla_force_host_platform_device_count=8")
     ap = argparse.ArgumentParser(description=description)
     ap.add_argument("--smoke", action="store_true",
                     help="tiny sizes for a fast functional check")

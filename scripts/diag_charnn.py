@@ -50,9 +50,6 @@ def run(tag, fused):
 
 
 if __name__ == "__main__":
-    ok, detail = bench.wait_for_backend(max_wait_s=120)
-    if not ok:
-        print(json.dumps({"backend_unavailable": True, "detail": detail}))
-        sys.exit(0)
+    bench.require_tpu()    # one process per chip: this one takes it
     run("charnn b256 bf16 fused-lstm-kernel", "auto")
     run("charnn b256 bf16 xla-scan", False)

@@ -232,10 +232,7 @@ PHASES = {"A": phase_a, "B": phase_b, "C": phase_c, "D": phase_d,
 
 if __name__ == "__main__":
     which = sys.argv[1:] or list(PHASES)
-    ok, detail = bench.wait_for_backend(max_wait_s=120)
-    if not ok:
-        print(json.dumps({"backend_unavailable": True, "detail": detail}))
-        sys.exit(0)
+    bench.require_tpu()    # one process per chip: this one takes it
     for w in which:
         t0 = time.perf_counter()
         PHASES[w]()

@@ -13,27 +13,12 @@ MeasuredBound philosophy), never a magic constant.
 
     python scripts/perf_gate.py                  # table + gate
     python scripts/perf_gate.py --offline        # CI mode (below)
-    python scripts/perf_gate.py --backfill       # seed 5 rounds of
-                                                 #   real history
     python scripts/perf_gate.py --update-baseline  # re-pin after an
                                                  #   accepted change
     python scripts/perf_gate.py --json
 
 Modes:
 
-- **--backfill**: ingest the historical round artifacts
-  (BENCH_r01–r05.json: headline ``parsed`` + the ``[bench] row: value``
-  stderr tail) and the current ``bench_secondary.json`` into the
-  ledger, normalizing row names/schemas across generations (both
-  headline metric strings map onto ``resnet50``; r2's ``dpscale``
-  deliberately does NOT map onto ``dpoverhead`` — different quantity)
-  so trends start with five rounds of real history. Unknown or renamed
-  rows are LOGGED and ingested under their own name — never dropped
-  silently. Idempotent: an entry whose (row, backend, value) already
-  exists is skipped — which also collapses an r05 stderr tail line
-  with its richer artifact record. Also seeds the documented T=4096
-  best-XLA session set (82–152k tokens/s, docs/PERF.md) so the
-  bimodality debt gets its machine verdict.
 - **--update-baseline**: pin, per (row, backend), the median of the
   recent captures + the measured band (bimodal rows pin BOTH cluster
   medians — the gate then accepts either mode and flags everything
@@ -59,7 +44,6 @@ from __future__ import annotations
 import argparse
 import importlib.util
 import json
-import re
 import sys
 import time
 from pathlib import Path
@@ -67,227 +51,13 @@ from typing import Any, Dict, List, Optional
 
 REPO = Path(__file__).resolve().parent.parent
 
-# standalone import by file path (the refresh_readme_table.py /
-# mem_report.py precedent): trend.py is jax-free by design, so the gate
+# standalone import by file path: trend.py is jax-free by design, so the gate
 # runs in any interpreter without pulling the package in
 _spec = importlib.util.spec_from_file_location(
     "_dl4j_obs_trend_standalone",
     REPO / "deeplearning4j_tpu" / "obs" / "trend.py")
 trend = importlib.util.module_from_spec(_spec)
 _spec.loader.exec_module(trend)
-
-# ---------------------------------------------------------------- backfill
-
-# row-name normalization across artifact generations: the ledger key is
-# the CURRENT bench.py config name. NOTE the deliberate non-rename:
-# r2's `dpscale` (a dp-8 scaling FRACTION, 0.084) is a different
-# quantity than today's `dpoverhead` (ms/step delta) — mapping them
-# onto one series would chart a fake 200× regression, so dpscale stays
-# under its own key and the backfill logs it as unknown.
-ROW_RENAMES: Dict[str, str] = {}
-# both headline metric strings (r01–r02 vs r03+) are the resnet50 row
-HEADLINE_METRICS = {
-    "MultiLayerNetwork.fit() samples/sec/chip (ResNet-50 ImageNet)":
-        "resnet50",
-    "ComputationGraph.fit(DataSetIterator) samples/sec/chip "
-    "(ResNet-50 ImageNet)": "resnet50",
-}
-# units for tail rows (the [bench] lines carry only the value); the
-# names mirror bench.CONFIGS — kept literal so this script stays
-# importable without jax
-ROW_UNITS = {
-    "resnet50": "samples/sec/chip",
-    "resnet50_rawstep": "samples/sec/chip",
-    "resnet50_fitscan": "samples/sec/chip",
-    "lenet": "samples/sec/chip",
-    "lenet_scan": "samples/sec/chip",
-    "charnn": "tokens/sec/chip",
-    "charnn_f32": "tokens/sec/chip",
-    "bert": "seq/sec/chip",
-    "transformer": "tokens/sec/chip",
-    "transformer_long": "tokens/sec/chip",
-    "transformer_xlong": "tokens/sec/chip",
-    "dpoverhead": "ms/step",
-    "inference_decode": "tokens/sec/chip",
-    "inference_ttft_1024": "ms",
-    "inference_ttft_4096": "ms",
-    "inference_scoring": "tokens/sec/chip",
-    "inference_beam": "tokens/sec/chip",
-    "inference_resnet_b1": "ms p50 (batch 1)",
-    "inference_bert_b1": "ms p50 (batch 1)",
-}
-
-_TAIL_ROW = re.compile(r"\[bench\] ([a-zA-Z0-9_]+): (-?[0-9][0-9.eE+-]*)\s")
-
-
-def _dedupe_key(entry: Dict[str, Any]):
-    # (row, backend, value): the r05 tail line and the artifact record
-    # are the SAME capture surfaced twice (one stderr print, one JSON
-    # row, different timestamps) — value identity is what collapses
-    # them, and re-running --backfill stays a no-op
-    return (entry.get("row"), entry.get("backend"), entry.get("value"))
-
-
-def backfill(ledger: Path, log=print) -> int:
-    """Ingest BENCH_r01–r05.json + bench_secondary.json + the recorded
-    T=4096 best-XLA session set. Returns the number of entries
-    appended. Idempotent on re-run."""
-    existing = {_dedupe_key(e) for e in trend.load_ledger(ledger)}
-    appended = 0
-
-    def put(entry: Optional[Dict[str, Any]]):
-        nonlocal appended
-        if entry is None:
-            return
-        if _dedupe_key(entry) in existing:
-            return
-        existing.add(_dedupe_key(entry))
-        trend.append_record(entry, ledger)
-        appended += 1
-
-    # the current one-sha artifact's rows, keyed for the tail-line
-    # substitution below: an r05 `[bench] row: value` stderr line and
-    # the artifact's JSON record are the SAME capture — when both
-    # exist, the RICH record (floor/slo/memory blocks) is the one that
-    # enters the ledger, at the tail line's chronological position
-    art_path = REPO / "bench_secondary.json"
-    try:
-        art = json.loads(art_path.read_text())
-    except (OSError, ValueError):
-        art = {}
-        log("backfill: bench_secondary.json missing/unparseable — "
-            "skipped")
-    artifact_entries: Dict[Any, Dict[str, Any]] = {}
-    head = art.get("headline", {}) if isinstance(art, dict) else {}
-    head_backend = (head.get("backend") or "tpu") \
-        if isinstance(head, dict) else "tpu"
-
-    def artifact_entry(row, rec):
-        entry = trend.ledger_record(row, rec,
-                                    source="backfill:bench_secondary")
-        if entry is not None:
-            # the artifact rows were captured on their own (TPU/CPU)
-            # hosts, not wherever this backfill runs — an unknown
-            # historical host must not adopt the local fingerprint
-            entry["host"] = None
-            if rec.get("backend") is None:
-                # pre-stamp records (the dpoverhead subprocess row)
-                # belong to the capture session the headline stamps —
-                # ingesting them as "unknown" would fork the series
-                # away from the BENCH_r* tail history
-                entry["backend"] = head_backend
-            artifact_entries.setdefault(_dedupe_key(entry), entry)
-
-    if isinstance(head, dict) and head.get("value") is not None:
-        artifact_entry("resnet50", head)
-    for section in ("secondary", "inference"):
-        for name, rec in (art.get(section) or {}).items():
-            if name.startswith("_"):
-                continue
-            row = ROW_RENAMES.get(name, name)
-            if row not in ROW_UNITS:
-                log(f"backfill: bench_secondary.json: unknown row "
-                    f"{name!r} — ingested under its own name")
-            artifact_entry(row, rec)
-
-    for path in sorted(REPO.glob("BENCH_r[0-9][0-9].json")):
-        try:
-            art = json.loads(path.read_text())
-        except ValueError:
-            log(f"backfill: {path.name} unparseable — skipped")
-            continue
-        source = f"backfill:{path.stem}"
-        rnd = art.get("n")
-        parsed = art.get("parsed")
-        if not isinstance(parsed, dict) or parsed.get("value") is None:
-            # a failed round (rc!=0 / backend unavailable) has no rows;
-            # that is a missing capture, not a silently-dropped row
-            log(f"backfill: {path.name}: no parsed headline "
-                f"(rc={art.get('rc')}, backend unavailable or crash) — "
-                "no rows to ingest")
-        else:
-            metric = parsed.get("metric", "")
-            row = HEADLINE_METRICS.get(metric)
-            if row is None:
-                log(f"backfill: {path.name}: unknown headline metric "
-                    f"{metric!r} — ingested under its raw name")
-                row = metric or "headline"
-            entry = trend.ledger_record(row, parsed, source=source)
-            if entry is not None:
-                # pre-r03 headlines predate the backend stamp; both
-                # were captured on the chip (the metric says /chip and
-                # BASELINE.md documents the TPU runs)
-                if parsed.get("backend") is None:
-                    entry["backend"] = "tpu"
-                if parsed.get("step_time_ms") is None \
-                        and parsed.get("mfu") is None:
-                    # pre-methodology capture (r01: 97k img/s with no
-                    # MFU audit — physically impossible): recorded in
-                    # the ledger for completeness, excluded from every
-                    # verdict pool, exactly like a live capture whose
-                    # own audit set timing_valid=false
-                    entry["timing_valid"] = False
-                    log(f"backfill: {path.name}: headline has no "
-                        "step_time/mfu audit — ingested with "
-                        "timing_valid=false (excluded from verdicts)")
-                entry["round"] = rnd
-                entry["host"] = None     # round hosts weren't stamped
-                put(entry)
-        for m in _TAIL_ROW.finditer(art.get("tail", "") + "\n"):
-            name, val = m.group(1), m.group(2)
-            row = ROW_RENAMES.get(name, name)
-            if name in ROW_RENAMES:
-                log(f"backfill: {path.name}: row {name!r} renamed to "
-                    f"{row!r} (schema generation map)")
-            if row not in ROW_UNITS:
-                log(f"backfill: {path.name}: unknown row {name!r} — "
-                    "ingested under its own name (never dropped)")
-            try:
-                value = float(val)
-            except ValueError:
-                log(f"backfill: {path.name}: row {name!r} value "
-                    f"{val!r} not numeric — skipped")
-                continue
-            tail_entry = {"kind": "perf", "row": row, "backend": "tpu",
-                          "host": None, "round": rnd,
-                          "git_sha": parsed.get("git_sha")
-                          if isinstance(parsed, dict) else None,
-                          "captured_at": parsed.get("captured_at")
-                          if isinstance(parsed, dict) else None,
-                          "unit": ROW_UNITS.get(row), "value": value,
-                          "source": source}
-            rich = artifact_entries.pop(_dedupe_key(tail_entry), None)
-            if rich is not None:
-                rich["round"] = rnd   # the tail line's chronology
-                for k in ("git_sha", "captured_at", "unit"):
-                    # the tail line knows the round's provenance; an
-                    # artifact record without its own stamp (the
-                    # dpoverhead subprocess row) inherits it
-                    if rich.get(k) is None and tail_entry.get(k) is not None:
-                        rich[k] = tail_entry[k]
-            put(rich if rich is not None else tail_entry)
-
-    # artifact rows no tail line covered (the inference section, the
-    # headline, any row refreshed after the round) append last — they
-    # are the newest captures
-    for entry in artifact_entries.values():
-        put(entry)
-
-    # the recorded T=4096 best-XLA session set (docs/PERF.md §long
-    # context): the bimodality debt, as data instead of prose
-    put({"kind": "perf", "row": trend.T4096_BEST_XLA_ROW,
-         "backend": "tpu", "host": None,
-         "unit": "tokens/sec/chip",
-         "value": trend.T4096_BEST_XLA_SAMPLES[-1],
-         "value_samples": list(trend.T4096_BEST_XLA_SAMPLES),
-         "source": "backfill:docs/PERF.md",
-         "note": "t4096 b4 best-XLA (bf16-scores remat-full) session "
-                 "extremes — 82–152k tok/s bimodal across r5 sessions; "
-                 "flash beat it in every paired run"})
-    log(f"backfill: {appended} entr{'y' if appended == 1 else 'ies'} "
-        f"appended to {ledger}")
-    return appended
-
 
 # ---------------------------------------------------------------- baseline
 
@@ -453,10 +223,6 @@ def main(argv=None) -> int:
                     help="pinned-baseline path (default "
                          "runs/perf_baseline.json; env "
                          "DL4J_TREND_BASELINE)")
-    ap.add_argument("--backfill", action="store_true",
-                    help="ingest BENCH_r01–r05.json + "
-                         "bench_secondary.json + the recorded T=4096 "
-                         "session set into the ledger (idempotent)")
     ap.add_argument("--update-baseline", action="store_true",
                     help="re-pin the baseline from the current ledger")
     ap.add_argument("--offline", action="store_true",
@@ -469,17 +235,13 @@ def main(argv=None) -> int:
     ledger = args.ledger or trend.ledger_path()
     baseline = args.baseline or trend.baseline_path()
 
-    if args.backfill:
-        backfill(ledger, log=lambda *a: print(*a, file=sys.stderr))
-
     records = trend.load_ledger(ledger)
     if not records:
         msg = f"perf_gate: no ledger records at {ledger}"
         if args.offline:
             print(msg + " — offline mode, nothing to gate (ok)")
             return 0
-        print(msg + " — run `python scripts/perf_gate.py --backfill` "
-              "or a bench capture first", file=sys.stderr)
+        print(msg + " — run a bench capture first", file=sys.stderr)
         return 1
 
     table = trend.trend_table(records)

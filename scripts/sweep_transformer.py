@@ -113,8 +113,5 @@ def phase4():
 
 if __name__ == "__main__":
     phase = sys.argv[1] if len(sys.argv) > 1 else "1"
-    ok, detail = bench.wait_for_backend(max_wait_s=120)
-    if not ok:
-        print(json.dumps({"backend_unavailable": True, "detail": detail}))
-        sys.exit(0)
+    bench.require_tpu()    # one process per chip: this one takes it
     {"1": phase1, "2": phase2, "3": phase3, "4": phase4}[phase]()

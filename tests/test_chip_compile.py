@@ -1,0 +1,232 @@
+"""The main path's kernels, compiled for a DESCRIBED v5e (no chip attached).
+
+The TPU compiler is installed here and compiles for a topology that is only
+described; interpret-mode tests cannot see what it refuses (layouts Mosaic
+cannot express, tiling, VMEM). This is the only file that describes the
+chip: the topology is touched inside a module-scoped, non-autouse fixture
+— never at import, in a ``skipif`` or in ``parametrize`` — so every xdist
+worker collects the same tests and only the worker that runs this file
+loads the TPU library. Nothing here runs on a device or times anything; a
+compile that passes is not a chip run.
+
+``jax.default_backend()`` still says "cpu" here, so the cases that guard a
+``== "tpu"`` branch steer it from the test (monkeypatch), not through an
+option of the program.
+"""
+
+from __future__ import annotations
+
+import functools
+import os
+import sys
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
+from chip_smoke import LM_WIDTH as LM  # noqa: E402 — what the chip will run
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")   # or libtpu logs to /tmp
+
+SLOTS, PAGE_LEN = 8, 16
+N_PAGES = SLOTS * (LM["max_seq"] // PAGE_LEN)
+# BN nodes that carry a relu in zoo.resnet.ResNet50: stem, then the a/b
+# convs of each stage — (spatial side, channels)
+RESNET_BN_ACT = ((112, 64), (56, 64), (28, 128), (14, 256), (7, 512))
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here: skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def no_persistent_cache():
+    """A compile for a described device is written to the persistent cache
+    but cannot be read back without a chip (the next one warns): keep it
+    off around these compiles."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture()
+def as_tpu(monkeypatch):
+    """Take the branches the program takes on the chip."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _sds(one_chip, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+
+def _placed(one_chip, tree):
+    return jax.tree_util.tree_map(
+        lambda s: _sds(one_chip, s.shape, s.dtype), tree)
+
+
+def _compile(fn, *args):
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def test_described_chip_is_a_v5e(topo):
+    from deeplearning4j_tpu.obs import floors
+    assert len(topo.devices) == 4
+    kind = topo.devices[0].device_kind
+    assert floors.device_peaks(kind)["flops"]["bf16"] == 197e12
+
+
+@pytest.mark.parametrize("shape,blocks", [
+    ((32, 8, 1024, 64), (512, 1024)),       # train_lm: T=1024 batch 32
+    ((4, 8, 4096, 64), (1024, 1024)),       # transformer_long: T=4096
+], ids=["b32_t1024_512x1024", "b4_t4096_1024x1024"])
+def test_flash_fwd_bwd_compiles(one_chip, no_persistent_cache, shape,
+                                blocks):
+    from deeplearning4j_tpu.kernels.flash_attention import flash_attention
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, None, True, *blocks, False)
+                       .astype(jnp.float32))
+
+    x = _sds(one_chip, shape, jnp.bfloat16)
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), x, x, x)
+    assert text.count("tpu_custom_call") >= 3       # fwd, dq, dkv
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_paged_decode_kernel_compiles(one_chip, no_persistent_cache, dtype):
+    """Mosaic refused the first form of this kernel (batched dots whose
+    batch axis sat at a different position in each operand)."""
+    from deeplearning4j_tpu.kernels.paged_attention import paged_attention
+    h, dh = LM["n_heads"], LM["d_model"] // LM["n_heads"]
+    pool = _sds(one_chip, (N_PAGES, PAGE_LEN, h, dh), dtype)
+    text = _compile(
+        functools.partial(paged_attention, interpret=False),
+        _sds(one_chip, (SLOTS, h, dh), dtype), pool, pool,
+        _sds(one_chip, (SLOTS, LM["max_seq"] // PAGE_LEN), jnp.int32),
+        _sds(one_chip, (SLOTS,), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+def test_kernel_program_is_the_same_from_any_call_site(one_chip, tmp_path,
+                                                       monkeypatch):
+    """The compile cache's key holds the kernel's serialized module, call
+    stacks included: with the cache helper on, lowering the same kernel
+    from another frame, after other kernels, gives the same program (the
+    LM train step missed the cache on every early chip run)."""
+    from deeplearning4j_tpu.kernels.paged_attention import paged_attention
+    from deeplearning4j_tpu.utils.compile_cache import enable_compile_cache
+    h, dh = LM["n_heads"], LM["d_model"] // LM["n_heads"]
+
+    def lower(page_len=PAGE_LEN):
+        pool = _sds(one_chip, (N_PAGES, page_len, h, dh), jnp.bfloat16)
+        return jax.jit(functools.partial(paged_attention, interpret=False)
+                       ).lower(
+            _sds(one_chip, (SLOTS, h, dh), jnp.bfloat16), pool, pool,
+            _sds(one_chip, (SLOTS, LM["max_seq"] // page_len), jnp.int32),
+            _sds(one_chip, (SLOTS,), jnp.int32)).as_text()
+
+    def from_another_frame():
+        lower(page_len=128)              # history: another kernel first
+        return (lambda: lower())()
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    was = jax.config.jax_traceback_in_locations_limit
+    try:
+        enable_compile_cache()           # sets no directory: the env has one
+        here, there = lower(), from_another_frame()
+    finally:
+        jax.config.update("jax_traceback_in_locations_limit", was)
+    assert "tpu_custom_call" in here and here == there
+
+
+@pytest.mark.parametrize("batch", [1, 128], ids=["b1", "b128"])
+@pytest.mark.parametrize("side,channels", RESNET_BN_ACT,
+                         ids=[f"{s}x{s}x{c}" for s, c in RESNET_BN_ACT])
+def test_fused_bn_act_compiles_at_resnet50_shapes(one_chip,
+                                                  no_persistent_cache,
+                                                  side, channels, batch):
+    from deeplearning4j_tpu.kernels.fused_ops import fused_bn_act
+    vec = _sds(one_chip, (channels,), jnp.float32)
+    text = _compile(
+        lambda x, s, b: fused_bn_act(x, s, b, "relu", False),
+        _sds(one_chip, (batch * side * side, channels), jnp.bfloat16),
+        vec, vec)
+    assert "tpu_custom_call" in text
+
+
+def test_bf16_scores_attention_compiles_with_its_tpu_preset(
+        one_chip, no_persistent_cache, as_tpu):
+    """DotAlgorithmPreset.BF16_BF16_F32 has no CPU form: this is the only
+    place it meets the installed jax before a chip does."""
+    from deeplearning4j_tpu.zoo import transformer as tfm
+    x = _sds(one_chip, (32, 1024, 8, 64), jnp.bfloat16)
+    lowered = jax.jit(tfm._xla_attention_bf16_scores).lower(x, x, x)
+    assert "accumulation_type = f32" in lowered.as_text()   # the preset
+    assert lowered.compile().memory_analysis() is not None
+
+
+def _serving(one_chip, quantized=False):
+    from deeplearning4j_tpu.serving import GenerationEngine, kvcache
+    from deeplearning4j_tpu.zoo import transformer as tfm
+    cfg = tfm.TransformerConfig(**LM, dtype=jnp.bfloat16, remat=False)
+    params = _placed(one_chip, jax.eval_shape(
+        lambda: tfm.init_params(jax.random.PRNGKey(0), cfg)))
+    cache = _placed(one_chip, jax.eval_shape(
+        lambda: kvcache.init_paged_cache(cfg, SLOTS, N_PAGES, PAGE_LEN,
+                                         cfg.max_seq, quantized=quantized)))
+    return GenerationEngine(cfg, params), params, cache
+
+
+@pytest.mark.parametrize("arm", ["gather", "kernel", "gather_int8_pool"])
+def test_paged_decode_step_compiles_at_lm_width(one_chip,
+                                                no_persistent_cache, as_tpu,
+                                                arm):
+    """The whole decode programs the serving races compile on the chip:
+    both arms of the paged-kernel race, and the int8 pool of the quant_kv
+    race."""
+    eng, params, cache = _serving(one_chip, quantized=arm.endswith("pool"))
+    text = _compile(
+        functools.partial(eng._decode_paged_raw, use_kernel=arm == "kernel"),
+        params, cache, _sds(one_chip, (SLOTS,), jnp.int32))
+    assert ("tpu_custom_call" in text) is (arm == "kernel")
+
+
+def test_dense_decode_with_int8_weights_compiles_at_lm_width(
+        one_chip, no_persistent_cache, as_tpu):
+    """The candidate arm of the quant_weights race (serving/quant.py)."""
+    from deeplearning4j_tpu.serving import kvcache, quant
+    eng, params, _ = _serving(one_chip)
+    qparams = _placed(one_chip, jax.eval_shape(quant.quantized_params,
+                                               params))
+    cache = _placed(one_chip, jax.eval_shape(
+        lambda: kvcache.init_cache(eng.cfg, 2, 256)))
+    _compile(eng._decode_raw, qparams, cache,
+             _sds(one_chip, (2,), jnp.int32))
+
+
+def test_prefill_chunk_compiles_at_lm_width(one_chip, no_persistent_cache,
+                                            as_tpu):
+    eng, params, cache = _serving(one_chip)
+    scalar = _sds(one_chip, (), jnp.int32)
+    _compile(eng._prefill_chunk_raw, params, cache,
+             _sds(one_chip, (1, eng.chunk_len), jnp.int32),
+             scalar, scalar, scalar)

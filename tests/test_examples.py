@@ -1,7 +1,6 @@
 """Every example runs green in --smoke mode (the examples are part of the
 product surface — the reference ships dl4j-examples; these mirror it)."""
 
-import os
 import pathlib
 import subprocess
 import sys
@@ -15,12 +14,10 @@ EXAMPLES = sorted(
 
 @pytest.mark.parametrize("script", EXAMPLES, ids=lambda p: p.stem)
 def test_example_smoke(script):
-    env = dict(os.environ)
-    env.pop("EXAMPLES_ON_TPU", None)
     proc = subprocess.run(
         [sys.executable, str(script), "--smoke"],
         capture_output=True, text=True, timeout=900,
-        cwd=script.parent, env=env)
+        cwd=script.parent)
     assert proc.returncode == 0, (
         f"{script.name} failed:\n{proc.stdout[-2000:]}\n"
         f"{proc.stderr[-2000:]}")

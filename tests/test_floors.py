@@ -161,16 +161,22 @@ def test_floor_total_failure_never_crashes(monkeypatch):
     assert "na" in rec["floor"]               # row survived floorless
 
 
-def test_floor_unknown_backend_has_no_peaks():
-    block = floors.floor_block({"flops": 1e9, "bytes": 1e6,
-                                "source": "cost_analysis"},
-                               step_ms=1.0, backend="quantum")
-    assert block["na"] == "no peak table for backend"
-    assert block["flops"] == 10**9            # costs still recorded
+def test_floor_unknown_device_kind_is_an_error():
+    """A chip without published peaks in the table is refused — never
+    judged against the v5e's."""
+    with pytest.raises(ValueError, match="TPU v9 imaginary"):
+        floors.floor_block({"flops": 1e9, "bytes": 1e6,
+                            "source": "cost_analysis"},
+                           step_ms=1.0, device_kind="TPU v9 imaginary")
+    with pytest.raises(ValueError, match="no peak table entry"):
+        floors.device_peaks("tpu")            # a platform is not a kind
+    assert floors.device_peaks("TPU v5 lite")["source"].startswith(
+        "Google Cloud documentation")
+    assert floors.device_peaks()["nominal"] is True      # this CPU run
 
 
 def test_floor_binding_resource_switches():
-    peaks_ok = dict(step_ms=10.0, backend="cpu")
+    peaks_ok = dict(step_ms=10.0, device_kind="cpu")
     hot = floors.floor_block({"flops": 1e12, "bytes": 1e3,
                               "source": "estimated"}, **peaks_ok)
     assert hot["binding_resource"] == "compute"
@@ -310,7 +316,8 @@ def test_floor_metrics_emitted_into_custom_registry():
     reg = MetricsRegistry()
     block = floors.floor_block({"flops": 4e9, "bytes": 2e9,
                                 "source": "cost_analysis"},
-                               step_ms=100.0, backend="tpu", dtype="bf16")
+                               step_ms=100.0, device_kind="TPU v5 lite",
+                               dtype="bf16")
     assert block["peak_flops"] == 197e12
     assert "peaks_nominal" not in block
     out = floors.emit_floor_metrics("cfg", block, registry=reg)

@@ -195,6 +195,68 @@ def test_autotune_picks_and_caches(tmp_path, monkeypatch):
     assert len(calls) == n
 
 
+def test_autotune_keeps_error_text_and_raises_when_all_fail(tmp_path,
+                                                            monkeypatch):
+    """A candidate the compiler refuses keeps its error text in the
+    record; with no candidate timed nothing is persisted and the call
+    raises (never candidates[0] dressed up as a measurement)."""
+    from deeplearning4j_tpu.kernels import autotune as at
+    monkeypatch.setattr(at, "_CACHE_PATH", tmp_path / "autotune.json")
+    at._memory_cache.clear()
+
+    def make_run(cand):
+        def run():
+            if cand == (1, 1):
+                raise ValueError("vmem exceeded for (1, 1)")
+            return jnp.zeros(1)
+        return run
+
+    assert at.autotune("kerr", [(1, 1), (2, 2)], make_run) == (2, 2)
+    meas = dict((tuple(c), t) for c, t in
+                at.measurement_meta("kerr")["measurements"])
+    assert "ValueError: vmem exceeded" in meas[(1, 1)]
+    assert isinstance(meas[(2, 2)], float)
+
+    with pytest.raises(RuntimeError, match="vmem exceeded"):
+        at.autotune("kallfail", [(1, 1)], make_run)
+    assert at.lookup("kallfail") is None
+    assert "kallfail" not in at._memory_cache
+
+
+def test_autotune_times_real_kernels_while_a_jit_is_tracing(tmp_path,
+                                                            monkeypatch):
+    """The flash block choice is made inside the model's traced forward
+    (under scan and remat): candidates must still run on concrete arrays
+    there. In the outer trace every array is a tracer and nothing can be
+    fetched; under ensure_compile_time_eval a pallas kernel cannot even be
+    traced (``program_id`` has no evaluation rule) — the first chip run of
+    this kernel's tuner failed exactly so, for all seven candidates."""
+    from deeplearning4j_tpu.kernels import autotune as at
+    from deeplearning4j_tpu.kernels.flash_attention import \
+        _flash_attention_pallas
+    monkeypatch.setattr(at, "_CACHE_PATH", tmp_path / "autotune.json")
+    at._memory_cache.clear()
+
+    def make_run(cand):
+        q = jax.random.normal(jax.random.PRNGKey(0), (1, 2, 128, 32))
+        grad = jax.jit(jax.grad(lambda q_, k_, v_: jnp.sum(
+            _flash_attention_pallas(q_, k_, v_, None, True, *cand, True))))
+        return lambda: grad(q, q, q)
+
+    def body(carry, _):
+        bq, _bk = at.autotune("ktrace", [(64, 64), (128, 64)], make_run)
+        return carry * bq, None
+
+    @jax.jit
+    def traced(y):
+        return jax.lax.scan(jax.checkpoint(body), y, None, length=2)[0]
+
+    assert float(traced(jnp.ones(()))) in (64.0 ** 2, 128.0 ** 2)
+    timed = at.measurement_meta("ktrace")["measurements"]
+    assert [c for c, _ in timed] == [[64, 64], [128, 64]]
+    assert all(isinstance(t, float) for _, t in timed), timed
+
+
 def test_tuned_blocks_defaults_off_tpu():
     # off-TPU fallback: the measured v5e sweet spot (512, 1024), clamped
     # to divisors of T (diag_t4096 phase-F sweep, 2026-08-01)

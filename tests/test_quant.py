@@ -193,10 +193,34 @@ def test_copy_page_carries_scales(model, engine):
 
 # -------------------------------------------- scheduler integration
 
+def _teacher_forced_logits(engine, ctx, quantized):
+    """(len(ctx), V): the next-token logits after every prefix of ``ctx``
+    through a paged pool of the given storage — ``verify_chunk`` is the
+    chunked-prefill body with every row's logits, rows written to (and
+    read back from) the pool as it goes."""
+    cache = engine.init_paged_cache(1, 16, 4, quantized=quantized)
+    table = PageTable.for_cache(cache)
+    assert table.map(0, len(ctx))
+    cache = table.sync(cache)
+    rows = []
+    for start in range(0, len(ctx), engine.chunk_len):
+        chunk = ctx[start:start + engine.chunk_len]
+        logits, cache = engine.verify_chunk(cache, chunk, slot=0,
+                                            start=start)
+        rows.append(np.asarray(logits, np.float32)[:len(chunk)])
+    return np.concatenate(rows)
+
+
 def test_scheduler_quant_kv_greedy_equivalence(engine):
     """The serve-loop oracle: a scheduler over an int8 pool (prefix
     sharing on — scales must survive shared pages and CoW splits)
-    produces the same greedy tokens as the bf16 pool."""
+    against the float pool. Judged on logits, the rule ROADMAP Speed 1
+    states for the kernel gate: per-position KL within the repo's own
+    promotion bound, and the same greedy tokens up to the first NEAR-TIE
+    — a position whose top-2 logit gap the int8 perturbation can close
+    (random weights produce them; an argmax flip there is not a defect,
+    and past it the two continuations legitimately differ)."""
+    from deeplearning4j_tpu.obs.fidelity import compare_logits
     prompts = [_toks((14,), seed=7), _toks((9,), seed=8)]
     # shared prefix: the second pair of requests exercises prefix-hit
     # admission over quantized pages
@@ -213,7 +237,24 @@ def test_scheduler_quant_kv_greedy_equivalence(engine):
         assert sched.check_pages()
         assert sched.kv_report()["kv_dtype"] == (
             "int8" if mode == "on" else "float32")
-    assert outs["on"] == outs["off"]
+    compared = 0
+    for prompt, off, on in zip(prompts, outs["off"], outs["on"]):
+        # both pools teacher-forced along the float pool's own tokens
+        ctx = np.concatenate([prompt, np.asarray(off[:-1], np.int32)])
+        ref = _teacher_forced_logits(engine, ctx, False)[len(prompt) - 1:]
+        cand = _teacher_forced_logits(engine, ctx, True)[len(prompt) - 1:]
+        assert ref.argmax(-1).tolist() == off      # the oracle is the path
+        assert compare_logits(ref, cand)["kl_max"] <= PROMOTION_MAX_KL
+        top2 = np.sort(ref, axis=-1)[:, -2:]
+        gap = top2[:, 1] - top2[:, 0]
+        # every logit moved by at most this much, so only a gap within
+        # twice it can flip
+        reach = 2.0 * np.abs(ref - cand).max(axis=-1) * 1.01 + 1e-5
+        near_tie = np.nonzero(gap <= reach)[0]
+        upto = int(near_tie[0]) if near_tie.size else len(off)
+        assert on[:upto] == off[:upto], (on, off, upto)
+        compared += upto
+    assert compared >= 12          # the rule still pins most of 18 tokens
 
 
 def test_scheduler_quant_kv_requires_paged_pool(engine):

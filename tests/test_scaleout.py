@@ -138,7 +138,6 @@ WORKER = textwrap.dedent("""
     import json, sys
     import numpy as np
     sys.path.insert(0, {repo!r})
-    import jax; jax.config.update("jax_platforms", "cpu")
     from deeplearning4j_tpu.data import DataSet
     from deeplearning4j_tpu.nn import (DenseLayer, MultiLayerNetwork,
                                        NeuralNetConfiguration, OutputLayer)
