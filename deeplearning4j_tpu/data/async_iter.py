@@ -9,6 +9,23 @@ queue fallback. A batch larger than a ring slot (ImageNet b128 f32 is
 77 MB) rides the queue as it is, a marker holding its place in the ring.
 Either way the consumer API is a normal DataSetIterator.
 
+What each side does is recorded where it happens (``obs.span``, the
+process's registry). The producer thread writes one ``data.produce`` span
+a batch (attrs ``batch``: the k-th batch this wrapper hands over, the
+consumer's count) with the children ``data.source_next`` (the inner
+iterator's ``next``), ``data.pack`` (``np.savez`` + ``getvalue``; attrs
+``bytes``, ``oversize``) and ``data.put`` (blocked until a slot is free);
+the pass that finds the source exhausted carries ``end`` and no ``batch``.
+Its parent is the span that was current when the generation started (a
+thread inherits no ``contextvars``, so it is handed over). The consumer
+writes ``data.unpack`` round ``_unpack``; how long it waited is the
+caller's to time (``fit.next``). Counters: ``dl4j_data_batches_total``,
+``dl4j_data_oversize_batches_total``, ``dl4j_data_packed_bytes_total``,
+``dl4j_data_pack_discarded_bytes_total`` (packed, then sent through the
+queue unpacked) and ``dl4j_data_consumer_waits_total`` (batches that
+``__next__`` did not find ready at its first look: the loop is
+producer-bound where this keeps pace with the batches).
+
 reset() swaps in a FRESH ring/queue generation before restarting the
 producer: an old producer blocked on a full buffer keeps writing (and
 sentinel-ing) only its own abandoned generation, so a stale sentinel can
@@ -24,6 +41,7 @@ from typing import Optional
 
 import numpy as np
 
+from ..obs import get_registry, get_tracer
 from .dataset import DataSet, MultiDataSet
 
 _SENTINEL = b"__END__"
@@ -118,6 +136,23 @@ class AsyncDataSetIterator:
         self._q: Optional[queue.Queue] = None
         self._thread: Optional[threading.Thread] = None
         self._stop: Optional[threading.Event] = None
+        self._handed = 0     # batches handed to the consumer so far
+        reg = get_registry()
+        self._n_batches = reg.counter(
+            "dl4j_data_batches_total",
+            "batches the async prefetch producer handed over")
+        self._n_oversize = reg.counter(
+            "dl4j_data_oversize_batches_total",
+            "batches larger than a ring slot, sent through the queue")
+        self._n_packed_bytes = reg.counter(
+            "dl4j_data_packed_bytes_total",
+            "bytes the async prefetch producer packed for the ring")
+        self._n_discarded_bytes = reg.counter(
+            "dl4j_data_pack_discarded_bytes_total",
+            "bytes packed and thrown away: the batch exceeded a ring slot")
+        self._n_waits = reg.counter(
+            "dl4j_data_consumer_waits_total",
+            "batches the consumer did not find ready at its first look")
         self._start()
 
     def _make_buffers(self):
@@ -137,31 +172,59 @@ class AsyncDataSetIterator:
         self._error = []   # generation-local; producer appends, consumer raises
         self._thread = threading.Thread(
             target=self._produce,
-            args=(self._ring, self._q, self._stop, self._error),
+            args=(self._ring, self._q, self._stop, self._error,
+                  get_tracer().current_context(), self._handed),
             daemon=True)
         self._thread.start()
 
-    def _produce(self, ring, q, stop, error):
+    def _produce(self, ring, q, stop, error, parent, k):
         """Writes ONLY to the generation's own (ring, q, stop, error) — after
         reset() these are abandoned objects and nothing here touches the
         live ones. A source exception is captured into `error` and re-raised
         on the CONSUMER side at the sentinel — silently truncating an epoch
-        because the data pipeline died would be a training-integrity bug."""
+        because the data pipeline died would be a training-integrity bug.
+        ``parent`` is the span this generation's spans hang under and ``k``
+        the number of its first batch."""
+        span = get_tracer().span
         try:
-            for ds in self.inner:
-                payload = _pack(ds) if ring is not None else ds
-                if ring is not None and len(payload) > ring.slot_size:
-                    # queue first, marker second: a consumer that pops
-                    # the marker always finds the batch waiting
-                    _put(None, q, stop, ds)
-                    payload = _OVERSIZE
-                _put(ring, q, stop, payload)
-                if stop.is_set():
-                    return
+            source = iter(self.inner)
+            while not stop.is_set():
+                with span("data.produce", parent=parent) as produce:
+                    with span("data.source_next") as source_next:
+                        ds = next(source, _SENTINEL)
+                        if ds is _SENTINEL:
+                            produce.set_attr("end", True)
+                            return
+                        produce.set_attr("batch", k)
+                        source_next.set_attr("batch", k)
+                    self._hand_over(ring, q, stop, ds, k)
+                k += 1
         except BaseException as e:  # noqa: BLE001 — handed to the consumer
             error.append(e)
         finally:
             _put(ring, q, stop, _SENTINEL)
+
+    def _hand_over(self, ring, q, stop, ds, k):
+        span = get_tracer().span
+        payload, oversize = ds, False
+        if ring is not None:
+            with span("data.pack", attrs={"batch": k}) as pack:
+                payload = _pack(ds)
+                oversize = len(payload) > ring.slot_size
+                pack.set_attr("bytes", len(payload))
+                pack.set_attr("oversize", oversize)
+            self._n_packed_bytes.inc(len(payload))
+            if oversize:
+                self._n_oversize.inc()
+                self._n_discarded_bytes.inc(len(payload))
+        with span("data.put", attrs={"batch": k}):
+            if oversize:
+                # queue first, marker second: a consumer that pops
+                # the marker always finds the batch waiting
+                _put(None, q, stop, ds)
+                payload = _OVERSIZE
+            _put(ring, q, stop, payload)
+        self._n_batches.inc()
 
     # ------------------------------------------------------------- consumer
     def __iter__(self):
@@ -169,7 +232,9 @@ class AsyncDataSetIterator:
 
     def __next__(self) -> DataSet:
         ring, q = self._ring, self._q
+        looks = 0
         while True:
+            looks += 1
             if ring is not None:
                 raw = ring.pop()
                 if raw is None:
@@ -178,14 +243,28 @@ class AsyncDataSetIterator:
                 if raw == _SENTINEL:
                     self._raise_producer_error()
                     raise StopIteration
+                k = self._took_batch(looks)
                 if raw == _OVERSIZE:
                     return q.get()
-                return _unpack(raw)
-            item = q.get()
+                with get_tracer().span("data.unpack", attrs={"batch": k}):
+                    return _unpack(raw)
+            try:
+                item = q.get(block=looks > 1)
+            except queue.Empty:
+                continue
             if isinstance(item, bytes) and item == _SENTINEL:
                 self._raise_producer_error()
                 raise StopIteration
+            self._took_batch(looks)
             return item
+
+    def _took_batch(self, looks: int) -> int:
+        """The number of the batch just taken, found at the ``looks``-th
+        look."""
+        if looks > 1:
+            self._n_waits.inc()
+        self._handed += 1
+        return self._handed - 1
 
     def _raise_producer_error(self):
         if self._error:

@@ -123,21 +123,23 @@ def _fwd(q, k, v, scale, causal, block_q, block_k, interpret):
     vf = v.reshape(b * h, t, d)
     kv_map = _causal_kv_map(bq, bk, causal)
     grid = (b * h, t // bq, t // bk)      # kv block = fastest dim (streamed)
-    out, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, scale=scale, causal=causal),
-        grid=grid,
-        in_specs=[pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
-                  pl.BlockSpec((1, bk, d), kv_map),
-                  pl.BlockSpec((1, bk, d), kv_map)],
-        out_specs=[pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
-                   pl.BlockSpec((1, bq, 8), lambda bh, i, j: (bh, i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
-                   jax.ShapeDtypeStruct((b * h, t, 8), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32),
-                        pltpu.VMEM((bq, 8), jnp.float32),
-                        pltpu.VMEM((bq, 8), jnp.float32)],
-        interpret=interpret,
-    )(qf, kf, vf)
+    with jax.named_scope("flash_fwd"):
+        out, lse = pl.pallas_call(
+            functools.partial(_fwd_kernel, scale=scale, causal=causal),
+            grid=grid,
+            in_specs=[pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
+                      pl.BlockSpec((1, bk, d), kv_map),
+                      pl.BlockSpec((1, bk, d), kv_map)],
+            out_specs=[pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
+                       pl.BlockSpec((1, bq, 8), lambda bh, i, j: (bh, i, 0))],
+            out_shape=[jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
+                       jax.ShapeDtypeStruct((b * h, t, 8), jnp.float32)],
+            scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32),
+                            pltpu.VMEM((bq, 8), jnp.float32),
+                            pltpu.VMEM((bq, 8), jnp.float32)],
+            interpret=interpret,
+            name="flash_fwd",
+        )(qf, kf, vf)
     return out.reshape(b, h, t, d), lse[:, :, 0].reshape(b, h, t)
 
 
@@ -291,40 +293,44 @@ def _flash_bwd_impl(scale, causal, block_q, block_k, interpret,
     else:
         q_map = lambda bh, j, i: (bh, i, 0)
 
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=scale, causal=causal),
-        grid=(b * h, t // bq, t // bk),   # kv block streamed (fastest dim)
-        in_specs=[pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
-                  pl.BlockSpec((1, bk, d), kv_map),
-                  pl.BlockSpec((1, bk, d), kv_map),
-                  pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
-                  pl.BlockSpec((1, bq, 8), lambda bh, i, j: (bh, i, 0)),
-                  pl.BlockSpec((1, bq, 8), lambda bh, i, j: (bh, i, 0))],
-        out_specs=pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        interpret=interpret,
-    )(qf, kf, vf, dof, lsef, deltaf)
+    with jax.named_scope("flash_bwd_dq"):
+        dq = pl.pallas_call(
+            functools.partial(_bwd_dq_kernel, scale=scale, causal=causal),
+            grid=(b * h, t // bq, t // bk),   # kv block streamed (fastest dim)
+            in_specs=[pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
+                      pl.BlockSpec((1, bk, d), kv_map),
+                      pl.BlockSpec((1, bk, d), kv_map),
+                      pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
+                      pl.BlockSpec((1, bq, 8), lambda bh, i, j: (bh, i, 0)),
+                      pl.BlockSpec((1, bq, 8), lambda bh, i, j: (bh, i, 0))],
+            out_specs=pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
+            out_shape=jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
+            scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
+            interpret=interpret,
+            name="flash_bwd_dq",
+        )(qf, kf, vf, dof, lsef, deltaf)
 
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal),
-        grid=(b * h, t // bk, t // bq),   # q block streamed (fastest dim)
-        in_specs=[pl.BlockSpec((1, bq, d), q_map),
-                  pl.BlockSpec((1, bk, d), lambda bh, j, i: (bh, j, 0)),
-                  pl.BlockSpec((1, bk, d), lambda bh, j, i: (bh, j, 0)),
-                  pl.BlockSpec((1, bq, d), q_map),
-                  # lse/delta stream with the q block — clamp them too, or
-                  # dead causal steps keep fetching these (1, bq, 8) blocks
-                  pl.BlockSpec((1, bq, 8), q_map),
-                  pl.BlockSpec((1, bq, 8), q_map)],
-        out_specs=[pl.BlockSpec((1, bk, d), lambda bh, j, i: (bh, j, 0)),
-                   pl.BlockSpec((1, bk, d), lambda bh, j, i: (bh, j, 0))],
-        out_shape=[jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
-                   jax.ShapeDtypeStruct((b * h, t, d), q.dtype)],
-        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
-                        pltpu.VMEM((bk, d), jnp.float32)],
-        interpret=interpret,
-    )(qf, kf, vf, dof, lsef, deltaf)
+    with jax.named_scope("flash_bwd_dkv"):
+        dk, dv = pl.pallas_call(
+            functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal),
+            grid=(b * h, t // bk, t // bq),   # q block streamed (fastest dim)
+            in_specs=[pl.BlockSpec((1, bq, d), q_map),
+                      pl.BlockSpec((1, bk, d), lambda bh, j, i: (bh, j, 0)),
+                      pl.BlockSpec((1, bk, d), lambda bh, j, i: (bh, j, 0)),
+                      pl.BlockSpec((1, bq, d), q_map),
+                      # lse/delta stream with the q block — clamp them too, or
+                      # dead causal steps keep fetching these (1, bq, 8) blocks
+                      pl.BlockSpec((1, bq, 8), q_map),
+                      pl.BlockSpec((1, bq, 8), q_map)],
+            out_specs=[pl.BlockSpec((1, bk, d), lambda bh, j, i: (bh, j, 0)),
+                       pl.BlockSpec((1, bk, d), lambda bh, j, i: (bh, j, 0))],
+            out_shape=[jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
+                       jax.ShapeDtypeStruct((b * h, t, d), q.dtype)],
+            scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
+                            pltpu.VMEM((bk, d), jnp.float32)],
+            interpret=interpret,
+            name="flash_bwd_dkv",
+        )(qf, kf, vf, dof, lsef, deltaf)
 
     shape = (b, h, t, d)
     return dq.reshape(shape), dk.reshape(shape), dv.reshape(shape)

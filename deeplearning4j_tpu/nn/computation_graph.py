@@ -17,6 +17,7 @@ from jax import lax
 import numpy as np
 import optax
 
+from ..obs.spans import span
 from ..train.updaters import NoOp, build_optimizer
 from .graph import ComputationGraphConfiguration
 from .layers.base import Ctx, Layer
@@ -580,15 +581,17 @@ class ComputationGraph:
             from ..train.anomaly import DelayedAnomalyCheck
             anomaly_check = DelayedAnomalyCheck(self._anomaly_detector)
         # async batch prep on a background thread, like MultiLayerNetwork.fit
-        # (DL4J wraps both fit entry points the same way)
+        # (DL4J wraps both fit entry points the same way); started inside
+        # the root span, so the producer's spans join this call's trace
         from ..data.async_iter import maybe_wrap_async
-        run_iter, wrapped = maybe_wrap_async(iterator)
-        try:
-            last = self._fit_epochs(run_iter, iterator, wrapped, epochs,
-                                    step_fn, anomaly_check)
-        finally:
-            if wrapped is not None:
-                wrapped.close()
+        with span("fit", attrs={"epochs": epochs}):
+            run_iter, wrapped = maybe_wrap_async(iterator)
+            try:
+                last = self._fit_epochs(run_iter, iterator, wrapped, epochs,
+                                        step_fn, anomaly_check)
+            finally:
+                if wrapped is not None:
+                    wrapped.close()
         if anomaly_check is not None:
             anomaly_check.flush()
         return None if last is None else float(last)
@@ -664,35 +667,61 @@ class ComputationGraph:
 
     def _fit_epochs(self, run_iter, source_iter, wrapped, epochs, step_fn,
                     anomaly_check):
+        """The epoch loop, with one ``fit.iteration`` span a pass (attrs
+        ``batch``: the k-th batch of this call, and ``examples``) whose
+        children, in order, are ``fit.next`` (until the batch is in hand),
+        ``fit.h2d`` (the ``jnp.asarray`` copies; attrs ``bytes``),
+        ``fit.dispatch`` (the step call), ``fit.loss_sync`` (``float(loss)``,
+        where listeners ask for it) and ``fit.listeners``. The pass that
+        finds the iterator exhausted carries ``end`` and no ``batch``."""
+        from ..data.dataset import MultiDataSet as MDS
         last = None
+        k = 0
         for e in range(epochs):
-            for ds in run_iter:
-                from ..data.dataset import MultiDataSet as MDS
-                if isinstance(ds, MDS):
-                    feats, labs = ds.features, ds.labels
-                    fmask = None if ds.features_masks is None else ds.features_masks[0]
-                    lmask = None if ds.labels_masks is None else ds.labels_masks[0]
-                else:
-                    feats, labs = [ds.features], [ds.labels]
-                    fmask, lmask = ds.features_mask, ds.labels_mask
-                inputs = {n: jnp.asarray(f) for n, f in zip(self.conf.inputs, feats)}
-                labels = {n: jnp.asarray(l) for n, l in zip(self.conf.outputs, labs)}
-                # examples-throughput telemetry (MetricsListener)
-                self._last_batch_size = int(next(iter(inputs.values())).shape[0])
-                fm = None if fmask is None else jnp.asarray(fmask)
-                lm = None if lmask is None else jnp.asarray(lmask)
-                (self.params, self.states, self._opt_state, loss, gstats,
-                 self._host_key) = step_fn(
-                    self.params, self.states, self._opt_state, inputs, labels,
-                    self._host_key, fm, lm)
-                self._step_count += 1
-                if anomaly_check is not None and gstats is not None:
-                    anomaly_check.push(gstats, self._step_count)
-                last = loss
-                if self.listeners:
-                    lv = float(loss)
-                    for listener in self.listeners:
-                        listener.iteration_done(self, self._step_count, self.epoch_count, lv)
+            batches = iter(run_iter)
+            while True:
+                with span("fit.iteration") as iteration:
+                    with span("fit.next", attrs={"batch": k}):
+                        ds = next(batches, None)
+                    if ds is None:
+                        iteration.set_attr("end", True)
+                        break
+                    if isinstance(ds, MDS):
+                        feats, labs = ds.features, ds.labels
+                        fmask = None if ds.features_masks is None else ds.features_masks[0]
+                        lmask = None if ds.labels_masks is None else ds.labels_masks[0]
+                    else:
+                        feats, labs = [ds.features], [ds.labels]
+                        fmask, lmask = ds.features_mask, ds.labels_mask
+                    with span("fit.h2d", attrs={"batch": k}) as h2d:
+                        inputs = {n: jnp.asarray(f) for n, f in zip(self.conf.inputs, feats)}
+                        labels = {n: jnp.asarray(l) for n, l in zip(self.conf.outputs, labs)}
+                        fm = None if fmask is None else jnp.asarray(fmask)
+                        lm = None if lmask is None else jnp.asarray(lmask)
+                        h2d.set_attr("bytes", sum(
+                            a.nbytes for a in (*inputs.values(),
+                                               *labels.values(), fm, lm)
+                            if a is not None))
+                    # examples-throughput telemetry (MetricsListener)
+                    self._last_batch_size = int(next(iter(inputs.values())).shape[0])
+                    iteration.set_attr("batch", k)
+                    iteration.set_attr("examples", self._last_batch_size)
+                    with span("fit.dispatch", attrs={"batch": k}):
+                        (self.params, self.states, self._opt_state, loss,
+                         gstats, self._host_key) = step_fn(
+                            self.params, self.states, self._opt_state, inputs,
+                            labels, self._host_key, fm, lm)
+                    self._step_count += 1
+                    if anomaly_check is not None and gstats is not None:
+                        anomaly_check.push(gstats, self._step_count)
+                    last = loss
+                    if self.listeners:
+                        with span("fit.loss_sync", attrs={"batch": k}):
+                            lv = float(loss)
+                        with span("fit.listeners", attrs={"batch": k}):
+                            for listener in self.listeners:
+                                listener.iteration_done(self, self._step_count, self.epoch_count, lv)
+                    k += 1
             self.epoch_count += 1
             if e < epochs - 1:
                 if hasattr(run_iter, "reset"):

@@ -22,6 +22,7 @@ from jax import lax
 import numpy as np
 import optax
 
+from ..obs.spans import span
 from ..train.updaters import NoOp, build_optimizer, gradient_normalization
 from .conf import MultiLayerConfiguration
 from .layers.base import Ctx, Layer
@@ -444,12 +445,26 @@ class MultiLayerNetwork:
                     jax.tree_util.tree_leaves(restored))
                 self._restored_opt_state = None
         step_fn = self._get_train_step()
-        last = None
         anomaly_check = None
         if getattr(self, "_anomaly_detector", None) is not None:
             from ..train.anomaly import DelayedAnomalyCheck
             anomaly_check = DelayedAnomalyCheck(self._anomaly_detector)
 
+        with span("fit", attrs={"epochs": epochs}):
+            last = self._fit_epochs(iterator, epochs, step_fn, anomaly_check)
+        if anomaly_check is not None:
+            anomaly_check.flush()
+        return None if last is None else float(last)
+
+    def _fit_epochs(self, iterator, epochs, step_fn, anomaly_check):
+        """The epoch loop, under the same span names at the same places as
+        ``ComputationGraph._fit_epochs``: one ``fit.iteration`` a pass (attrs
+        ``batch``, ``examples``; ``end`` on the pass that finds the iterator
+        exhausted) over ``fit.next``, ``fit.h2d``, ``fit.dispatch``,
+        ``fit.loss_sync`` and ``fit.listeners``. Where the score fetch is
+        deferred, batch k-1's ``fit.loss_sync`` and ``fit.listeners`` lie
+        in batch k's iteration (their ``batch`` says whose they are) and
+        the epoch's last pair directly under ``fit``."""
         # DL4J's fit wraps the source in an AsyncDataSetIterator so batch
         # prep runs on a background thread while the device computes; do
         # the same when the iterator opts in (async_supported).
@@ -466,44 +481,63 @@ class MultiLayerNetwork:
         defer_ok = all(getattr(ls, "deferred_score_ok", False)
                        for ls in self.listeners)
         pending = None
+        last = None
+        k = 0
+
+        def report(loss_d, si, ei, batch):
+            with span("fit.loss_sync", attrs={"batch": batch}):
+                lv = float(loss_d)
+            with span("fit.listeners", attrs={"batch": batch}):
+                for listener in self.listeners:
+                    listener.iteration_done(self, si, ei, lv)
 
         def flush_pending():
             nonlocal pending
             if pending is not None:
-                loss_d, si, ei = pending
-                pending = None
-                lv = float(loss_d)
-                for listener in self.listeners:
-                    listener.iteration_done(self, si, ei, lv)
+                args, pending = pending, None
+                report(*args)
 
         try:
             for e in range(epochs):
-                for ds in run_iter:
-                    x = jnp.asarray(ds.features)
-                    y = jnp.asarray(ds.labels)
-                    # examples-throughput telemetry (MetricsListener)
-                    self._last_batch_size = int(x.shape[0])
-                    fmask = None if ds.features_mask is None else jnp.asarray(ds.features_mask)
-                    lmask = None if ds.labels_mask is None else jnp.asarray(ds.labels_mask)
-                    (self.params, self.states, self._opt_state, loss, gstats,
-                     self._host_key) = step_fn(
-                        self.params, self.states, self._opt_state, x, y,
-                        self._host_key, fmask, lmask)
-                    self._step_count += 1
-                    if anomaly_check is not None and gstats is not None:
-                        anomaly_check.push(gstats, self._step_count)
-                    last = loss
-                    if self.listeners:
-                        if defer_ok:
-                            flush_pending()
-                            pending = (loss, self._step_count,
-                                       self.epoch_count)
-                        else:
-                            lv = float(loss)
-                            for listener in self.listeners:
-                                listener.iteration_done(
-                                    self, self._step_count, self.epoch_count,
-                                    lv)
+                batches = iter(run_iter)
+                while True:
+                    with span("fit.iteration") as iteration:
+                        with span("fit.next", attrs={"batch": k}):
+                            ds = next(batches, None)
+                        if ds is None:
+                            iteration.set_attr("end", True)
+                            break
+                        with span("fit.h2d", attrs={"batch": k}) as h2d:
+                            x = jnp.asarray(ds.features)
+                            y = jnp.asarray(ds.labels)
+                            fmask = None if ds.features_mask is None else jnp.asarray(ds.features_mask)
+                            lmask = None if ds.labels_mask is None else jnp.asarray(ds.labels_mask)
+                            h2d.set_attr("bytes", sum(
+                                a.nbytes for a in (x, y, fmask, lmask)
+                                if a is not None))
+                        # examples-throughput telemetry (MetricsListener)
+                        self._last_batch_size = int(x.shape[0])
+                        iteration.set_attr("batch", k)
+                        iteration.set_attr("examples", self._last_batch_size)
+                        with span("fit.dispatch", attrs={"batch": k}):
+                            (self.params, self.states, self._opt_state, loss,
+                             gstats, self._host_key) = step_fn(
+                                self.params, self.states, self._opt_state, x,
+                                y, self._host_key, fmask, lmask)
+                        self._step_count += 1
+                        if anomaly_check is not None and gstats is not None:
+                            anomaly_check.push(gstats, self._step_count)
+                        last = loss
+                        if self.listeners:
+                            if defer_ok:
+                                # step k-1's loss, while step k is in flight
+                                flush_pending()
+                                pending = (loss, self._step_count,
+                                           self.epoch_count, k)
+                            else:
+                                report(loss, self._step_count,
+                                       self.epoch_count, k)
+                        k += 1
                 self.epoch_count += 1
                 if e < epochs - 1:
                     if hasattr(run_iter, "reset"):
@@ -531,9 +565,7 @@ class MultiLayerNetwork:
                 pass
             if wrapped is not None:
                 wrapped.close()
-        if anomaly_check is not None:
-            anomaly_check.flush()
-        return None if last is None else float(last)
+        return last
 
     def fit_scanned(self, data, *, epochs: int = 1):
         """TPU-idiomatic epoch loop: ONE jit dispatch per epoch.

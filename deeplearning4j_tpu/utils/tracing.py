@@ -215,11 +215,6 @@ def profile_trace(log_dir: str = "runs/profile", host_tracer_level: int = 2):
         yield log_dir
 
 
-def annotate(name: str):
-    """Named region that shows up on the trace timeline (host + device)."""
-    return jax.profiler.TraceAnnotation(name)
-
-
 def start_profiler_server(port: int = 9999):
     """On-demand profiling: connect tensorboard's capture-profile to this."""
     return jax.profiler.start_server(port)

@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import functools
 import os
+import re
 import sys
 from pathlib import Path
 
@@ -108,6 +109,10 @@ def test_flash_fwd_bwd_compiles(one_chip, no_persistent_cache, shape,
     x = _sds(one_chip, shape, jnp.bfloat16)
     text = _compile(jax.grad(loss, argnums=(0, 1, 2)), x, x, x)
     assert text.count("tpu_custom_call") >= 3       # fwd, dq, dkv
+    # each kernel's HLO instruction carries the kernel's own name (a trace
+    # names device events by their HLO line), not the jaxpr scope's
+    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert re.search(rf"%{name}(\.\d+)? = [^\n]*tpu_custom_call", text), name
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],

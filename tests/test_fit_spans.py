@@ -1,0 +1,321 @@
+"""Spans and counters inside ``fit()`` and its async prefetch (ISSUE 26).
+
+One ``fit`` root per call; one ``fit.iteration`` per batch whose children
+lie inside it, in order, on one clock; the producer thread's ``data.*``
+spans in the same trace under the same ``batch`` numbers; the five
+``dl4j_data_*`` counters at the ring's boundaries; what a span costs; and
+the keys ``record()`` and the JSONL have always had.
+"""
+
+import threading
+import time
+
+import numpy as np
+import pytest
+
+from deeplearning4j_tpu import obs
+from deeplearning4j_tpu.data.async_iter import AsyncDataSetIterator
+from deeplearning4j_tpu.data.iterators import ArrayDataSetIterator
+from deeplearning4j_tpu.nn.conf import NeuralNetConfiguration
+from deeplearning4j_tpu.nn.computation_graph import ComputationGraph
+from deeplearning4j_tpu.nn.layers.base import InputType
+from deeplearning4j_tpu.nn.layers.core import DenseLayer, OutputLayer
+from deeplearning4j_tpu.nn.listeners import TrainingListener
+from deeplearning4j_tpu.nn.multi_layer_network import MultiLayerNetwork
+
+N_BATCHES, BATCH = 5, 4
+#: the children of ``fit.iteration`` in the order the loop runs them
+ORDER = ["fit.next", "fit.h2d", "fit.dispatch", "fit.loss_sync",
+         "fit.listeners"]
+COUNTERS = ["dl4j_data_batches_total", "dl4j_data_oversize_batches_total",
+            "dl4j_data_packed_bytes_total",
+            "dl4j_data_pack_discarded_bytes_total",
+            "dl4j_data_consumer_waits_total"]
+
+
+class _Scores(TrainingListener):
+    def __init__(self):
+        self.scores = []
+
+    def iteration_done(self, model, iteration, epoch, score):
+        self.scores.append(score)
+
+
+def _data(seed=0):
+    rng = np.random.default_rng(seed)
+    x = rng.normal(size=(N_BATCHES * BATCH, 6)).astype(np.float32)
+    y = np.eye(3, dtype=np.float32)[rng.integers(0, 3, len(x))]
+    return x, y
+
+
+def _graph():
+    g = NeuralNetConfiguration.builder().seed(1).graph_builder() \
+        .add_inputs("in")
+    g.add_layer("d", DenseLayer(n_in=6, n_out=8, activation="relu"), "in")
+    g.add_layer("out", OutputLayer(n_in=8, n_out=3, activation="softmax",
+                                   loss="mcxent"), "d")
+    g.set_outputs("out")
+    g.set_input_types(InputType.feed_forward(6))
+    return ComputationGraph(g.build()).init()
+
+
+def _mln():
+    conf = NeuralNetConfiguration.builder().seed(1).list() \
+        .layer(DenseLayer(n_in=6, n_out=8, activation="relu")) \
+        .layer(OutputLayer(n_in=8, n_out=3, activation="softmax",
+                           loss="mcxent")) \
+        .build()
+    return MultiLayerNetwork(conf).init()
+
+
+def _fit_and_collect(net, epochs=1):
+    """Spans of ONE fit() call over an iterator that opts into the async
+    wrapper, as {name: [spans in start order]}, and the root."""
+    net.set_listeners(_Scores())
+    x, y = _data()
+    tracer = obs.get_tracer()
+    before = {id(s) for s in tracer.spans()}
+    net.fit(ArrayDataSetIterator(x, y, BATCH), epochs=epochs)
+    mine = sorted((s for s in tracer.spans() if id(s) not in before),
+                  key=lambda s: s.t0_ns)
+    roots = [s for s in mine if s.name == "fit"]
+    assert len(roots) == 1
+    root = roots[0]
+    mine = [s for s in mine if s.trace_id == root.trace_id]
+    by_name = {}
+    for s in mine:
+        by_name.setdefault(s.name, []).append(s)
+    return root, by_name, mine
+
+
+@pytest.mark.parametrize("make_net", [_graph, _mln],
+                         ids=["ComputationGraph", "MultiLayerNetwork"])
+def test_fit_span_tree_on_one_clock(make_net):
+    root, by_name, mine = _fit_and_collect(make_net())
+    assert root.parent_id is None and root.attrs == {"epochs": 1}
+    me = threading.current_thread().name
+
+    iterations = [s for s in by_name["fit.iteration"] if "batch" in s.attrs]
+    ends = [s for s in by_name["fit.iteration"] if s.attrs.get("end")]
+    assert [s.attrs["batch"] for s in iterations] == list(range(N_BATCHES))
+    assert all(s.attrs["examples"] == BATCH for s in iterations)
+    assert len(ends) == 1 and "batch" not in ends[0].attrs
+    for it in iterations + ends:
+        assert it.parent_id == root.span_id and it.thread == me
+        assert root.t0_ns <= it.t0_ns <= it.t1_ns <= root.t1_ns
+
+    for it in iterations:
+        kids = [s for s in mine if s.parent_id == it.span_id]
+        assert [s.name for s in kids] == ORDER
+        assert all(s.attrs["batch"] == it.attrs["batch"] for s in kids)
+        assert all(s.thread == me for s in kids)
+        # inside the parent, one after the other, on perf_counter_ns
+        edges = [it.t0_ns]
+        for s in kids:
+            edges += [s.t0_ns, s.t1_ns]
+        edges.append(it.t1_ns)
+        assert edges == sorted(edges)
+    assert [s.attrs["bytes"] for s in by_name["fit.h2d"]] \
+        == [BATCH * (6 + 3) * 4] * N_BATCHES
+
+    # the producer's side: same trace, another thread, the same numbering
+    produced = [s for s in by_name["data.produce"] if "batch" in s.attrs]
+    assert [s.attrs["batch"] for s in produced] == list(range(N_BATCHES))
+    assert sum(bool(s.attrs.get("end")) for s in by_name["data.produce"]) == 1
+    for p in produced:
+        assert p.parent_id == root.span_id and p.trace_id == root.trace_id
+        assert p.thread != me
+        kids = [s for s in mine if s.parent_id == p.span_id]
+        assert [s.name for s in kids] in (
+            ["data.source_next", "data.pack", "data.put"],
+            ["data.source_next", "data.put"])       # no native ring: no pack
+        for s in kids:
+            assert s.thread == p.thread
+            assert s.attrs["batch"] == p.attrs["batch"]
+            assert p.t0_ns <= s.t0_ns <= s.t1_ns <= p.t1_ns
+        # a batch is produced before the training thread has it in hand
+        got = by_name["fit.next"][p.attrs["batch"]]
+        assert p.t0_ns <= got.t1_ns
+    for s in by_name.get("data.unpack", []):
+        assert by_name["fit.next"][s.attrs["batch"]].span_id == s.parent_id
+
+
+def test_fit_root_per_call_and_epochs():
+    net = _graph()
+    root1, _, _ = _fit_and_collect(net)
+    root2, by_name, _ = _fit_and_collect(net, epochs=2)
+    assert root1.trace_id != root2.trace_id
+    assert root2.attrs == {"epochs": 2}
+    batches = [s.attrs["batch"] for s in by_name["fit.iteration"]
+               if "batch" in s.attrs]
+    assert batches == list(range(2 * N_BATCHES))    # k runs on over epochs
+    assert sorted(s.attrs["batch"] for s in by_name["data.produce"]
+                  if "batch" in s.attrs) == batches
+
+
+def test_mln_deferred_scores_keep_their_batch():
+    """Logging listeners get step k-1's score while step k is in flight:
+    the sync and listener spans say whose they are."""
+    net = _mln()
+
+    class Deferred(_Scores):
+        deferred_score_ok = True
+
+    net.set_listeners(Deferred())
+    x, y = _data()
+    tracer = obs.get_tracer()
+    before = {id(s) for s in tracer.spans()}
+    net.fit(ArrayDataSetIterator(x, y, BATCH))
+    mine = [s for s in tracer.spans() if id(s) not in before]
+    syncs = sorted((s for s in mine if s.name == "fit.loss_sync"),
+                   key=lambda s: s.t0_ns)
+    assert [s.attrs["batch"] for s in syncs] == list(range(N_BATCHES))
+    by_id = {s.span_id: s for s in mine}
+    parents = [by_id[s.parent_id] for s in syncs]
+    assert [p.attrs.get("batch") for p in parents[:-1]] \
+        == list(range(1, N_BATCHES))
+    assert parents[-1].name == "fit"
+    assert len(net.listeners[0].scores) == N_BATCHES
+
+
+def _counter_values():
+    reg = obs.get_registry()
+    return {n: (reg.get(n).value() if reg.get(n) else 0.0) for n in COUNTERS}
+
+
+@pytest.mark.parametrize("slot_size, oversize", [(64, True), (1 << 20, False)],
+                         ids=["slot_smaller_than_batch", "batch_fits_slot"])
+def test_data_counters_at_the_ring(slot_size, oversize):
+    x, y = _data()
+    source = ArrayDataSetIterator(x, y, BATCH)
+    before = _counter_values()      # the producer starts with the wrapper
+    it = AsyncDataSetIterator(source, queue_size=2, slot_size=slot_size)
+    try:
+        got = list(it)
+    finally:
+        it.close()
+    after = _counter_values()
+    d = {n: after[n] - before[n] for n in COUNTERS}
+    assert len(got) == N_BATCHES
+    np.testing.assert_array_equal(
+        np.concatenate([np.asarray(b.features) for b in got]), x)
+    assert d["dl4j_data_batches_total"] == N_BATCHES
+    if not _has_native():
+        # queue path: nothing is packed, so nothing can be discarded
+        assert d["dl4j_data_packed_bytes_total"] == 0
+        assert d["dl4j_data_pack_discarded_bytes_total"] == 0
+        assert d["dl4j_data_oversize_batches_total"] == 0
+        return
+    assert d["dl4j_data_packed_bytes_total"] > 0
+    if oversize:
+        assert d["dl4j_data_oversize_batches_total"] == N_BATCHES
+        assert d["dl4j_data_pack_discarded_bytes_total"] \
+            == d["dl4j_data_packed_bytes_total"]
+    else:
+        assert d["dl4j_data_oversize_batches_total"] == 0
+        assert d["dl4j_data_pack_discarded_bytes_total"] == 0
+
+
+def _has_native():
+    from deeplearning4j_tpu.utils import native
+    return native.load() is not None
+
+
+def test_queue_path_packs_and_discards_nothing():
+    x, y = _data()
+    before = _counter_values()
+    it = AsyncDataSetIterator(ArrayDataSetIterator(x, y, BATCH),
+                              use_native=False)
+    try:
+        assert len(list(it)) == N_BATCHES
+    finally:
+        it.close()
+    d = {n: _counter_values()[n] - before[n] for n in COUNTERS}
+    assert d["dl4j_data_batches_total"] == N_BATCHES
+    assert d["dl4j_data_packed_bytes_total"] == 0
+    assert d["dl4j_data_pack_discarded_bytes_total"] == 0
+
+
+@pytest.mark.parametrize("use_native", [True, False], ids=["ring", "queue"])
+def test_consumer_wait_is_counted_once_a_batch(use_native):
+    """A consumer faster than its producer finds nothing at its first
+    look: one count each time, however long it then polls."""
+    x, y = _data()
+
+    class Slow(ArrayDataSetIterator):
+        def next(self, num=None):
+            time.sleep(0.05)
+            return super().next(num)
+
+    before = _counter_values()
+    it = AsyncDataSetIterator(Slow(x, y, BATCH), slot_size=1 << 16,
+                              use_native=use_native)
+    try:
+        assert len(list(it)) == N_BATCHES
+    finally:
+        it.close()
+    d = {n: _counter_values()[n] - before[n] for n in COUNTERS}
+    # waiting for the end of the source is not waiting for a batch
+    assert 2 <= d["dl4j_data_consumer_waits_total"] <= N_BATCHES
+
+
+def test_hand_assembled_span_names_no_thread():
+    """Only ``Tracer.span`` knows which thread ran a span: one built from
+    ``start_ts`` and ``time_s`` (scaleout hub, reqtrace, compiles) does
+    not take the assembling thread's name."""
+    sp = obs.Span("round", "t" * 16, "s" * 16, start_ts=time.time(),
+                  time_s=0.5)
+    assert sp.thread is None and sp.record()["thread"] is None
+    assert sp.time_s == 0.5
+    named = obs.Span("round", "t" * 16, "s" * 16, thread="hub")
+    assert named.thread == "hub"
+
+
+def test_span_cost_is_within_budget():
+    """10,000 empty open/close pairs: the budget is 5 us each; the limit
+    is ten times that, so a loaded test machine does not fail it."""
+    tracer = obs.Tracer(max_spans=1000)
+    n = 10_000
+    t0 = time.perf_counter()
+    for _ in range(n):
+        with tracer.span("empty"):
+            pass
+    each = (time.perf_counter() - t0) / n
+    assert each < 50e-6, f"{each * 1e6:.1f} us a span"
+    assert tracer.dropped == n - 1000 and len(tracer.spans()) == 1000
+
+
+def test_record_and_jsonl_keep_their_keys(tmp_path):
+    tracer = obs.Tracer()
+    wall0 = time.time()
+    with tracer.span("outer", attrs={"k": 1}) as outer:
+        with tracer.span("inner", sync=None) as inner:
+            pass
+    had = {"kind", "name", "trace_id", "span_id", "parent_id", "start_ts",
+           "time_s", "synced", "attrs"}
+    rec = outer.record()
+    assert had <= set(rec) and rec["kind"] == "span"
+    assert set(rec) - had == {"t0_ns", "t1_ns", "thread"}
+    # both derived from the one clock: epoch seconds and a duration
+    assert wall0 - 1 <= rec["start_ts"] <= time.time() + 1
+    assert rec["time_s"] == (outer.t1_ns - outer.t0_ns) / 1e9 > 0
+    assert rec["thread"] == threading.current_thread().name
+    assert outer.t0_ns <= inner.t0_ns <= inner.t1_ns <= outer.t1_ns
+    # ids: 16 hex characters, distinct, one prefix a process
+    ids = {outer.span_id, outer.trace_id, inner.span_id}
+    assert len(ids) == 3 and all(len(i) == 16 for i in ids)
+    assert all(int(i, 16) >= 0 for i in ids)
+    assert len({i[:8] for i in ids}) == 1
+
+    path = tmp_path / "spans.jsonl"
+    assert tracer.export_jsonl(path) == 2
+    loaded = obs.load_spans(path)
+    assert [r["name"] for r in loaded] == ["inner", "outer"]
+    assert all(had <= set(r) for r in loaded)
+    assert loaded[1] == rec
+
+    # a span assembled by hand from epoch seconds lands on the same clock
+    by_hand = obs.Span(name="x", trace_id="t", span_id="s",
+                       start_ts=rec["start_ts"], time_s=0.25)
+    assert abs(by_hand.t0_ns - outer.t0_ns) < 1_000     # float rounding
+    assert by_hand.time_s == 0.25 and by_hand.attrs == {}

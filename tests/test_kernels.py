@@ -43,6 +43,23 @@ def test_flash_matches_reference_grads(causal):
         assert float(jnp.max(jnp.abs(a - b))) < 1e-4
 
 
+def test_flash_kernels_carry_stable_names():
+    """``name=`` on the three pallas_calls, inside a named_scope of the same
+    name: the lowered program (with debug info) and the jaxpr hold each, so
+    a trace reduction can tell forward, dq and dkv apart."""
+    q = jnp.zeros((1, 2, 128, 64), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True).astype(jnp.float32).sum()
+
+    grad = jax.grad(loss, argnums=(0, 1, 2))
+    lowered = jax.jit(grad).lower(q, q, q).as_text(debug_info=True)
+    jaxpr = str(jax.make_jaxpr(grad)(q, q, q))
+    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert name in lowered, name
+        assert name in jaxpr, name
+
+
 def test_flash_odd_seq_falls_back_to_smaller_blocks():
     # t=48 not divisible by 32 → block sizes shrink to 16
     q, k, v = _qkv(t=48)
