@@ -3,24 +3,31 @@
 Reference parity: ``org.deeplearning4j.datasets.iterator.AsyncDataSetIterator``
 (worker thread + bounded queue so host ETL overlaps device compute).
 Backing store is the native SPSC ring (`native/dl4j_tpu_native.cpp`) when the
-lib is available — batches are serialized into fixed byte slots, so the
-producer thread never holds the GIL during the copy — with a pure-Python
-queue fallback. A batch larger than a ring slot (ImageNet b128 f32 is
-77 MB) rides the queue as it is, a marker holding its place in the ring.
-Either way the consumer API is a normal DataSetIterator.
+lib is available — a batch that fits a slot is serialized (npz) into it, and
+the copies into and out of the slot run with the GIL released — with a
+pure-Python queue fallback that hands the batch over by reference. A batch
+whose arrays alone exceed a ring slot (ImageNet b128 f32 is 77 MB) is not
+packed at all: it rides the queue as it is, a marker holding its place in
+the ring. The size is looked at BEFORE packing; only a batch whose arrays
+fit and whose npz (a few hundred bytes more) does not is packed and then
+sent unpacked. Either way the consumer API is a normal DataSetIterator, and
+a look at an empty ring costs one native call and no allocation.
 
 What each side does is recorded where it happens (``obs.span``, the
 process's registry). The producer thread writes one ``data.produce`` span
 a batch (attrs ``batch``: the k-th batch this wrapper hands over, the
 consumer's count) with the children ``data.source_next`` (the inner
 iterator's ``next``), ``data.pack`` (``np.savez`` + ``getvalue``; attrs
-``bytes``, ``oversize``) and ``data.put`` (blocked until a slot is free);
+``bytes``, ``oversize``; written ONLY where a batch is packed, so neither
+on the queue fallback nor for a batch sent unpacked by its size) and
+``data.put`` (blocked until a slot is free);
 the pass that finds the source exhausted carries ``end`` and no ``batch``.
 Its parent is the span that was current when the generation started (a
 thread inherits no ``contextvars``, so it is handed over). The consumer
 writes ``data.unpack`` round ``_unpack``; how long it waited is the
 caller's to time (``fit.next``). Counters: ``dl4j_data_batches_total``,
-``dl4j_data_oversize_batches_total``, ``dl4j_data_packed_bytes_total``,
+``dl4j_data_oversize_batches_total`` (sent through the queue unpacked,
+packed first or not), ``dl4j_data_packed_bytes_total``,
 ``dl4j_data_pack_discarded_bytes_total`` (packed, then sent through the
 queue unpacked) and ``dl4j_data_consumer_waits_total`` (batches that
 ``__next__`` did not find ready at its first look: the loop is
@@ -48,8 +55,8 @@ _SENTINEL = b"__END__"
 _OVERSIZE = b"__VIA_QUEUE__"   # ring marker: the batch itself is in the queue
 
 
-def _pack(ds) -> bytes:
-    buf = io.BytesIO()
+def _arrays(ds) -> dict:
+    """The arrays a batch holds, under the names they have in the npz."""
     if isinstance(ds, MultiDataSet):
         parts = {}
         for i, f in enumerate(ds.features):
@@ -68,7 +75,12 @@ def _pack(ds) -> bytes:
             parts["features_mask"] = ds.features_mask
         if ds.labels_mask is not None:
             parts["labels_mask"] = ds.labels_mask
-    np.savez(buf, **parts)
+    return parts
+
+
+def _pack(ds) -> bytes:
+    buf = io.BytesIO()
+    np.savez(buf, **_arrays(ds))
     return buf.getvalue()
 
 
@@ -208,15 +220,21 @@ class AsyncDataSetIterator:
         span = get_tracer().span
         payload, oversize = ds, False
         if ring is not None:
-            with span("data.pack", attrs={"batch": k}) as pack:
-                payload = _pack(ds)
-                oversize = len(payload) > ring.slot_size
-                pack.set_attr("bytes", len(payload))
-                pack.set_attr("oversize", oversize)
-            self._n_packed_bytes.inc(len(payload))
+            # the npz only adds to its arrays (it does not compress): a
+            # batch whose arrays alone exceed a slot goes unpacked
+            oversize = sum(a.nbytes for a in _arrays(ds).values()) \
+                > ring.slot_size
+            if not oversize:
+                with span("data.pack", attrs={"batch": k}) as pack:
+                    payload = _pack(ds)
+                    oversize = len(payload) > ring.slot_size
+                    pack.set_attr("bytes", len(payload))
+                    pack.set_attr("oversize", oversize)
+                self._n_packed_bytes.inc(len(payload))
+                if oversize:    # the arrays fit, their npz did not
+                    self._n_discarded_bytes.inc(len(payload))
             if oversize:
                 self._n_oversize.inc()
-                self._n_discarded_bytes.inc(len(payload))
         with span("data.put", attrs={"batch": k}):
             if oversize:
                 # queue first, marker second: a consumer that pops

@@ -83,7 +83,8 @@ def has_native() -> bool:
 
 
 class NativeRing:
-    """SPSC ring of byte slots (AsyncDataSetIterator backing store)."""
+    """SPSC ring of byte slots (AsyncDataSetIterator backing store). ONE
+    consumer: every pop receives into the one buffer the ring keeps."""
 
     def __init__(self, slot_size: int, n_slots: int):
         lib = load()
@@ -94,6 +95,10 @@ class NativeRing:
         if not self._ptr:
             raise MemoryError("ring_create failed")
         self.slot_size = slot_size
+        # over memory that is not zeroed: only the pages a payload
+        # reaches are ever touched
+        self._buf = (ctypes.c_char * slot_size).from_buffer(
+            np.empty(slot_size, np.uint8))
 
     def push(self, payload: bytes) -> bool:
         rc = self._lib.ring_push(self._ptr, payload, len(payload))
@@ -102,11 +107,12 @@ class NativeRing:
         return rc == 1
 
     def pop(self) -> Optional[bytes]:
-        buf = ctypes.create_string_buffer(self.slot_size)
-        n = self._lib.ring_pop(self._ptr, buf, self.slot_size)
+        """The oldest payload, or None from an empty ring; neither
+        allocates a slot, so a consumer may poll with this."""
+        n = self._lib.ring_pop(self._ptr, self._buf, self.slot_size)
         if n <= 0:
             return None
-        return buf.raw[:n]
+        return ctypes.string_at(self._buf, n)
 
     def __len__(self):
         return int(self._lib.ring_size(self._ptr))

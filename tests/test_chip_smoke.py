@@ -80,37 +80,70 @@ def test_phase_fit_resnet_shallow():
                                       require_fused=True)
 
 
-def test_async_iterator_batch_larger_than_ring_slot_keeps_order():
+def _plain_batch(i, n):
+    from deeplearning4j_tpu.data.dataset import DataSet
+    return DataSet(np.full((n, 8), i, np.float32),
+                   np.full((n, 2), i, np.float32))
+
+
+def _multi_batch_with_masks(i, n):
+    """Two inputs, one output, a mask on the second input and on the
+    labels: the masks are what takes the large ones over the slot's
+    size (features and labels alone are 12 KB of its 16 KB)."""
+    from deeplearning4j_tpu.data.dataset import MultiDataSet
+    return MultiDataSet(
+        [np.full((n, 2), i, np.float32), np.full((n, 3), i, np.float32)],
+        [np.full((n, 1), i, np.float32)],
+        [None, np.full((n, 3), i % 2, np.float32)],
+        [np.full((n, 1), 1, np.float32)])
+
+
+@pytest.mark.parametrize("batch, large", [(_plain_batch, 4096),
+                                          (_multi_batch_with_masks, 512)],
+                         ids=["DataSet", "MultiDataSet_with_masks"])
+def test_async_iterator_batch_larger_than_ring_slot_keeps_order(batch, large):
     """What stopped `fit(DataSetIterator)` at the headline's own size: a
     batch that does not fit a ring slot (ImageNet b128 f32 is 77 MB
     against the 64 MB default) killed the prefetch producer. It now rides
-    the queue with a marker in the ring: every batch arrives, in order,
-    mixed with ring-sized ones."""
-    from deeplearning4j_tpu.data.async_iter import AsyncDataSetIterator
-    from deeplearning4j_tpu.data.dataset import DataSet
+    the queue with a marker in the ring, unpacked (ISSUE 27: the very
+    arrays the source made, never serialized): every batch arrives, in
+    order, mixed with ring-sized ones, through `reset()` too."""
+    from deeplearning4j_tpu.data.async_iter import (
+        AsyncDataSetIterator, _arrays)
     from deeplearning4j_tpu.utils import native
     if not native.has_native():
         pytest.skip("no native ring to overflow")
-
-    def ds(i, n):
-        return DataSet(np.full((n, 8), i, np.float32),
-                       np.full((n, 2), i, np.float32))
+    sizes = (4, large, 4, large, large, 4)
+    made = {}       # by place in the epoch; each epoch makes its own
 
     class Mixed:
         batch_size = 4
 
         def __iter__(self):
-            for i, n in enumerate((4, 4096, 4, 4096, 4096, 4)):
-                yield ds(i, n)        # 4096 rows pack to ~160 KB
+            for i, n in enumerate(sizes):
+                made[i] = batch(i, n)
+                yield made[i]
 
-    it = AsyncDataSetIterator(Mixed(), queue_size=2, slot_size=16 << 10)
+    slot = 16 << 10
+    it = AsyncDataSetIterator(Mixed(), queue_size=2, slot_size=slot)
     try:
         assert it._ring is not None
-        got = [(int(b.features[0, 0]), b.num_examples()) for b in it]
-        assert got == [(0, 4), (1, 4096), (2, 4), (3, 4096), (4, 4096),
-                       (5, 4)]
-        it.reset()
-        assert len(list(it)) == 6
+        for epoch in range(2):
+            got = list(it)      # the producer has ended when this has
+            assert [b.num_examples() for b in got] == list(sizes)
+            for i, b in enumerate(got):
+                m = made[i]
+                sent, have = _arrays(m), _arrays(b)
+                assert list(have) == list(sent)     # a missing mask stays so
+                nbytes = sum(a.nbytes for a in sent.values())
+                assert (nbytes > slot) == (sizes[i] == large)
+                # over the slot: by reference; under it: an equal copy
+                assert (b is m) == (sizes[i] == large)
+                for name in sent:
+                    np.testing.assert_array_equal(have[name], sent[name])
+                    assert have[name].dtype == sent[name].dtype
+                assert float(np.asarray(have[next(iter(have))]).flat[0]) == i
+            it.reset()
     finally:
         it.close()
 

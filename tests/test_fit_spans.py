@@ -183,37 +183,62 @@ def _counter_values():
     return {n: (reg.get(n).value() if reg.get(n) else 0.0) for n in COUNTERS}
 
 
-@pytest.mark.parametrize("slot_size, oversize", [(64, True), (1 << 20, False)],
-                         ids=["slot_smaller_than_batch", "batch_fits_slot"])
-def test_data_counters_at_the_ring(slot_size, oversize):
+#: one batch of ``_data()``: 4 x 6 features + 4 x 3 labels, float32; its npz
+#: is some 500 bytes longer
+RAW_BYTES = BATCH * (6 + 3) * 4
+
+
+@pytest.mark.parametrize(
+    "slot_size, oversize, packed",
+    [(64, True, False), (RAW_BYTES + 8, True, True), (1 << 20, False, True)],
+    ids=["slot_smaller_than_batch", "slot_between_arrays_and_npz",
+         "batch_fits_slot"])
+def test_data_counters_at_the_ring(slot_size, oversize, packed):
+    """A batch whose arrays exceed the slot is sent unpacked and nothing
+    is packed for it; one whose arrays fit and whose npz does not is the
+    one case that packs and discards."""
     x, y = _data()
     source = ArrayDataSetIterator(x, y, BATCH)
     before = _counter_values()      # the producer starts with the wrapper
-    it = AsyncDataSetIterator(source, queue_size=2, slot_size=slot_size)
+    tracer = obs.get_tracer()
+    spans_before = {id(s) for s in tracer.spans()}
+    with tracer.span("test.ring") as root:     # the producer's spans' parent
+        it = AsyncDataSetIterator(source, queue_size=2, slot_size=slot_size)
     try:
         got = list(it)
     finally:
         it.close()
     after = _counter_values()
     d = {n: after[n] - before[n] for n in COUNTERS}
+    packs = [s for s in tracer.spans() if id(s) not in spans_before
+             and s.name == "data.pack" and s.trace_id == root.trace_id]
     assert len(got) == N_BATCHES
     np.testing.assert_array_equal(
         np.concatenate([np.asarray(b.features) for b in got]), x)
+    np.testing.assert_array_equal(
+        np.concatenate([np.asarray(b.labels) for b in got]), y)
     assert d["dl4j_data_batches_total"] == N_BATCHES
     if not _has_native():
         # queue path: nothing is packed, so nothing can be discarded
         assert d["dl4j_data_packed_bytes_total"] == 0
         assert d["dl4j_data_pack_discarded_bytes_total"] == 0
         assert d["dl4j_data_oversize_batches_total"] == 0
+        assert packs == []
         return
-    assert d["dl4j_data_packed_bytes_total"] > 0
-    if oversize:
-        assert d["dl4j_data_oversize_batches_total"] == N_BATCHES
-        assert d["dl4j_data_pack_discarded_bytes_total"] \
-            == d["dl4j_data_packed_bytes_total"]
-    else:
-        assert d["dl4j_data_oversize_batches_total"] == 0
+    assert d["dl4j_data_oversize_batches_total"] \
+        == (N_BATCHES if oversize else 0)
+    if not packed:
+        assert packs == []
+        assert d["dl4j_data_packed_bytes_total"] == 0
         assert d["dl4j_data_pack_discarded_bytes_total"] == 0
+        return
+    assert sorted(s.attrs["batch"] for s in packs) == list(range(N_BATCHES))
+    assert all(s.attrs["oversize"] == oversize for s in packs)
+    assert all(RAW_BYTES < s.attrs["bytes"] for s in packs)
+    assert d["dl4j_data_packed_bytes_total"] \
+        == sum(s.attrs["bytes"] for s in packs) > 0
+    assert d["dl4j_data_pack_discarded_bytes_total"] \
+        == (d["dl4j_data_packed_bytes_total"] if oversize else 0)
 
 
 def _has_native():
