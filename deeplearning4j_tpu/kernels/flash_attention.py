@@ -14,8 +14,12 @@ that persists across it (TPU grids iterate sequentially). Peak VMEM is
 O(block_q·d + block_k·d), independent of sequence length, so the kernel
 works exactly in the long-context regime flash attention exists for.
 
-Shapes: q, k, v are (B, H, T, D); output (B, H, T, D). ``causal`` applies a
-lower-triangular mask (fully-masked blocks are skipped via pl.when).
+Shapes: q is (B, H, T, D), k and v are (B, Hkv, T, D) with H a multiple of
+Hkv (query head i reads K/V head i // (H // Hkv): grouped-query attention;
+Hkv == H is the plain case); output (B, H, T, D). ``causal`` applies a
+lower-triangular mask, ``window`` (with ``causal``) keeps only the keys with
+i - j < window: key blocks that lie wholly outside the band, on either side,
+are skipped via pl.when and their fetches clamped away, in all three kernels.
 Falls back to interpreter mode off-TPU so the same code path is
 unit-testable on the CPU mesh.
 """
@@ -46,10 +50,32 @@ def _block_sizes(t: int, d: int, block_q: int, block_k: int):
     return max(bq, 1), max(bk, 1)
 
 
+def _live(qi, kj, bq, bk, causal, window):
+    """Does query block ``qi`` see any key of key block ``kj``? (The dkv
+    kernel asks the same of its grid, which streams the query blocks.)"""
+    if not causal:
+        return kj >= 0
+    live = kj * bk <= qi * bq + bq - 1          # not wholly above the diagonal
+    if window is not None:                      # not wholly left of the band
+        live = live & (kj * bk + bk - 1 > qi * bq - window)
+    return live
+
+
+def _masked(s, qi, kj, bq, bk, causal, window):
+    if not causal:
+        return s
+    q_idx = qi * bq + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    k_idx = kj * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    dead = k_idx > q_idx
+    if window is not None:
+        dead = dead | (q_idx - k_idx >= window)
+    return jnp.where(dead, NEG_INF, s)
+
+
 # ---------------------------------------------------------------- forward --
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
-                *, scale, causal):
+                *, scale, causal, window):
     qi = pl.program_id(1)
     kj = pl.program_id(2)
     nk = pl.num_programs(2)
@@ -62,8 +88,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    # causal: skip key blocks that lie entirely above the diagonal
-    live = (kj * bk <= qi * bq + bq - 1) if causal else (kj >= 0)
+    # causal: skip key blocks that lie entirely above the diagonal (or, with
+    # a window, entirely left of the band)
+    live = _live(qi, kj, bq, bk, causal, window)
 
     @pl.when(live)
     def _compute():
@@ -76,10 +103,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32
                                 ) * scale                   # (bq, bk) f32
-        if causal:
-            q_idx = qi * bq + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            k_idx = kj * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(k_idx > q_idx, NEG_INF, s)
+        # a row whose keys in this block are all masked adds exp(0) terms
+        # while its running max is still NEG_INF; the first block with a
+        # key it sees (the diagonal at the latest) wipes them: corr = 0
+        s = _masked(s, qi, kj, bq, bk, causal, window)
         m = m_ref[:, 0]
         l = l_ref[:, 0]
         m_new = jnp.maximum(m, jnp.max(s, axis=-1))
@@ -102,30 +129,46 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
             (m_ref[:, 0] + jnp.log(l_safe))[:, None], lse_ref.shape[1:])
 
 
-def _causal_kv_map(bq, bk, causal):
+def _causal_kv_map(bq, bk, causal, window=None, group=1):
     """KV-block index map. For causal grids, dead steps (key block entirely
-    above the diagonal) CLAMP to the last live key block: Pallas skips the
-    HBM->VMEM fetch when successive steps reference the same block, so the
-    ~half of the rectangular grid that pl.when skips stops costing
-    bandwidth too. (Compute for dead steps is already skipped; without the
-    clamp their DMAs still ran — measured ~2x wasted attention traffic at
-    long T.)"""
+    above the diagonal, or entirely left of the window's band) CLAMP to the
+    nearest live key block: Pallas skips the HBM->VMEM fetch when successive
+    steps reference the same block, so the part of the rectangular grid that
+    pl.when skips stops costing bandwidth too. (Compute for dead steps is
+    already skipped; without the clamp their DMAs still ran — measured ~2x
+    wasted attention traffic at long T.) Row ``bh`` of the flattened
+    (B*H) queries reads row ``bh // group`` of the flattened (B*Hkv) keys."""
+    row = (lambda bh: bh) if group == 1 else (lambda bh: bh // group)
     if not causal:
-        return lambda bh, i, j: (bh, j, 0)
-    return lambda bh, i, j: (bh, jnp.minimum(j, (i * bq + bq - 1) // bk), 0)
+        return lambda bh, i, j: (row(bh), j, 0)
+    if window is None:
+        return lambda bh, i, j: (row(bh),
+                                 jnp.minimum(j, (i * bq + bq - 1) // bk), 0)
+    return lambda bh, i, j: (row(bh), jnp.clip(
+        j, jnp.maximum(i * bq - window + 1, 0) // bk,
+        (i * bq + bq - 1) // bk), 0)
 
 
-def _fwd(q, k, v, scale, causal, block_q, block_k, interpret):
+def _suffix(window):
+    """The window kernels carry names of their own, so that a trace tells
+    them from the full causal ones."""
+    return "" if window is None else "_win"
+
+
+def _fwd(q, k, v, scale, causal, block_q, block_k, interpret, window=None):
     b, h, t, d = q.shape
+    hkv = k.shape[1]
     bq, bk = _block_sizes(t, d, block_q, block_k)
     qf = q.reshape(b * h, t, d)
-    kf = k.reshape(b * h, t, d)
-    vf = v.reshape(b * h, t, d)
-    kv_map = _causal_kv_map(bq, bk, causal)
+    kf = k.reshape(b * hkv, t, d)
+    vf = v.reshape(b * hkv, t, d)
+    kv_map = _causal_kv_map(bq, bk, causal, window, h // hkv)
     grid = (b * h, t // bq, t // bk)      # kv block = fastest dim (streamed)
-    with jax.named_scope("flash_fwd"):
+    name = "flash_fwd" + _suffix(window)
+    with jax.named_scope(name):
         out, lse = pl.pallas_call(
-            functools.partial(_fwd_kernel, scale=scale, causal=causal),
+            functools.partial(_fwd_kernel, scale=scale, causal=causal,
+                              window=window),
             grid=grid,
             in_specs=[pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
                       pl.BlockSpec((1, bk, d), kv_map),
@@ -138,7 +181,7 @@ def _fwd(q, k, v, scale, causal, block_q, block_k, interpret):
                             pltpu.VMEM((bq, 8), jnp.float32),
                             pltpu.VMEM((bq, 8), jnp.float32)],
             interpret=interpret,
-            name="flash_fwd",
+            name=name,
         )(qf, kf, vf)
     return out.reshape(b, h, t, d), lse[:, :, 0].reshape(b, h, t)
 
@@ -146,7 +189,7 @@ def _fwd(q, k, v, scale, causal, block_q, block_k, interpret):
 # --------------------------------------------------------------- backward --
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   dq_acc_ref, *, scale, causal):
+                   dq_acc_ref, *, scale, causal, window):
     qi = pl.program_id(1)
     kj = pl.program_id(2)
     nk = pl.num_programs(2)
@@ -157,7 +200,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     def _init():
         dq_acc_ref[...] = jnp.zeros_like(dq_acc_ref)
 
-    live = (kj * bk <= qi * bq + bq - 1) if causal else (kj >= 0)
+    live = _live(qi, kj, bq, bk, causal, window)
 
     @pl.when(live)
     def _compute():
@@ -169,10 +212,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         v = v_ref[0]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-        if causal:
-            q_idx = qi * bq + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            k_idx = kj * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(k_idx > q_idx, NEG_INF, s)
+        s = _masked(s, qi, kj, bq, bk, causal, window)
         p = jnp.exp(s - lse[:, None])
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
@@ -186,20 +226,25 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, dk_acc_ref, dv_acc_ref, *, scale, causal):
+                    dk_ref, dv_ref, dk_acc_ref, dv_acc_ref, *, scale, causal,
+                    window, group):
     kj = pl.program_id(1)
-    qi = pl.program_id(2)
-    nq = pl.num_programs(2)
+    # the streamed dimension runs over the query blocks of every query head
+    # of this K/V head's group in turn: dK and dV sum over the group
+    step = pl.program_id(2)
+    n_steps = pl.num_programs(2)
+    qi = step if group == 1 else step % (n_steps // group)
     bk = k_ref.shape[1]
     bq = q_ref.shape[1]
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _init():
         dk_acc_ref[...] = jnp.zeros_like(dk_acc_ref)
         dv_acc_ref[...] = jnp.zeros_like(dv_acc_ref)
 
-    # causal: only query blocks at or below this key block contribute
-    live = (qi * bq + bq - 1 >= kj * bk) if causal else (qi >= 0)
+    # causal: only query blocks at or below this key block contribute (and,
+    # with a window, none wholly beyond the band)
+    live = _live(qi, kj, bq, bk, causal, window)
 
     @pl.when(live)
     def _compute():
@@ -212,10 +257,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32
                                 ) * scale                    # (bq, bk)
-        if causal:
-            q_idx = qi * bq + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            k_idx = kj * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(k_idx > q_idx, NEG_INF, s)
+        s = _masked(s, qi, kj, bq, bk, causal, window)
         p = jnp.exp(s - lse[:, None])
         dv_acc_ref[...] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
@@ -226,7 +268,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_acc_ref[...] += jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
-    @pl.when(qi == nq - 1)
+    @pl.when(step == n_steps - 1)
     def _finalize():
         dk_ref[0] = dk_acc_ref[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc_ref[...].astype(dv_ref.dtype)
@@ -234,41 +276,54 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 # ------------------------------------------------------------- public api --
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def _flash_attention_pallas(q, k, v, scale: Optional[float] = None,
                             causal: bool = False, block_q: int = 128,
                             block_k: int = 128,
-                            interpret: Optional[bool] = None):
-    out, _ = _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret)
+                            interpret: Optional[bool] = None,
+                            window: Optional[int] = None):
+    out, _ = _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret,
+                        window)
     return out
 
 
 def flash_attention(q, k, v, scale: Optional[float] = None,
                     causal: bool = False, block_q: int = 128,
-                    block_k: int = 128, interpret: Optional[bool] = None):
-    """Fused scaled-dot-product attention. q/k/v: (B, H, T, D) → (B, H, T, D)."""
+                    block_k: int = 128, interpret: Optional[bool] = None,
+                    window: Optional[int] = None):
+    """Fused scaled-dot-product attention. q: (B, H, T, D), k/v:
+    (B, Hkv, T, D) with H % Hkv == 0 → (B, H, T, D). ``window`` (needs
+    ``causal``): query i sees keys j with 0 <= i - j < window."""
+    if window is not None and not causal:
+        raise ValueError("a window is a band under the diagonal: it needs "
+                         "causal=True")
+    if q.shape[1] % k.shape[1] or k.shape != v.shape:
+        raise ValueError(f"{q.shape[1]} query heads cannot share "
+                         f"{k.shape[1]} K/V heads")
     return _flash_attention_pallas(q, k, v, scale, causal, block_q, block_k,
-                                   interpret)
+                                   interpret, window)
 
 
-def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret):
+def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret,
+               window=None):
     if interpret is None:
         interpret = _interpret_default()
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    out, lse = _fwd(q, k, v, scale, causal, block_q, block_k, interpret)
+    out, lse = _fwd(q, k, v, scale, causal, block_q, block_k, interpret,
+                    window)
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(scale, causal, block_q, block_k, interpret, res, g):
+def _flash_bwd(scale, causal, block_q, block_k, interpret, window, res, g):
     q, k, v, out, lse = res
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
     return _flash_bwd_impl(scale, causal, block_q, block_k, interpret,
-                           q, k, v, g, lse, delta)
+                           q, k, v, g, lse, delta, window)
 
 
 def _flash_bwd_impl(scale, causal, block_q, block_k, interpret,
-                    q, k, v, g, lse, delta):
+                    q, k, v, g, lse, delta, window=None):
     """Shared backward. ``delta`` is rowsum(dO·O) for the plain kernel; the
     lse-returning variant passes rowsum(dO·O) − dLSE instead — the ONLY
     difference an lse cotangent makes (ds = p·(dp − delta + dlse), so it
@@ -278,24 +333,42 @@ def _flash_bwd_impl(scale, causal, block_q, block_k, interpret,
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     b, h, t, d = q.shape
+    hkv = k.shape[1]
+    group = h // hkv
     bq, bk = _block_sizes(t, d, block_q, block_k)
-    flat = lambda x: x.reshape(b * h, t, -1)
+    nq = t // bq
+    flat = lambda x: x.reshape(-1, t, x.shape[-1])
     qf, kf, vf, dof = flat(q), flat(k), flat(v), flat(g)
     lsef = jnp.broadcast_to(lse.reshape(b * h, t)[:, :, None], (b * h, t, 8))
     deltaf = jnp.broadcast_to(delta.reshape(b * h, t)[:, :, None], (b * h, t, 8))
 
-    kv_map = _causal_kv_map(bq, bk, causal)
-    if causal:
-        # dkv grid streams q blocks; dead steps (q block entirely above the
-        # diagonal) clamp to the FIRST live q block — same no-refetch trick
-        # as _causal_kv_map, mirrored
-        q_map = lambda bh, j, i: (bh, jnp.maximum(i, (j * bk) // bq), 0)
+    kv_map = _causal_kv_map(bq, bk, causal, window, group)
+    # dkv grid streams q blocks (of each query head of the group in turn);
+    # dead steps (q block entirely above the diagonal, or entirely beyond
+    # the band) clamp to the nearest live q block — same no-refetch trick as
+    # _causal_kv_map, mirrored
+    if group == 1:
+        q_of = lambda bh, i: (bh, i)
     else:
-        q_map = lambda bh, j, i: (bh, i, 0)
+        q_of = lambda bh, i: (bh * group + i // nq, i % nq)
+    if not causal:
+        q_blk = lambda j, i: i
+    elif window is None:
+        q_blk = lambda j, i: jnp.maximum(i, (j * bk) // bq)
+    else:
+        q_blk = lambda j, i: jnp.clip(
+            i, (j * bk) // bq,
+            jnp.minimum((j * bk + bk + window - 2) // bq, nq - 1))
 
-    with jax.named_scope("flash_bwd_dq"):
+    def q_map(bh, j, i):
+        row, blk = q_of(bh, i)
+        return (row, q_blk(j, blk), 0)
+
+    sfx = _suffix(window)
+    with jax.named_scope("flash_bwd_dq" + sfx):
         dq = pl.pallas_call(
-            functools.partial(_bwd_dq_kernel, scale=scale, causal=causal),
+            functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
+                              window=window),
             grid=(b * h, t // bq, t // bk),   # kv block streamed (fastest dim)
             in_specs=[pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
                       pl.BlockSpec((1, bk, d), kv_map),
@@ -307,13 +380,15 @@ def _flash_bwd_impl(scale, causal, block_q, block_k, interpret,
             out_shape=jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
             scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
             interpret=interpret,
-            name="flash_bwd_dq",
+            name="flash_bwd_dq" + sfx,
         )(qf, kf, vf, dof, lsef, deltaf)
 
-    with jax.named_scope("flash_bwd_dkv"):
+    with jax.named_scope("flash_bwd_dkv" + sfx):
         dk, dv = pl.pallas_call(
-            functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal),
-            grid=(b * h, t // bk, t // bq),   # q block streamed (fastest dim)
+            functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
+                              window=window, group=group),
+            # q block streamed (fastest dim), group * nq steps a key block
+            grid=(b * hkv, t // bk, group * nq),
             in_specs=[pl.BlockSpec((1, bq, d), q_map),
                       pl.BlockSpec((1, bk, d), lambda bh, j, i: (bh, j, 0)),
                       pl.BlockSpec((1, bk, d), lambda bh, j, i: (bh, j, 0)),
@@ -324,16 +399,15 @@ def _flash_bwd_impl(scale, causal, block_q, block_k, interpret,
                       pl.BlockSpec((1, bq, 8), q_map)],
             out_specs=[pl.BlockSpec((1, bk, d), lambda bh, j, i: (bh, j, 0)),
                        pl.BlockSpec((1, bk, d), lambda bh, j, i: (bh, j, 0))],
-            out_shape=[jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
-                       jax.ShapeDtypeStruct((b * h, t, d), q.dtype)],
+            out_shape=[jax.ShapeDtypeStruct((b * hkv, t, d), q.dtype),
+                       jax.ShapeDtypeStruct((b * hkv, t, d), q.dtype)],
             scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                             pltpu.VMEM((bk, d), jnp.float32)],
             interpret=interpret,
-            name="flash_bwd_dkv",
+            name="flash_bwd_dkv" + sfx,
         )(qf, kf, vf, dof, lsef, deltaf)
 
-    shape = (b, h, t, d)
-    return dq.reshape(shape), dk.reshape(shape), dv.reshape(shape)
+    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
 
 
 _flash_attention_pallas.defvjp(_flash_fwd, _flash_bwd)
@@ -384,11 +458,13 @@ def _flash_bwd_lse(scale, causal, block_q, block_k, interpret, res, g):
 _flash_attention_lse_pallas.defvjp(_flash_fwd_lse, _flash_bwd_lse)
 
 
-def _tuned_blocks(b, h, t, d, dtype, causal, interpret) -> tuple:
+def _tuned_blocks(b, h, t, d, dtype, causal, interpret, window=None,
+                  group=1) -> tuple:
     """Autotuned (block_q, block_k) for this attention shape — timed on the
-    real chip once, cached to disk (kernels/autotune.py). Off-TPU (or with
-    tuning disabled) falls back to the measured v5e sweet spot
-    (min(512,T), min(1024,T)) rather than re-timing."""
+    real chip once per (shape, window, group), cached to disk
+    (kernels/autotune.py). Off-TPU (or with tuning disabled) falls back to
+    the measured v5e sweet spot (min(512,T), min(1024,T)) rather than
+    re-timing."""
     import os
 
     if interpret or jax.default_backend() != "tpu" \
@@ -402,19 +478,20 @@ def _tuned_blocks(b, h, t, d, dtype, causal, interpret) -> tuple:
             return None
         key = jax.random.PRNGKey(0)
         q = jax.random.normal(key, (b, h, t, d), dtype)
+        kv = q[:, ::group]
 
         # Time the TRAIN path (fwd + both bwd passes): block-size choice is
         # dominated by the backward kernels, and a fwd-only race mispicks
         # (the flash4 tuner's 128×128 regression).
         def loss(q_, k_, v_):
             return jnp.sum(_flash_attention_pallas(
-                q_, k_, v_, None, causal, bq, bk, False
+                q_, k_, v_, None, causal, bq, bk, False, window
             ).astype(jnp.float32))
 
         grad_fn = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
 
         def run():
-            return grad_fn(q, q, q)[0]
+            return grad_fn(q, kv, kv)[0]
         return run
 
     chip = jax.devices()[0].device_kind.replace(" ", "_")
@@ -428,34 +505,45 @@ def _tuned_blocks(b, h, t, d, dtype, causal, interpret) -> tuple:
     # Candidates ≥2048 are not raced: none has been timed on a chip, and
     # 1024×1024 (s block 4 MB f32 + kv 256 KB) already sits well inside
     # VMEM at d=64.
+    key = f"flash5:{chip}:{b}x{h}x{t}x{d}:{jnp.dtype(dtype).name}:{causal}"
+    if window is not None or group != 1:       # a race of their own
+        key += f":w{window}:g{group}"
     return autotune(
-        f"flash5:{chip}:{b}x{h}x{t}x{d}:{jnp.dtype(dtype).name}:{causal}",
+        key,
         [(512, 1024), (1024, 1024), (1024, 512), (512, 512),
          (256, 512), (256, 256), (128, 128)],
         make_run)
 
 
-def flash_attention_ntc(q, k, v, causal=False, interpret=None):
+def flash_attention_ntc(q, k, v, causal=False, interpret=None, window=None):
     """(B, T, H, D)-layout adapter around :func:`flash_attention` — the
-    layout the nn layers and the transformer use. Block sizes are
-    autotuned per shape on the real chip."""
+    layout the nn layers and the transformer use; k and v may hold fewer
+    heads than q (grouped-query attention). Block sizes are autotuned per
+    (shape, window, group) on the real chip."""
     b, t, h, d = q.shape
-    bq, bk = _tuned_blocks(b, h, t, d, q.dtype, causal, interpret)
+    bq, bk = _tuned_blocks(b, h, t, d, q.dtype, causal, interpret, window,
+                           h // k.shape[2])
     out = flash_attention(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
                           v.transpose(0, 2, 1, 3), None, causal, bq, bk,
-                          interpret)
+                          interpret, window)
     return out.transpose(0, 2, 1, 3)
 
 
-def mha_reference(q, k, v, scale=None, causal=False):
-    """Plain-XLA oracle used by tests and as a fallback."""
+def mha_reference(q, k, v, scale=None, causal=False, window=None):
+    """Plain-XLA oracle used by tests and as a fallback; k and v may hold
+    fewer heads than q, ``window`` as in :func:`flash_attention`."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
+    group = q.shape[1] // k.shape[1]
+    if group != 1:
+        k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
     s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
     if causal:
         t = q.shape[2]
         mask = jnp.tril(jnp.ones((t, t), bool))
+        if window is not None:
+            mask = mask & ~jnp.tril(jnp.ones((t, t), bool), -window)
         s = jnp.where(mask, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32)).astype(q.dtype)

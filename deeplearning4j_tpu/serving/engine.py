@@ -137,6 +137,14 @@ class GenerationEngine:
                 "GenerationEngine is dense-only: MoE expert dispatch has "
                 "no single-token decode path yet (train MoE via the GSPMD "
                 "path; see ROADMAP)")
+        if (getattr(cfg, "layer_positions", ()) or getattr(cfg, "layer_windows", ())
+                or getattr(cfg, "kv_heads", cfg.n_heads) != cfg.n_heads
+                or getattr(cfg, "mlp", "gelu") != "gelu"):
+            raise NotImplementedError(
+                "GenerationEngine serves the GPT-2-style block only: the KV "
+                "pool's row holds one K/V head per query head, the decode "
+                "step adds the learned position table and knows no window "
+                "(ROADMAP Reach B2, B5)")
         if cfg.use_ring_attention:
             raise NotImplementedError(
                 "ring attention is a sequence-parallel TRAINING path; the "

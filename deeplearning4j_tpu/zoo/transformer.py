@@ -87,18 +87,94 @@ class TransformerConfig:
     # keeps scores in VMEM and is exact).
     attn_scores_bf16: bool = True
     tie_embeddings: bool = False
+    # ---- what the block IS (PR 28). Each field describes the architecture;
+    # none picks an implementation. The defaults are the GPT-2-style block
+    # the fields above describe: one K/V head per query head, heads of
+    # d_model // n_heads, a learned position table added at the embedding,
+    # full causal attention, an ungated GELU MLP, every expert held.
+    n_kv_heads: int = 0         # 0 → n_heads; else query head i reads K/V
+    #                             head i // (n_heads // n_kv_heads)
+    head_size: int = 0          # 0 → d_model // n_heads
+    # Kinds of layer, one entry per layer of a PERIOD that repeats down the
+    # stack (n_layers % period == 0; both tuples the same length).
+    # layer_positions: "rope" (rotary on q and k, split-half pairs over the
+    # whole head) | "none" (no positions at all); () = the learned table.
+    # layer_windows: keys a query sees (0 <= i - j < w); 0 = full causal.
+    layer_positions: tuple = ()
+    layer_windows: tuple = ()
+    rope_theta: float = 10000.0
+    embed_scale: bool = True    # token embedding times sqrt(d_model)
+    mlp: str = "gelu"           # "gelu": gelu(x W_in) W_out | "reglu":
+    #                             (relu(x Wg) * (x Wu)) W_out, Wg|Wu side by
+    #                             side in one (d, 2·d_ff) matrix
+    # The share of the n_experts published experts this program holds:
+    # (first id, count). The router keeps n_experts outputs and top-k over
+    # all of them; the layer computes the held experts' part of the result
+    # for exactly the tokens routed to them — no capacity, nothing dropped —
+    # and leaves out what the absent experts would add (expert parallelism
+    # without its exchange). () = every expert held, with capacity_factor.
+    experts_held: tuple = ()
+    # what the router reads: the block's normed input ("pre_attention", what
+    # attention reads) or the normed input of the MLP ("post_attention")
+    router_input: str = "post_attention"
 
     @property
     def head_dim(self):
-        return self.d_model // self.n_heads
+        return self.head_size or self.d_model // self.n_heads
+
+    @property
+    def kv_heads(self):
+        return self.n_kv_heads or self.n_heads
+
+    @property
+    def layer_kinds(self):
+        """((positions, window), ...) for the layers of one period."""
+        n = max(len(self.layer_positions), len(self.layer_windows), 1)
+        pos = self.layer_positions or ("learned",) * n
+        win = self.layer_windows or (0,) * n
+        if len(pos) != len(win) or self.n_layers % n \
+                or set(pos) - {"rope", "none", "learned"} \
+                or ("learned" in pos and set(pos) != {"learned"}):
+            raise ValueError(
+                f"layer_positions {self.layer_positions} and layer_windows "
+                f"{self.layer_windows} must describe one period that "
+                f"divides n_layers={self.n_layers}")
+        return tuple(zip(pos, (int(w) for w in win)))
+
+
+def _check(cfg: TransformerConfig):
+    """Combinations of fields that no code computes."""
+    if cfg.n_heads % cfg.kv_heads:
+        raise ValueError(f"{cfg.n_heads} query heads cannot share "
+                         f"{cfg.kv_heads} K/V heads")
+    if cfg.mlp not in ("gelu", "reglu"):
+        raise ValueError(f"Unknown mlp {cfg.mlp!r}; 'gelu' or 'reglu'")
+    if cfg.router_input not in ("pre_attention", "post_attention"):
+        raise ValueError(f"Unknown router_input {cfg.router_input!r}")
+    if cfg.experts_held:
+        first, held = cfg.experts_held
+        if not (0 <= first and held >= 1 and first + held <= cfg.n_experts):
+            raise ValueError(f"experts_held {cfg.experts_held} is not a "
+                             f"share of n_experts={cfg.n_experts}")
+    elif cfg.n_experts and (cfg.mlp != "gelu"
+                            or cfg.router_input != "post_attention"):
+        # ROADMAP Design: the two expert layers are to become one
+        raise NotImplementedError(
+            "the capacity expert layer (experts_held=()) has GELU experts "
+            "and routes on the MLP's input; give experts_held=(0, n_experts)"
+            " for the dropless layer")
+    cfg.layer_kinds
 
 
 # ---------------------------------------------------------------- params
 
 def init_params(key, cfg: TransformerConfig):
     """Stacked-block params. Names are stable for checkpoints/sharding."""
+    _check(cfg)
     k = jax.random.split(key, 12)
     d, f, h, L = cfg.d_model, cfg.d_ff, cfg.n_heads * cfg.head_dim, cfg.n_layers
+    hkv = cfg.kv_heads * cfg.head_dim
+    f_in = 2 * f if cfg.mlp == "reglu" else f    # gate | up side by side
     pd = cfg.param_dtype
 
     def norm(key, shape, fan_in):
@@ -109,19 +185,22 @@ def init_params(key, cfg: TransformerConfig):
         "pos_embed": 0.02 * jax.random.normal(k[1], (cfg.max_seq, d), pd),
         "blocks": {
             "ln1": jnp.ones((L, d), pd),
-            "wqkv": norm(k[2], (L, d, 3 * h), d),
+            "wqkv": norm(k[2], (L, d, h + 2 * hkv), d),
             "wo": norm(k[3], (L, h, d), h),
             "ln2": jnp.ones((L, d), pd),
         },
         "ln_f": jnp.ones((d,), pd),
     }
+    if cfg.layer_positions:         # rotary or no positions: no table
+        del params["pos_embed"]
     if cfg.n_experts:
-        E = cfg.n_experts
+        E = cfg.n_experts           # the router's width, held or not
+        held = cfg.experts_held[1] if cfg.experts_held else E
         params["blocks"]["router"] = norm(k[4], (L, d, E), d)
-        params["blocks"]["we_in"] = norm(k[5], (L, E, d, f), d)
-        params["blocks"]["we_out"] = norm(k[6], (L, E, f, d), f)
+        params["blocks"]["we_in"] = norm(k[5], (L, held, d, f_in), d)
+        params["blocks"]["we_out"] = norm(k[6], (L, held, f, d), f)
     else:
-        params["blocks"]["w_in"] = norm(k[7], (L, d, f), d)
+        params["blocks"]["w_in"] = norm(k[7], (L, d, f_in), d)
         params["blocks"]["w_out"] = norm(k[8], (L, f, d), f)
     if not cfg.tie_embeddings:
         params["head"] = norm(k[9], (d, cfg.vocab_size), d)
@@ -169,6 +248,8 @@ def param_pspecs(cfg: TransformerConfig):
         },
         "ln_f": P(),
     }
+    if cfg.layer_positions:
+        del specs["pos_embed"]
     if cfg.n_experts:
         specs["blocks"]["router"] = P()
         specs["blocks"]["we_in"] = P(None, "ep", None, "tp")
@@ -236,27 +317,50 @@ def attention_path(cfg, t, dtype) -> str:
     return "xla_sdpa"
 
 
-def _attention(cfg, q, k, v, mask_bias=None):
+def _rope(x, theta, pos_offset=0):
+    """Rotary positions on (B, T, H, Dh): dimension i pairs with i + Dh/2
+    (the split-half layout), over the whole head, angles in float32."""
+    t, half = x.shape[1], x.shape[-1] // 2
+    inv = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    ang = (pos_offset + jnp.arange(t, dtype=jnp.float32))[:, None] * inv
+    cos, sin = jnp.cos(ang)[None, :, None], jnp.sin(ang)[None, :, None]
+    xf = x.astype(jnp.float32)
+    x1, x2 = xf[..., :half], xf[..., half:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           -1).astype(x.dtype)
+
+
+def _attention(cfg, q, k, v, mask_bias=None, positions="learned", window=0):
+    """``positions`` and ``window`` are the layer's kind (static): rotary
+    q and k or none; the keys a query sees (0 = every earlier one)."""
     b, t = q.shape[0], q.shape[1]
     q = q.reshape(b, t, cfg.n_heads, cfg.head_dim)
-    k = k.reshape(b, t, cfg.n_heads, cfg.head_dim)
-    v = v.reshape(b, t, cfg.n_heads, cfg.head_dim)
+    k = k.reshape(b, t, cfg.kv_heads, cfg.head_dim)
+    v = v.reshape(b, t, cfg.kv_heads, cfg.head_dim)
+    if positions == "rope":
+        q, k = _rope(q, cfg.rope_theta), _rope(k, cfg.rope_theta)
     path = attention_path(cfg, t, q.dtype)
     if path == "ring":
+        if window or cfg.kv_heads != cfg.n_heads:
+            raise NotImplementedError(
+                "ring attention has no window and no grouped K/V heads")
         from ..parallel.ring_attention import ring_attention_inner
         out = ring_attention_inner(q, k, v, causal=True)
     elif path == "flash":
         from ..kernels.flash_attention import flash_attention_ntc
-        out = flash_attention_ntc(q, k, v, causal=True)
+        out = flash_attention_ntc(q, k, v, causal=True,
+                                  window=window or None)
     elif path == "xla_bf16_scores":
-        out = _xla_attention_bf16_scores(q, k, v)
+        out = _xla_attention_bf16_scores(q, k, v, window=window)
     else:
-        out = jax.nn.dot_product_attention(q, k, v, is_causal=True)
+        out = jax.nn.dot_product_attention(
+            q, k, v, is_causal=True,
+            local_window_size=(window - 1, 0) if window else None)
     out = checkpoint_name(out, "attn_out")  # remat_policy="save_attn" hook
     return out.reshape(b, t, cfg.n_heads * cfg.head_dim)
 
 
-def _xla_attention_bf16_scores(q, k, v, causal=True, bias=None):
+def _xla_attention_bf16_scores(q, k, v, causal=True, bias=None, window=0):
     """Attention with the (B,H,T,S) score matrix MATERIALIZED bf16:
     the QK^T matmul accumulates f32 in-register (BF16_BF16_F32) but stores
     bf16, and the f32 upcast for the softmax fuses into its reduce — so
@@ -265,6 +369,9 @@ def _xla_attention_bf16_scores(q, k, v, causal=True, bias=None):
     additive mask broadcastable to (B, H, T, S) (e.g. padding mask −1e9,
     well inside bf16 range)."""
     t = q.shape[1]
+    if k.shape[2] != q.shape[2]:        # grouped K/V heads, written out
+        k = jnp.repeat(k, q.shape[2] // k.shape[2], axis=2)
+        v = jnp.repeat(v, q.shape[2] // v.shape[2], axis=2)
     scale = 1.0 / math.sqrt(q.shape[-1])
     q = (q.astype(jnp.float32) * scale).astype(q.dtype)  # pre-scale q (exact
     # for power-of-two head dims), so no extra pass over the T^2 logits
@@ -281,6 +388,8 @@ def _xla_attention_bf16_scores(q, k, v, causal=True, bias=None):
     if causal:
         neg = jnp.asarray(jnp.finfo(jnp.bfloat16).min / 2, jnp.bfloat16)
         mask = jnp.tril(jnp.ones((t, t), jnp.bool_))
+        if window:
+            mask = mask & ~jnp.tril(jnp.ones((t, t), jnp.bool_), -window)
         logits = jnp.where(mask[None, None, :, :], logits, neg)
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1
                            ).astype(q.dtype)
@@ -322,9 +431,18 @@ def _rmsnorm(x, scale, eps=1e-6):
 def _dense_mlp(cfg, x, w_in, w_out):
     h = jnp.einsum("btd,df->btf", x, w_in.astype(x.dtype))
     h = _constrain(h, "dp", "sp", "tp")
-    h = jax.nn.gelu(h)
+    h = _mlp_act(cfg, h)
     o = jnp.einsum("btf,fd->btd", h, w_out.astype(x.dtype))
     return o
+
+
+def _mlp_act(cfg, h):
+    """The MLP's nonlinearity on the (…, d_ff) or, gated, (…, 2·d_ff)
+    output of its first product."""
+    if cfg.mlp == "gelu":
+        return jax.nn.gelu(h)
+    gate, up = jnp.split(h, 2, axis=-1)
+    return jax.nn.relu(gate) * up
 
 
 def _moe_mlp(cfg, x, router, we_in, we_out):
@@ -364,6 +482,120 @@ def _moe_mlp(cfg, x, router, we_in, we_out):
     return out.reshape(b, t, d), aux.astype(jnp.float32)
 
 
+def _router_logits(x, router):
+    """(B, T, d) → (B·T, E) float32 logits, accumulated in float32."""
+    with jax.named_scope("moe_router"):
+        return jnp.einsum("btd,de->bte", x, router.astype(x.dtype),
+                          preferred_element_type=jnp.float32
+                          ).reshape(-1, router.shape[-1])
+
+
+def _take_rows(x, idx):
+    """x[idx] along axis 0 for indices known to be in bounds."""
+    return x.at[idx].get(mode="promise_in_bounds")
+
+
+# The K·N assignments of a routed layer are numbered K-MAJOR: assignment
+# a = k·N + n is token n's k-th choice. An (A, d) array in assignment order
+# is then a (K, N, d) array without moving a byte, and the sum over a
+# token's K rows is a sum of K slabs; numbered token-major, (N, K, d) pads
+# its K = 6 rows to the chip's tile of 8 and every reshape is a copy
+# (1.8 ms each at 98,304 x 2560; my chip run, PR 28).
+
+@jax.custom_vjp
+def _rows_out(tokens, order, inv, local):
+    """(N, d) tokens → (A, d) rows in expert order: row a is the token of
+    assignment ``order[a]``. ``inv`` is the inverse permutation and
+    ``local`` (K, N) says which assignments are to held experts: the
+    gradient is a gather by ``inv`` and a sum over each token's local rows
+    (those of absent experts were never computed), not a scatter-add."""
+    return _take_rows(tokens, order % tokens.shape[0])
+
+
+def _rows_out_fwd(tokens, order, inv, local):
+    return _rows_out(tokens, order, inv, local), (inv, local)
+
+
+def _rows_out_bwd(res, g):
+    inv, local = res
+    back = _take_rows(g, inv).reshape(*local.shape, g.shape[-1])
+    return (jnp.sum(jnp.where(local[:, :, None], back, 0), axis=0,
+                    dtype=jnp.float32).astype(g.dtype), None, None, None)
+
+
+_rows_out.defvjp(_rows_out_fwd, _rows_out_bwd)
+
+
+@jax.custom_vjp
+def _rows_back(rows, order, inv, local):
+    """(A, d) rows in expert order → (K, N, d) in assignment order, zero
+    where the assignment is not local (the absent experts' rows are
+    unset). The gradient is the gather by ``order``."""
+    return jnp.where(local[:, :, None],
+                     _take_rows(rows, inv).reshape(*local.shape, -1), 0)
+
+
+def _rows_back_fwd(rows, order, inv, local):
+    return _rows_back(rows, order, inv, local), order
+
+
+def _rows_back_bwd(order, g):
+    return (_take_rows(g.reshape(order.shape[0], -1), order),
+            None, None, None)
+
+
+_rows_back.defvjp(_rows_back_fwd, _rows_back_bwd)
+
+
+def _moe_share(cfg, x, logits, we_in, we_out):
+    """The held experts' part of a routed expert layer, dropless.
+
+    ``logits`` (N, n_experts) float32 are the router's, over every published
+    expert; ``we_in`` (held, d, f_in) and ``we_out`` (held, d_ff, d) are the
+    experts ``cfg.experts_held`` = (first, held) names. Top-k over all
+    experts, weights = softmax over the kept logits. The K·N assignments
+    are sorted by expert, the absent experts' last; one gather fills a
+    static (K·N, d) buffer (enough for EVERY assignment to be local, so no
+    routing can drop one); grouped products over the held experts' group
+    sizes compute exactly the rows routed here and leave the rest unset;
+    the rows go back to their tokens, where the local ones are weighted
+    and summed. Returns (y, stats): stats = float32 [assignments,
+    assignments to held experts, assignments dropped (rows the buffer
+    could not take: 0), largest held expert's load over their mean]."""
+    b, t, d = x.shape
+    n, k = b * t, cfg.expert_top_k
+    first, held = cfg.experts_held
+    tokens = x.reshape(n, d)
+    with jax.named_scope("moe_router"):
+        top, chosen = lax.top_k(logits, k)                      # (N, K)
+        weight = jax.nn.softmax(top, axis=-1).T                 # (K, N)
+        chosen = chosen.T
+    with jax.named_scope("moe_dispatch"):
+        local = (chosen >= first) & (chosen < first + held)     # (K, N)
+        slot = jnp.where(local, chosen - first, held).reshape(-1)    # (A,)
+        order = jnp.argsort(slot, stable=True).astype(jnp.int32)
+        inv = jnp.zeros_like(order).at[order].set(
+            jnp.arange(n * k, dtype=jnp.int32), unique_indices=True)
+        sizes = jnp.sum(slot[:, None] == jnp.arange(held)[None, :], axis=0,
+                        dtype=jnp.int32)                         # (held,)
+        n_local = jnp.sum(sizes)
+        rows = _rows_out(tokens, order, inv, local)
+    with jax.named_scope("moe_experts"):
+        hidden = _mlp_act(cfg, lax.ragged_dot(rows, we_in.astype(x.dtype),
+                                              sizes))
+        out = lax.ragged_dot(hidden, we_out.astype(x.dtype), sizes)
+    with jax.named_scope("moe_combine"):
+        parts = _rows_back(out, order, inv, local)              # (K, N, d)
+        y = jnp.sum(parts.astype(jnp.float32) * weight[:, :, None], axis=0
+                    ).astype(x.dtype)
+    f32 = jnp.float32
+    stats = jnp.stack([
+        jnp.asarray(n * k, f32), n_local.astype(f32),
+        jnp.maximum(n_local - rows.shape[0], 0).astype(f32),
+        jnp.max(sizes).astype(f32) * held / jnp.maximum(n_local, 1).astype(f32)])
+    return y.reshape(b, t, d), stats
+
+
 def embed(params, cfg: TransformerConfig, ids, pos_offset=0):
     """ids (B,T) → embedded activations (B,T,d) in compute dtype.
 
@@ -373,10 +605,12 @@ def embed(params, cfg: TransformerConfig, ids, pos_offset=0):
     but sees a local (B, T_local) slice."""
     t = ids.shape[1]
     x = jnp.take(params["embed"], ids, axis=0).astype(cfg.dtype)
-    x = x * math.sqrt(cfg.d_model)
-    pos = lax.dynamic_slice_in_dim(params["pos_embed"],
-                                   pos_offset, t, axis=0)
-    x = x + pos.astype(cfg.dtype)
+    if cfg.embed_scale:
+        x = x * math.sqrt(cfg.d_model)
+    if not cfg.layer_positions:     # else the layers place their own
+        pos = lax.dynamic_slice_in_dim(params["pos_embed"],
+                                       pos_offset, t, axis=0)
+        x = x + pos.astype(cfg.dtype)
     return _constrain(x, "dp", "sp", None)
 
 
@@ -437,21 +671,45 @@ def apply_blocks(blocks, cfg: TransformerConfig, x, *, return_kv=False):
 
     ``return_kv=True`` is the serving-plane prefill hook: the SAME block
     math additionally yields each layer's per-head key/value activations,
-    stacked ``(L, B, T, H, Dh)`` in compute dtype, and the return becomes
+    stacked ``(L, B, T, Hkv, Dh)`` in compute dtype, and the return becomes
     ``(x, aux_sum, (k, v))``. Remat is skipped on that path — prefill is
     forward-only, there are no residuals to trade for recompute — which
     keeps the captured k/v out of any checkpoint policy's hands."""
+    x, auxes, kvs, _ = _run_blocks(blocks, cfg, x, return_kv)
+    if return_kv:
+        return x, jnp.sum(auxes), kvs
+    return x, jnp.sum(auxes)
 
-    def block(x, blk):
+
+def _run_blocks(blocks, cfg: TransformerConfig, x, return_kv=False):
+    """(x, auxes (L,), kvs, expert stats (L, 4) or None). The scan runs
+    over PERIODS of the layer pattern (``cfg.layer_kinds``; a period of one
+    layer for a uniform stack): inside a period the layers' kinds are
+    static, and each layer is rematerialized on its own."""
+    _check(cfg)
+    hq, hkv = cfg.n_heads * cfg.head_dim, cfg.kv_heads * cfg.head_dim
+
+    def block(x, blk, positions, window):
         h = _rmsnorm(x, blk["ln1"])
         qkv = jnp.einsum("btd,dz->btz", h, blk["wqkv"].astype(h.dtype))
         qkv = _constrain(qkv, "dp", "sp", "tp")
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        a = _attention(cfg, q, k, v)
+        q, k, v = jnp.split(qkv, 3, axis=-1) if hkv == hq else \
+            jnp.split(qkv, (hq, hq + hkv), axis=-1)
+        routed = None
+        if cfg.experts_held and cfg.router_input == "pre_attention":
+            routed = _router_logits(h, blk["router"])
+        a = _attention(cfg, q, k, v, positions=positions, window=window)
         a = jnp.einsum("bth,hd->btd", a, blk["wo"].astype(h.dtype))
         x = x + _constrain(a, "dp", "sp", None)
         h2 = _rmsnorm(x, blk["ln2"])
-        if cfg.n_experts:
+        stats = None
+        if cfg.experts_held:
+            if routed is None:
+                routed = _router_logits(h2, blk["router"])
+            m, stats = _moe_share(cfg, h2, routed, blk["we_in"],
+                                  blk["we_out"])
+            aux = 0.0
+        elif cfg.n_experts:
             m, aux = _moe_mlp(cfg, h2, blk["router"], blk["we_in"], blk["we_out"])
         else:
             m, aux = _dense_mlp(cfg, h2, blk["w_in"], blk["w_out"]), 0.0
@@ -459,22 +717,42 @@ def apply_blocks(blocks, cfg: TransformerConfig, x, *, return_kv=False):
         kv = None
         if return_kv:
             b, t = x.shape[0], x.shape[1]
-            kv = (k.reshape(b, t, cfg.n_heads, cfg.head_dim),
-                  v.reshape(b, t, cfg.n_heads, cfg.head_dim))
-        return x, (aux, kv)
+            kv = (k.reshape(b, t, cfg.kv_heads, cfg.head_dim),
+                  v.reshape(b, t, cfg.kv_heads, cfg.head_dim))
+        return x, (aux, kv, stats)
 
-    blk_fn = block if (return_kv or not cfg.remat) \
-        else _remat_wrap(block, cfg.remat_policy)
+    def of_kind(positions, window):
+        fn = lambda x, blk: block(x, blk, positions, window)   # noqa: E731
+        return fn if (return_kv or not cfg.remat) \
+            else _remat_wrap(fn, cfg.remat_policy)
 
-    def scan_body(carry, blk):
-        x = carry
-        x, ys = blk_fn(x, blk)
-        return x, ys
+    blk_fns = [of_kind(*kind) for kind in cfg.layer_kinds]
+    period = len(blk_fns)
+    if period == 1:     # a uniform stack keeps the scan it always had
+        blk_fn = blk_fns[0]
 
-    x, (auxes, kvs) = lax.scan(scan_body, x, blocks)
-    if return_kv:
-        return x, jnp.sum(auxes), kvs
-    return x, jnp.sum(auxes)
+        def scan_body(carry, blk):
+            x = carry
+            x, ys = blk_fn(x, blk)
+            return x, ys
+    else:
+        # (L, ...) → (L / period, period, ...): one scan step is one period
+        blocks = jax.tree_util.tree_map(
+            lambda w: w.reshape(w.shape[0] // period, period, *w.shape[1:]),
+            blocks)
+
+        def scan_body(x, blks):
+            ys = []
+            for i, blk_fn in enumerate(blk_fns):
+                x, y = blk_fn(x, jax.tree_util.tree_map(lambda w: w[i], blks))
+                ys.append(y)
+            return x, jax.tree_util.tree_map(lambda *l: jnp.stack(l), *ys)
+
+    x, (auxes, kvs, stats) = lax.scan(scan_body, x, blocks)
+    if period > 1:      # (L / period, period, ...) → (L, ...)
+        auxes, kvs, stats = jax.tree_util.tree_map(
+            lambda y: y.reshape(-1, *y.shape[2:]), (auxes, kvs, stats))
+    return x, auxes, kvs, stats
 
 
 def forward(params, cfg: TransformerConfig, ids, *, train=False, rng=None,
@@ -538,31 +816,47 @@ def _chunked_ce(x, head, targets, chunk, weights=None, bias=None):
 
 def lm_loss(params, cfg: TransformerConfig, ids, targets, *, aux_weight=1e-2,
             pos_offset=0):
+    return _lm_loss_stats(params, cfg, ids, targets, aux_weight=aux_weight,
+                          pos_offset=pos_offset)[0]
+
+
+def _lm_loss_stats(params, cfg: TransformerConfig, ids, targets, *,
+                   aux_weight=1e-2, pos_offset=0):
+    """(loss, per-layer expert-load stats (L, 4) or None): see
+    :func:`_moe_share` for the four numbers."""
     b, t = ids.shape
+    x = embed(params, cfg, ids, pos_offset)
+    x, auxes, _, stats = _run_blocks(params["blocks"], cfg, x)
+    aux = jnp.sum(auxes)
     if _use_fused_loss(cfg, b * t):
-        x = embed(params, cfg, ids, pos_offset)
-        x, aux = apply_blocks(params["blocks"], cfg, x)
         x = _rmsnorm(x, params["ln_f"])
         head = _resolve_head(params, cfg)
         nll = _chunked_ce(x.reshape(b * t, -1), head.astype(x.dtype),
                           targets.reshape(b * t), cfg.loss_chunk) / (b * t)
-        return nll + aux_weight * aux
-    logits, aux = forward(params, cfg, ids, train=True, pos_offset=pos_offset)
+        return nll + aux_weight * aux, stats
+    logits = head_logits(params, cfg, x)
     logp = jax.nn.log_softmax(logits, axis=-1)
     nll = -jnp.take_along_axis(logp, targets[..., None].astype(jnp.int32), -1)[..., 0]
-    return nll.mean() + aux_weight * aux
+    return nll.mean() + aux_weight * aux, stats
 
 
 def make_train_step(cfg: TransformerConfig, optimizer):
     """One jitted step: grads → optax update → new params. Shard via the
-    caller's jit(in_shardings=...) or run as-is on one device."""
+    caller's jit(in_shardings=...) or run as-is on one device. Returns
+    (params, opt_state, loss) and, where the configuration holds a share of
+    routed experts (``experts_held``), a fourth output: the per-layer
+    expert-load stats (L, 4) float32 of :func:`_moe_share`, computed on the
+    device beside the loss (``obs.moe.record_expert_load`` counts them)."""
 
     def step(params, opt_state, ids, targets):
-        loss, grads = jax.value_and_grad(lm_loss)(params, cfg, ids, targets)
+        (loss, stats), grads = jax.value_and_grad(
+            _lm_loss_stats, has_aux=True)(params, cfg, ids, targets)
         updates, opt_state = optimizer.update(grads, opt_state, params)
         import optax as _optax
         params = _optax.apply_updates(params, updates)
-        return params, opt_state, loss
+        if stats is None:
+            return params, opt_state, loss
+        return params, opt_state, loss, stats
 
     return step
 

@@ -115,6 +115,30 @@ def test_flash_fwd_bwd_compiles(one_chip, no_persistent_cache, shape,
         assert re.search(rf"%{name}(\.\d+)? = [^\n]*tpu_custom_call", text), name
 
 
+@pytest.mark.parametrize("window,suffix", [(4096, "_win"), (None, "")],
+                         ids=["window_4096", "full"])
+def test_flash_grouped_heads_compile_at_the_moe_cell_shape(
+        one_chip, no_persistent_cache, window, suffix):
+    """smallthinker-train-b2-t8192's attention: 7 query heads of 128 on one
+    K/V head at T 8192, with and without the 4096 window (PR 28); the
+    window kernels carry names of their own."""
+    from deeplearning4j_tpu.kernels.flash_attention import flash_attention
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, None, True, 1024, 1024, False,
+                                       window).astype(jnp.float32))
+
+    q = _sds(one_chip, (2, 7, 8192, 128), jnp.bfloat16)
+    kv = _sds(one_chip, (2, 1, 8192, 128), jnp.bfloat16)
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert re.search(rf"%{name}{suffix}(\.\d+)? = [^\n]*tpu_custom_call",
+                         text), name
+    # dK and dV come out of the kernel summed over the group: one K/V head
+    assert re.search(r"%flash_bwd_dkv\w* = \(bf16\[2,8192,128\]", text) or \
+        "bf16[2,8192,128]" in text
+
+
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
                          ids=["bf16", "f32"])
 def test_paged_decode_kernel_compiles(one_chip, no_persistent_cache, dtype):
