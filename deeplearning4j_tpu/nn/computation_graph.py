@@ -19,6 +19,7 @@ import optax
 
 from ..obs.spans import span
 from ..train.updaters import NoOp, build_optimizer
+from ._fit_common import fit_counters, stage_batch
 from .graph import ComputationGraphConfiguration
 from .layers.base import Ctx, Layer
 from .layers.wrappers import unwrap
@@ -667,44 +668,53 @@ class ComputationGraph:
 
     def _fit_epochs(self, run_iter, source_iter, wrapped, epochs, step_fn,
                     anomaly_check):
-        """The epoch loop, with one ``fit.iteration`` span a pass (attrs
-        ``batch``: the k-th batch of this call, and ``examples``) whose
-        children, in order, are ``fit.next`` (until the batch is in hand),
-        ``fit.h2d`` (the ``jnp.asarray`` copies; attrs ``bytes``),
-        ``fit.dispatch`` (the step call), ``fit.loss_sync`` (``float(loss)``,
-        where listeners ask for it) and ``fit.listeners``. The pass that
-        finds the iterator exhausted carries ``end`` and no ``batch``."""
+        """The epoch loop, one batch staged ahead. One ``fit.iteration``
+        span a pass (attrs ``batch``: the k-th batch of this call, the one
+        the pass dispatches, and ``examples``) whose children, in order, are
+        ``fit.dispatch`` (the step call on batch k's device arrays),
+        ``fit.next`` and ``fit.h2d`` of batch k+1 (until the iterator hands
+        it over; the ``jnp.asarray`` copies, attrs ``bytes``),
+        ``fit.loss_sync`` (``float(loss)`` of step k, where listeners ask
+        for it) and ``fit.listeners`` of step k. Every child carries its own
+        ``batch``, so ``fit.next`` and ``fit.h2d`` of batch k+1 lie in the
+        pass of batch k: its copy runs beside step k, and the listeners of
+        step k still see the parameters as step k left them, before step
+        k+1 is dispatched. The epoch's first ``fit.next`` and ``fit.h2d``
+        lie directly under ``fit``. The pass whose ``fit.next`` finds the
+        iterator exhausted, the epoch's last batch's, carries ``end`` (an
+        epoch without a batch leaves none). An exception out of that fetch
+        reaches the caller after step k's ``fit.loss_sync`` and
+        ``fit.listeners``. Two batches are resident on the device at a
+        time, the one in the step and the one staged."""
         from ..data.dataset import MultiDataSet as MDS
+
+        def to_device(ds):
+            if isinstance(ds, MDS):
+                feats, labs = ds.features, ds.labels
+                fmask = None if ds.features_masks is None else ds.features_masks[0]
+                lmask = None if ds.labels_masks is None else ds.labels_masks[0]
+            else:
+                feats, labs = [ds.features], [ds.labels]
+                fmask, lmask = ds.features_mask, ds.labels_mask
+            return ({n: jnp.asarray(f) for n, f in zip(self.conf.inputs, feats)},
+                    {n: jnp.asarray(l) for n, l in zip(self.conf.outputs, labs)},
+                    None if fmask is None else jnp.asarray(fmask),
+                    None if lmask is None else jnp.asarray(lmask))
+
+        n_batches, n_ahead = fit_counters()
         last = None
         k = 0
         for e in range(epochs):
             batches = iter(run_iter)
-            while True:
-                with span("fit.iteration") as iteration:
-                    with span("fit.next", attrs={"batch": k}):
-                        ds = next(batches, None)
-                    if ds is None:
-                        iteration.set_attr("end", True)
-                        break
-                    if isinstance(ds, MDS):
-                        feats, labs = ds.features, ds.labels
-                        fmask = None if ds.features_masks is None else ds.features_masks[0]
-                        lmask = None if ds.labels_masks is None else ds.labels_masks[0]
-                    else:
-                        feats, labs = [ds.features], [ds.labels]
-                        fmask, lmask = ds.features_mask, ds.labels_mask
-                    with span("fit.h2d", attrs={"batch": k}) as h2d:
-                        inputs = {n: jnp.asarray(f) for n, f in zip(self.conf.inputs, feats)}
-                        labels = {n: jnp.asarray(l) for n, l in zip(self.conf.outputs, labs)}
-                        fm = None if fmask is None else jnp.asarray(fmask)
-                        lm = None if lmask is None else jnp.asarray(lmask)
-                        h2d.set_attr("bytes", sum(
-                            a.nbytes for a in (*inputs.values(),
-                                               *labels.values(), fm, lm)
-                            if a is not None))
+            staged = stage_batch(batches, k, to_device)
+            while staged is not None:
+                with span("fit.iteration", attrs={"batch": k}) as iteration:
+                    # `held`: batch k's host arrays, referenced until the
+                    # next pass, so past the sync of the step that reads
+                    # their copy (see stage_batch)
+                    held, (inputs, labels, fm, lm) = staged
                     # examples-throughput telemetry (MetricsListener)
                     self._last_batch_size = int(next(iter(inputs.values())).shape[0])
-                    iteration.set_attr("batch", k)
                     iteration.set_attr("examples", self._last_batch_size)
                     with span("fit.dispatch", attrs={"batch": k}):
                         (self.params, self.states, self._opt_state, loss,
@@ -712,15 +722,25 @@ class ComputationGraph:
                             self.params, self.states, self._opt_state, inputs,
                             labels, self._host_key, fm, lm)
                     self._step_count += 1
+                    n_batches.inc()
                     if anomaly_check is not None and gstats is not None:
                         anomaly_check.push(gstats, self._step_count)
                     last = loss
-                    if self.listeners:
-                        with span("fit.loss_sync", attrs={"batch": k}):
-                            lv = float(loss)
-                        with span("fit.listeners", attrs={"batch": k}):
-                            for listener in self.listeners:
-                                listener.iteration_done(self, self._step_count, self.epoch_count, lv)
+                    try:
+                        # batch k+1 crosses to the device while step k runs
+                        staged = stage_batch(batches, k + 1, to_device)
+                        if staged is None:
+                            iteration.set_attr("end", True)
+                        else:
+                            n_ahead.inc()
+                    finally:
+                        # step k's report, also where the iterator raised
+                        if self.listeners:
+                            with span("fit.loss_sync", attrs={"batch": k}):
+                                lv = float(loss)
+                            with span("fit.listeners", attrs={"batch": k}):
+                                for listener in self.listeners:
+                                    listener.iteration_done(self, self._step_count, self.epoch_count, lv)
                     k += 1
             self.epoch_count += 1
             if e < epochs - 1:

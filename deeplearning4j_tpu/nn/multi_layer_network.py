@@ -24,6 +24,7 @@ import optax
 
 from ..obs.spans import span
 from ..train.updaters import NoOp, build_optimizer, gradient_normalization
+from ._fit_common import fit_counters, stage_batch
 from .conf import MultiLayerConfiguration
 from .layers.base import Ctx, Layer
 from .layers.wrappers import unwrap
@@ -457,14 +458,20 @@ class MultiLayerNetwork:
         return None if last is None else float(last)
 
     def _fit_epochs(self, iterator, epochs, step_fn, anomaly_check):
-        """The epoch loop, under the same span names at the same places as
-        ``ComputationGraph._fit_epochs``: one ``fit.iteration`` a pass (attrs
-        ``batch``, ``examples``; ``end`` on the pass that finds the iterator
-        exhausted) over ``fit.next``, ``fit.h2d``, ``fit.dispatch``,
-        ``fit.loss_sync`` and ``fit.listeners``. Where the score fetch is
-        deferred, batch k-1's ``fit.loss_sync`` and ``fit.listeners`` lie
-        in batch k's iteration (their ``batch`` says whose they are) and
-        the epoch's last pair directly under ``fit``."""
+        """The epoch loop, one batch staged ahead, under the same span names
+        at the same places as ``ComputationGraph._fit_epochs``: one
+        ``fit.iteration`` a pass (attrs ``batch``, ``examples``; ``end`` on
+        the epoch's last batch's pass, whose ``fit.next`` finds the iterator
+        exhausted) over ``fit.dispatch`` of batch k, ``fit.next`` and
+        ``fit.h2d`` of batch k+1 (the epoch's first pair directly under
+        ``fit``), then ``fit.loss_sync`` and ``fit.listeners`` of step k,
+        each carrying its own ``batch``. Where the score fetch is deferred,
+        the pass of batch k ends with the ``fit.loss_sync`` and
+        ``fit.listeners`` of batch k-1 instead, and the epoch's last pair
+        lies directly under ``fit``. Either way batch k+1's copy is issued
+        before the host waits for a loss, an exception out of its fetch
+        reaches the caller after every finished step's report, and two
+        batches are resident on the device at a time."""
         # DL4J's fit wraps the source in an AsyncDataSetIterator so batch
         # prep runs on a background thread while the device computes; do
         # the same when the iterator opts in (async_supported).
@@ -497,27 +504,23 @@ class MultiLayerNetwork:
                 args, pending = pending, None
                 report(*args)
 
+        def to_device(ds):
+            return (jnp.asarray(ds.features), jnp.asarray(ds.labels),
+                    None if ds.features_mask is None else jnp.asarray(ds.features_mask),
+                    None if ds.labels_mask is None else jnp.asarray(ds.labels_mask))
+
+        n_batches, n_ahead = fit_counters()
         try:
             for e in range(epochs):
                 batches = iter(run_iter)
-                while True:
-                    with span("fit.iteration") as iteration:
-                        with span("fit.next", attrs={"batch": k}):
-                            ds = next(batches, None)
-                        if ds is None:
-                            iteration.set_attr("end", True)
-                            break
-                        with span("fit.h2d", attrs={"batch": k}) as h2d:
-                            x = jnp.asarray(ds.features)
-                            y = jnp.asarray(ds.labels)
-                            fmask = None if ds.features_mask is None else jnp.asarray(ds.features_mask)
-                            lmask = None if ds.labels_mask is None else jnp.asarray(ds.labels_mask)
-                            h2d.set_attr("bytes", sum(
-                                a.nbytes for a in (x, y, fmask, lmask)
-                                if a is not None))
+                staged = stage_batch(batches, k, to_device)
+                while staged is not None:
+                    with span("fit.iteration", attrs={"batch": k}) as iteration:
+                        # `held`: batch k's host arrays, referenced until
+                        # the next pass (see stage_batch)
+                        held, (x, y, fmask, lmask) = staged
                         # examples-throughput telemetry (MetricsListener)
                         self._last_batch_size = int(x.shape[0])
-                        iteration.set_attr("batch", k)
                         iteration.set_attr("examples", self._last_batch_size)
                         with span("fit.dispatch", attrs={"batch": k}):
                             (self.params, self.states, self._opt_state, loss,
@@ -525,18 +528,28 @@ class MultiLayerNetwork:
                                 self.params, self.states, self._opt_state, x,
                                 y, self._host_key, fmask, lmask)
                         self._step_count += 1
+                        n_batches.inc()
                         if anomaly_check is not None and gstats is not None:
                             anomaly_check.push(gstats, self._step_count)
                         last = loss
-                        if self.listeners:
-                            if defer_ok:
-                                # step k-1's loss, while step k is in flight
-                                flush_pending()
-                                pending = (loss, self._step_count,
-                                           self.epoch_count, k)
+                        try:
+                            # batch k+1 crosses to the device while step k runs
+                            staged = stage_batch(batches, k + 1, to_device)
+                            if staged is None:
+                                iteration.set_attr("end", True)
                             else:
-                                report(loss, self._step_count,
-                                       self.epoch_count, k)
+                                n_ahead.inc()
+                        finally:
+                            # step k's report, also where the iterator raised
+                            if self.listeners:
+                                if defer_ok:
+                                    # step k-1's loss, while step k is in flight
+                                    flush_pending()
+                                    pending = (loss, self._step_count,
+                                               self.epoch_count, k)
+                                else:
+                                    report(loss, self._step_count,
+                                           self.epoch_count, k)
                         k += 1
                 self.epoch_count += 1
                 if e < epochs - 1:

@@ -1,10 +1,14 @@
-"""Spans and counters inside ``fit()`` and its async prefetch (ISSUE 26).
+"""Spans and counters inside ``fit()`` and its async prefetch (ISSUE 26),
+and the loop's order since it stages one batch ahead (ISSUE 29).
 
 One ``fit`` root per call; one ``fit.iteration`` per batch whose children
-lie inside it, in order, on one clock; the producer thread's ``data.*``
-spans in the same trace under the same ``batch`` numbers; the five
-``dl4j_data_*`` counters at the ring's boundaries; what a span costs; and
-the keys ``record()`` and the JSONL have always had.
+lie inside it, in order, on one clock, the fetch and copy of batch k+1
+between the dispatch of step k and its loss; the producer thread's
+``data.*`` spans in the same trace under the same ``batch`` numbers; the
+five ``dl4j_data_*`` counters at the ring's boundaries and the two
+``dl4j_fit_*`` of the loop; that the order changes no score, no parameter
+and no listener's view; what a span costs; and the keys ``record()`` and
+the JSONL have always had.
 """
 
 import threading
@@ -24,13 +28,15 @@ from deeplearning4j_tpu.nn.listeners import TrainingListener
 from deeplearning4j_tpu.nn.multi_layer_network import MultiLayerNetwork
 
 N_BATCHES, BATCH = 5, 4
-#: the children of ``fit.iteration`` in the order the loop runs them
-ORDER = ["fit.next", "fit.h2d", "fit.dispatch", "fit.loss_sync",
-         "fit.listeners"]
+#: the children of batch k's ``fit.iteration`` in the order the loop runs
+#: them, each with the batch it carries less k
+ORDER = [("fit.dispatch", 0), ("fit.next", 1), ("fit.h2d", 1),
+         ("fit.loss_sync", 0), ("fit.listeners", 0)]
 COUNTERS = ["dl4j_data_batches_total", "dl4j_data_oversize_batches_total",
             "dl4j_data_packed_bytes_total",
             "dl4j_data_pack_discarded_bytes_total",
-            "dl4j_data_consumer_waits_total"]
+            "dl4j_data_consumer_waits_total",
+            "dl4j_fit_batches_total", "dl4j_fit_staged_ahead_total"]
 
 
 class _Scores(TrainingListener):
@@ -68,6 +74,11 @@ def _mln():
     return MultiLayerNetwork(conf).init()
 
 
+NETS = pytest.mark.parametrize(
+    "make_net", [_graph, _mln],
+    ids=["ComputationGraph", "MultiLayerNetwork"])
+
+
 def _fit_and_collect(net, epochs=1):
     """Spans of ONE fit() call over an iterator that opts into the async
     wrapper, as {name: [spans in start order]}, and the root."""
@@ -88,33 +99,49 @@ def _fit_and_collect(net, epochs=1):
     return root, by_name, mine
 
 
-@pytest.mark.parametrize("make_net", [_graph, _mln],
-                         ids=["ComputationGraph", "MultiLayerNetwork"])
+@NETS
 def test_fit_span_tree_on_one_clock(make_net):
     root, by_name, mine = _fit_and_collect(make_net())
     assert root.parent_id is None and root.attrs == {"epochs": 1}
     me = threading.current_thread().name
 
-    iterations = [s for s in by_name["fit.iteration"] if "batch" in s.attrs]
-    ends = [s for s in by_name["fit.iteration"] if s.attrs.get("end")]
+    iterations = by_name["fit.iteration"]
     assert [s.attrs["batch"] for s in iterations] == list(range(N_BATCHES))
     assert all(s.attrs["examples"] == BATCH for s in iterations)
-    assert len(ends) == 1 and "batch" not in ends[0].attrs
-    for it in iterations + ends:
+    # one ``end`` marker: on the last batch's pass, whose fit.next finds
+    # the source exhausted (and so has no fit.h2d after it)
+    assert [bool(s.attrs.get("end")) for s in iterations] \
+        == [False] * (N_BATCHES - 1) + [True]
+    for it in iterations:
         assert it.parent_id == root.span_id and it.thread == me
         assert root.t0_ns <= it.t0_ns <= it.t1_ns <= root.t1_ns
 
     for it in iterations:
+        k = it.attrs["batch"]
         kids = [s for s in mine if s.parent_id == it.span_id]
-        assert [s.name for s in kids] == ORDER
-        assert all(s.attrs["batch"] == it.attrs["batch"] for s in kids)
+        want = [(n, k + d) for n, d in ORDER
+                if not (it.attrs.get("end") and n == "fit.h2d")]
+        assert [(s.name, s.attrs["batch"]) for s in kids] == want
         assert all(s.thread == me for s in kids)
-        # inside the parent, one after the other, on perf_counter_ns
+        # inside the parent, one after the other, on perf_counter_ns: so
+        # batch k+1's copy is issued before the host waits for loss k
         edges = [it.t0_ns]
         for s in kids:
             edges += [s.t0_ns, s.t1_ns]
         edges.append(it.t1_ns)
         assert edges == sorted(edges)
+    for k in range(N_BATCHES - 1):
+        assert by_name["fit.h2d"][k + 1].t1_ns \
+            <= by_name["fit.loss_sync"][k].t0_ns
+    # every batch is fetched and copied once, in order; the epoch's first
+    # pair lies directly under ``fit``, before the first pass
+    assert [s.attrs["batch"] for s in by_name["fit.next"]] \
+        == list(range(N_BATCHES + 1))
+    assert [s.attrs["batch"] for s in by_name["fit.h2d"]] \
+        == list(range(N_BATCHES))
+    for s in (by_name["fit.next"][0], by_name["fit.h2d"][0]):
+        assert s.parent_id == root.span_id and s.thread == me
+        assert s.t1_ns <= iterations[0].t0_ns
     assert [s.attrs["bytes"] for s in by_name["fit.h2d"]] \
         == [BATCH * (6 + 3) * 4] * N_BATCHES
 
@@ -158,10 +185,7 @@ def test_mln_deferred_scores_keep_their_batch():
     the sync and listener spans say whose they are."""
     net = _mln()
 
-    class Deferred(_Scores):
-        deferred_score_ok = True
-
-    net.set_listeners(Deferred())
+    net.set_listeners(_Deferred())
     x, y = _data()
     tracer = obs.get_tracer()
     before = {id(s) for s in tracer.spans()}
@@ -176,6 +200,145 @@ def test_mln_deferred_scores_keep_their_batch():
         == list(range(1, N_BATCHES))
     assert parents[-1].name == "fit"
     assert len(net.listeners[0].scores) == N_BATCHES
+
+
+class _Deferred(_Scores):
+    deferred_score_ok = True
+
+
+#: both loops, and both branches of MultiLayerNetwork's
+LOOPS = pytest.mark.parametrize(
+    "make_net, listener",
+    [(_graph, _Scores), (_mln, _Scores), (_mln, _Deferred)],
+    ids=["ComputationGraph", "MultiLayerNetwork",
+         "MultiLayerNetwork-deferred"])
+
+
+def _leaves(net):
+    import jax
+    return [np.asarray(a) for a in jax.tree_util.tree_leaves(net.params)]
+
+
+def _one_by_one(net, epochs):
+    """The sequential order: ``fit(DataSet)`` once a batch, nothing staged
+    (a single batch has no batch k+1). After each call, the step count and
+    the bytes of the first parameter leaf."""
+    from deeplearning4j_tpu.data.dataset import DataSet
+    x, y = _data()
+    seen = []
+    for _ in range(epochs):
+        for i in range(0, len(x), BATCH):
+            net.fit(DataSet(x[i:i + BATCH], y[i:i + BATCH]))
+            seen.append((net._step_count, _leaves(net)[0].tobytes()))
+    return seen
+
+
+@LOOPS
+def test_staged_fit_is_bit_identical_to_one_batch_a_call(make_net, listener):
+    x, y = _data()
+    staged, plain = make_net(), make_net()
+    staged.set_listeners(listener())
+    plain.set_listeners(listener())
+    staged.fit(ArrayDataSetIterator(x, y, BATCH), epochs=2)
+    _one_by_one(plain, epochs=2)
+    assert len(staged.listeners[0].scores) == 2 * N_BATCHES
+    assert staged.listeners[0].scores == plain.listeners[0].scores
+    assert staged._step_count == plain._step_count == 2 * N_BATCHES
+    for a, b in zip(_leaves(staged), _leaves(plain)):
+        assert a.tobytes() == b.tobytes()
+
+
+@NETS
+def test_listener_of_step_k_sees_the_parameters_of_step_k(make_net):
+    """Batch k+1 is fetched and copied before step k's listeners run, but
+    dispatched after them: they see the model as step k left it."""
+    class Watch(_Scores):
+        def __init__(self):
+            super().__init__()
+            self.seen = []
+
+        def iteration_done(self, model, iteration, epoch, score):
+            assert iteration == model._step_count
+            self.seen.append((iteration, _leaves(model)[0].tobytes()))
+
+    x, y = _data()
+    net = make_net()
+    net.set_listeners(Watch())
+    net.fit(ArrayDataSetIterator(x, y, BATCH), epochs=2)
+    want = _one_by_one(make_net(), epochs=2)
+    assert [k for k, _ in want] == list(range(1, 2 * N_BATCHES + 1))
+    assert net.listeners[0].seen == want
+    assert len({leaf for _, leaf in want}) == len(want)     # each step moved it
+
+
+class _Breaks:
+    """A plain iterable (no async wrapper) whose fourth ``next`` raises."""
+
+    def __iter__(self):
+        from deeplearning4j_tpu.data.dataset import DataSet
+        x, y = _data()
+        for i in range(3):
+            yield DataSet(x[i * BATCH:(i + 1) * BATCH],
+                          y[i * BATCH:(i + 1) * BATCH])
+        raise OSError("the source broke")
+
+
+class _BreaksBehindTheWrapper(ArrayDataSetIterator):
+    """The same through ``fit()``'s own AsyncDataSetIterator, which hands
+    the producer thread's exception over as a RuntimeError."""
+
+    def __init__(self):
+        super().__init__(*_data(), BATCH)
+        self.calls = 0
+
+    def next(self, num=None):
+        self.calls += 1
+        if self.calls == 4:
+            raise OSError("the source broke")
+        return super().next(num)
+
+
+@pytest.mark.parametrize("source, error",
+                         [(_Breaks, OSError),
+                          (_BreaksBehindTheWrapper, RuntimeError)],
+                         ids=["plain", "async"])
+@LOOPS
+def test_a_failing_fetch_loses_no_finished_steps_report(make_net, listener,
+                                                        source, error):
+    """Batch 3's fetch raises inside the pass of batch 2, after step 2 was
+    dispatched: its score still reaches the listener, then the caller gets
+    the exception."""
+    net = make_net()
+    net.set_listeners(listener())
+    before = _counter_values()
+    with pytest.raises(error):
+        net.fit(source())
+    assert len(net.listeners[0].scores) == 3 and net._step_count == 3
+    d = {n: _counter_values()[n] - before[n] for n in COUNTERS}
+    assert d["dl4j_fit_batches_total"] == 3
+    assert d["dl4j_fit_staged_ahead_total"] == 2
+
+
+@pytest.mark.parametrize("epochs", [1, 2])
+@LOOPS
+def test_fit_counters_count_batches_and_those_staged_ahead(make_net, listener,
+                                                           epochs):
+    """Every batch but an epoch's first is copied while the step before it
+    is in flight; a single DataSet has no batch to stage."""
+    from deeplearning4j_tpu.data.dataset import DataSet
+    x, y = _data()
+    net = make_net()
+    net.set_listeners(listener())
+    before = _counter_values()
+    net.fit(ArrayDataSetIterator(x, y, BATCH), epochs=epochs)
+    d = {n: _counter_values()[n] - before[n] for n in COUNTERS}
+    assert d["dl4j_fit_batches_total"] == epochs * N_BATCHES
+    assert d["dl4j_fit_staged_ahead_total"] == epochs * (N_BATCHES - 1)
+    before = _counter_values()
+    net.fit(DataSet(x[:BATCH], y[:BATCH]), epochs=epochs)
+    d = {n: _counter_values()[n] - before[n] for n in COUNTERS}
+    assert d["dl4j_fit_batches_total"] == epochs
+    assert d["dl4j_fit_staged_ahead_total"] == 0
 
 
 def _counter_values():
