@@ -13,13 +13,12 @@ from typing import Any, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 import numpy as np
-import optax
 
-from ..obs.spans import span
 from ..train.updaters import NoOp, build_optimizer
-from ._fit_common import fit_counters, stage_batch
+from ._fit_common import (build_train_step,
+                          enable_gradient_anomaly_detection, fit_epochs)
+from ._scan_common import check_scan_listeners, fit_scanned_epochs
 from .graph import ComputationGraphConfiguration
 from .layers.base import Ctx, Layer
 from .layers.wrappers import unwrap
@@ -511,53 +510,16 @@ class ComputationGraph:
 
     def _get_train_step(self):
         if self._train_step is None:
-            optimizer = self._optimizer
-
-            with_stats = getattr(self, "_anomaly_detector", None) is not None
-            # numerics sentinel (ISSUE 13) — see MLN._get_train_step
-            gate = with_stats and getattr(self._anomaly_detector,
-                                          "gate_updates", True)
-
-            def step(params, states, opt_state, inputs, labels, rng, fmask, lmask):
-                # split inside jit; next key rides the outputs (no separate
-                # host-side split dispatch per batch — see MLN._get_train_step)
-                use_rng, next_rng = jax.random.split(rng)
-                (loss, new_states), grads = jax.value_and_grad(
-                    self._loss, has_aux=True)(params, states, inputs, labels,
-                                              use_rng, fmask, lmask)
-                updates, new_opt_state = optimizer.update(grads, opt_state, params)
-                new_params = self._apply_constraints(
-                    optax.apply_updates(params, updates))
-                stats = None
-                if with_stats:
-                    from ..train.anomaly import maybe_stats_and_gate
-                    stats, new_params, new_opt_state, new_states = \
-                        maybe_stats_and_gate(
-                            gate, grads, params, new_params, opt_state,
-                            new_opt_state, states, new_states)
-                return new_params, new_states, new_opt_state, loss, stats, next_rng
-
-            # compile sentinel (ISSUE 12) — see MLN._get_train_step
-            from ..obs.compiles import CompileSentinel
-            self._train_step = CompileSentinel(
-                "cg_train_step",
-                jax.jit(step, donate_argnums=(0, 1, 2)))
+            self._train_step, _ = build_train_step(self, "cg_train_step")
         return self._train_step
 
-    def enable_gradient_anomaly_detection(self, detector=None):
-        """See MultiLayerNetwork.enable_gradient_anomaly_detection."""
-        from ..train.anomaly import GradientAnomalyDetector
-        if detector is False:
-            self._anomaly_detector = None
-        else:
-            self._anomaly_detector = detector or GradientAnomalyDetector()
-        self._train_step = None
-        self._scan_epoch = None
-        return self
+    # the module-level function, bound as a method
+    enable_gradient_anomaly_detection = enable_gradient_anomaly_detection
 
     # ------------------------------------------------------------------ fit
     def fit(self, data, *, epochs: int = 1):
-        """fit(MultiDataSetIterator | MultiDataSet | DataSet | iterator)."""
+        """fit(MultiDataSetIterator | MultiDataSet | DataSet | iterator).
+        The loop is ``_fit_common.fit_epochs``, as for MultiLayerNetwork."""
         from ..data.dataset import DataSet, MultiDataSet
         if isinstance(data, (DataSet, MultiDataSet)):
             iterator = [data]
@@ -575,27 +537,24 @@ class ComputationGraph:
             except TypeError:
                 ipe = 1
             self._build_optimizer(max(int(ipe), 1))
-        step_fn = self._get_train_step()
-        last = None
-        anomaly_check = None
-        if getattr(self, "_anomaly_detector", None) is not None:
-            from ..train.anomaly import DelayedAnomalyCheck
-            anomaly_check = DelayedAnomalyCheck(self._anomaly_detector)
-        # async batch prep on a background thread, like MultiLayerNetwork.fit
-        # (DL4J wraps both fit entry points the same way); started inside
-        # the root span, so the producer's spans join this call's trace
-        from ..data.async_iter import maybe_wrap_async
-        with span("fit", attrs={"epochs": epochs}):
-            run_iter, wrapped = maybe_wrap_async(iterator)
-            try:
-                last = self._fit_epochs(run_iter, iterator, wrapped, epochs,
-                                        step_fn, anomaly_check)
-            finally:
-                if wrapped is not None:
-                    wrapped.close()
-        if anomaly_check is not None:
-            anomaly_check.flush()
-        return None if last is None else float(last)
+
+        def to_device(ds):
+            if isinstance(ds, MultiDataSet):
+                feats, labs = ds.features, ds.labels
+                fmask = None if ds.features_masks is None else ds.features_masks[0]
+                lmask = None if ds.labels_masks is None else ds.labels_masks[0]
+            else:
+                feats, labs = [ds.features], [ds.labels]
+                fmask, lmask = ds.features_mask, ds.labels_mask
+            inputs = {n: jnp.asarray(f) for n, f in zip(self.conf.inputs, feats)}
+            return next(iter(inputs.values())).shape[0], (
+                inputs,
+                {n: jnp.asarray(l) for n, l in zip(self.conf.outputs, labs)},
+                None if fmask is None else jnp.asarray(fmask),
+                None if lmask is None else jnp.asarray(lmask))
+
+        return fit_epochs(self, iterator, epochs, self._get_train_step(),
+                          to_device)
 
     def fit_scanned(self, data, *, epochs: int = 1):
         """One jit dispatch per epoch: ``lax.scan`` of the train step over
@@ -629,7 +588,6 @@ class ComputationGraph:
         if len(shapes) > 1:
             raise ValueError("fit_scanned needs equally-shaped batches; "
                              "use fit()")
-        from ._scan_common import check_scan_listeners
         check_scan_listeners(self)
         if not self.initialized:
             self.init([tuple(np.asarray(f).shape[1:])
@@ -640,124 +598,8 @@ class ComputationGraph:
               for i, n in enumerate(self.conf.inputs)}
         ys = {n: jnp.stack([jnp.asarray(ls[i]) for _, ls in pairs])
               for i, n in enumerate(self.conf.outputs)}
-        step_fn = self._get_train_step()
-
-        if self._scan_epoch is None:
-            def scan_epoch(params, states, opt_state, rng, xs, ys):
-                def body(carry, xy):
-                    p, s, o, k = carry
-                    x, y = xy
-                    p, s, o, loss, _, k = step_fn.__wrapped__(
-                        p, s, o, x, y, k, None, None)
-                    return (p, s, o, k), loss
-                (params, states, opt_state, rng), losses = lax.scan(
-                    body, (params, states, opt_state, rng), (xs, ys))
-                return params, states, opt_state, rng, losses
-            self._scan_epoch = jax.jit(scan_epoch, donate_argnums=(0, 1, 2))
-        losses = None
-        for _ in range(epochs):
-            (self.params, self.states, self._opt_state, self._host_key,
-             losses) = self._scan_epoch(self.params, self.states,
-                                        self._opt_state, self._host_key,
-                                        xs, ys)
-            self._step_count += len(batches)
-            self.epoch_count += 1
-            from ._scan_common import replay_scan_listeners
-            replay_scan_listeners(self, losses, len(batches))
-        return float(np.asarray(losses)[-1])
-
-    def _fit_epochs(self, run_iter, source_iter, wrapped, epochs, step_fn,
-                    anomaly_check):
-        """The epoch loop, one batch staged ahead. One ``fit.iteration``
-        span a pass (attrs ``batch``: the k-th batch of this call, the one
-        the pass dispatches, and ``examples``) whose children, in order, are
-        ``fit.dispatch`` (the step call on batch k's device arrays),
-        ``fit.next`` and ``fit.h2d`` of batch k+1 (until the iterator hands
-        it over; the ``jnp.asarray`` copies, attrs ``bytes``),
-        ``fit.loss_sync`` (``float(loss)`` of step k, where listeners ask
-        for it) and ``fit.listeners`` of step k. Every child carries its own
-        ``batch``, so ``fit.next`` and ``fit.h2d`` of batch k+1 lie in the
-        pass of batch k: its copy runs beside step k, and the listeners of
-        step k still see the parameters as step k left them, before step
-        k+1 is dispatched. The epoch's first ``fit.next`` and ``fit.h2d``
-        lie directly under ``fit``. The pass whose ``fit.next`` finds the
-        iterator exhausted, the epoch's last batch's, carries ``end`` (an
-        epoch without a batch leaves none). An exception out of that fetch
-        reaches the caller after step k's ``fit.loss_sync`` and
-        ``fit.listeners``. Two batches are resident on the device at a
-        time, the one in the step and the one staged."""
-        from ..data.dataset import MultiDataSet as MDS
-
-        def to_device(ds):
-            if isinstance(ds, MDS):
-                feats, labs = ds.features, ds.labels
-                fmask = None if ds.features_masks is None else ds.features_masks[0]
-                lmask = None if ds.labels_masks is None else ds.labels_masks[0]
-            else:
-                feats, labs = [ds.features], [ds.labels]
-                fmask, lmask = ds.features_mask, ds.labels_mask
-            return ({n: jnp.asarray(f) for n, f in zip(self.conf.inputs, feats)},
-                    {n: jnp.asarray(l) for n, l in zip(self.conf.outputs, labs)},
-                    None if fmask is None else jnp.asarray(fmask),
-                    None if lmask is None else jnp.asarray(lmask))
-
-        n_batches, n_ahead = fit_counters()
-        last = None
-        k = 0
-        for e in range(epochs):
-            batches = iter(run_iter)
-            staged = stage_batch(batches, k, to_device)
-            while staged is not None:
-                with span("fit.iteration", attrs={"batch": k}) as iteration:
-                    # `held`: batch k's host arrays, referenced until the
-                    # next pass, so past the sync of the step that reads
-                    # their copy (see stage_batch)
-                    held, (inputs, labels, fm, lm) = staged
-                    # examples-throughput telemetry (MetricsListener)
-                    self._last_batch_size = int(next(iter(inputs.values())).shape[0])
-                    iteration.set_attr("examples", self._last_batch_size)
-                    with span("fit.dispatch", attrs={"batch": k}):
-                        (self.params, self.states, self._opt_state, loss,
-                         gstats, self._host_key) = step_fn(
-                            self.params, self.states, self._opt_state, inputs,
-                            labels, self._host_key, fm, lm)
-                    self._step_count += 1
-                    n_batches.inc()
-                    if anomaly_check is not None and gstats is not None:
-                        anomaly_check.push(gstats, self._step_count)
-                    last = loss
-                    try:
-                        # batch k+1 crosses to the device while step k runs
-                        staged = stage_batch(batches, k + 1, to_device)
-                        if staged is None:
-                            iteration.set_attr("end", True)
-                        else:
-                            n_ahead.inc()
-                    finally:
-                        # step k's report, also where the iterator raised
-                        if self.listeners:
-                            with span("fit.loss_sync", attrs={"batch": k}):
-                                lv = float(loss)
-                            with span("fit.listeners", attrs={"batch": k}):
-                                for listener in self.listeners:
-                                    listener.iteration_done(self, self._step_count, self.epoch_count, lv)
-                    k += 1
-            self.epoch_count += 1
-            if e < epochs - 1:
-                if hasattr(run_iter, "reset"):
-                    run_iter.reset()
-            elif wrapped is not None:
-                # final epoch: close the wrapper FIRST so reset doesn't
-                # spin up a producer whose prefetch is thrown away
-                wrapped.close()
-                if hasattr(source_iter, "reset"):
-                    source_iter.reset()
-            elif hasattr(run_iter, "reset"):
-                run_iter.reset()
-            for listener in self.listeners:
-                if hasattr(listener, "on_epoch_end"):
-                    listener.on_epoch_end(self)
-        return last
+        return fit_scanned_epochs(
+            self, self, self._get_train_step().__wrapped__, xs, ys, epochs)
 
     def score(self, ds):
         from ..data.dataset import MultiDataSet as MDS

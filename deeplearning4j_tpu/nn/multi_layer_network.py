@@ -13,18 +13,16 @@ optax. Listeners run on host between steps.
 
 from __future__ import annotations
 
-import time
 from typing import Any, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 import numpy as np
-import optax
 
-from ..obs.spans import span
 from ..train.updaters import NoOp, build_optimizer, gradient_normalization
-from ._fit_common import fit_counters, stage_batch
+from ._fit_common import (build_train_step,
+                          enable_gradient_anomaly_detection, fit_epochs)
+from ._scan_common import check_scan_listeners, fit_scanned_epochs
 from .conf import MultiLayerConfiguration
 from .layers.base import Ctx, Layer
 from .layers.wrappers import unwrap
@@ -356,69 +354,19 @@ class MultiLayerNetwork:
 
     def _get_train_step(self):
         if self._train_step is None:
-            optimizer = self._optimizer
-            with_stats = getattr(self, "_anomaly_detector", None) is not None
-            # numerics sentinel (ISSUE 13): a detector with
-            # gate_updates=False (policy "warn") observes grad stats
-            # WITHOUT the in-jit finiteness gate — the poisoned update
-            # is applied, which is exactly what "warn" promises
-            gate = with_stats and getattr(self._anomaly_detector,
-                                          "gate_updates", True)
-
-            def step(params, states, opt_state, x, y, rng, fmask, lmask):
-                # the per-step key split happens INSIDE the jitted step and
-                # the next chain key rides the outputs: the fit loop never
-                # dispatches a separate host-side split per batch (a real
-                # extra device launch per step)
-                use_rng, next_rng = jax.random.split(rng)
-                (loss, new_states), grads = jax.value_and_grad(
-                    self._loss, has_aux=True)(params, states, x, y, use_rng,
-                                              fmask, lmask)
-                updates, new_opt_state = optimizer.update(grads, opt_state, params)
-                new_params = self._apply_constraints(
-                    optax.apply_updates(params, updates))
-                stats = None
-                if with_stats:
-                    # A non-finite batch becomes a whole-step no-op (params,
-                    # opt state, BN running stats) so the detector can raise
-                    # without the run already being poisoned.
-                    from ..train.anomaly import maybe_stats_and_gate
-                    stats, new_params, new_opt_state, new_states = \
-                        maybe_stats_and_gate(
-                            gate, grads, params, new_params, opt_state,
-                            new_opt_state, states, new_states)
-                return new_params, new_states, new_opt_state, loss, stats, next_rng
-
-            # compile sentinel (ISSUE 12): counts/times every compile of
-            # the donated step and warns on post-warmup retraces — the
-            # wrapper is transparent (fit_scanned's `.__wrapped__` and
-            # floor probes' `.lower` delegate through)
-            from ..obs.compiles import CompileSentinel
-            self._train_step = CompileSentinel(
-                "mln_train_step",
-                jax.jit(step, donate_argnums=(0, 1, 2)))
+            self._train_step, _ = build_train_step(self, "mln_train_step")
         return self._train_step
 
-    def enable_gradient_anomaly_detection(self, detector=None):
-        """Failure detection (SURVEY §2.9): per-layer gradient stats computed
-        inside the jitted step, checked host-side each iteration. Pass a
-        configured ``train.anomaly.GradientAnomalyDetector`` or None for
-        defaults. Call with detector=False to disable."""
-        from ..train.anomaly import GradientAnomalyDetector
-        if detector is False:
-            self._anomaly_detector = None
-        else:
-            self._anomaly_detector = detector or GradientAnomalyDetector()
-        self._train_step = None  # rebuild with/without stats
-        self._scan_epoch = None
-        return self
+    # the module-level function, bound as a method
+    enable_gradient_anomaly_detection = enable_gradient_anomaly_detection
 
     # ------------------------------------------------------------------ fit
     def fit(self, data, labels=None, *, epochs: int = 1):
         """fit(DataSetIterator) | fit(DataSet) | fit(features, labels).
 
         Reference: MultiLayerNetwork.fit — one optimizer step per minibatch,
-        listeners invoked per iteration, epoch counter maintained.
+        listeners invoked per iteration, epoch counter maintained. The loop
+        is ``_fit_common.fit_epochs``.
         """
         from ..data.dataset import DataSet
         if labels is not None:
@@ -445,140 +393,16 @@ class MultiLayerNetwork:
                     jax.tree_util.tree_structure(self._opt_state),
                     jax.tree_util.tree_leaves(restored))
                 self._restored_opt_state = None
-        step_fn = self._get_train_step()
-        anomaly_check = None
-        if getattr(self, "_anomaly_detector", None) is not None:
-            from ..train.anomaly import DelayedAnomalyCheck
-            anomaly_check = DelayedAnomalyCheck(self._anomaly_detector)
-
-        with span("fit", attrs={"epochs": epochs}):
-            last = self._fit_epochs(iterator, epochs, step_fn, anomaly_check)
-        if anomaly_check is not None:
-            anomaly_check.flush()
-        return None if last is None else float(last)
-
-    def _fit_epochs(self, iterator, epochs, step_fn, anomaly_check):
-        """The epoch loop, one batch staged ahead, under the same span names
-        at the same places as ``ComputationGraph._fit_epochs``: one
-        ``fit.iteration`` a pass (attrs ``batch``, ``examples``; ``end`` on
-        the epoch's last batch's pass, whose ``fit.next`` finds the iterator
-        exhausted) over ``fit.dispatch`` of batch k, ``fit.next`` and
-        ``fit.h2d`` of batch k+1 (the epoch's first pair directly under
-        ``fit``), then ``fit.loss_sync`` and ``fit.listeners`` of step k,
-        each carrying its own ``batch``. Where the score fetch is deferred,
-        the pass of batch k ends with the ``fit.loss_sync`` and
-        ``fit.listeners`` of batch k-1 instead, and the epoch's last pair
-        lies directly under ``fit``. Either way batch k+1's copy is issued
-        before the host waits for a loss, an exception out of its fetch
-        reaches the caller after every finished step's report, and two
-        batches are resident on the device at a time."""
-        # DL4J's fit wraps the source in an AsyncDataSetIterator so batch
-        # prep runs on a background thread while the device computes; do
-        # the same when the iterator opts in (async_supported).
-        from ..data.async_iter import maybe_wrap_async
-        run_iter, wrapped = maybe_wrap_async(iterator)
-
-        # Listener score fetches are deferred ONE iteration when every
-        # attached listener opts in (`deferred_score_ok`, the pure logging
-        # ones): float(loss) blocks until the step finishes, so fetching
-        # step k-1's loss while step k is in flight keeps the device
-        # pipeline full. Listeners that read model state at the reported
-        # iteration (checkpointing, eval, NaN watchdog) keep the exact
-        # synchronous semantics — params must match the (step, score) pair.
-        defer_ok = all(getattr(ls, "deferred_score_ok", False)
-                       for ls in self.listeners)
-        pending = None
-        last = None
-        k = 0
-
-        def report(loss_d, si, ei, batch):
-            with span("fit.loss_sync", attrs={"batch": batch}):
-                lv = float(loss_d)
-            with span("fit.listeners", attrs={"batch": batch}):
-                for listener in self.listeners:
-                    listener.iteration_done(self, si, ei, lv)
-
-        def flush_pending():
-            nonlocal pending
-            if pending is not None:
-                args, pending = pending, None
-                report(*args)
 
         def to_device(ds):
-            return (jnp.asarray(ds.features), jnp.asarray(ds.labels),
-                    None if ds.features_mask is None else jnp.asarray(ds.features_mask),
-                    None if ds.labels_mask is None else jnp.asarray(ds.labels_mask))
+            x = jnp.asarray(ds.features)
+            return x.shape[0], (
+                x, jnp.asarray(ds.labels),
+                None if ds.features_mask is None else jnp.asarray(ds.features_mask),
+                None if ds.labels_mask is None else jnp.asarray(ds.labels_mask))
 
-        n_batches, n_ahead = fit_counters()
-        try:
-            for e in range(epochs):
-                batches = iter(run_iter)
-                staged = stage_batch(batches, k, to_device)
-                while staged is not None:
-                    with span("fit.iteration", attrs={"batch": k}) as iteration:
-                        # `held`: batch k's host arrays, referenced until
-                        # the next pass (see stage_batch)
-                        held, (x, y, fmask, lmask) = staged
-                        # examples-throughput telemetry (MetricsListener)
-                        self._last_batch_size = int(x.shape[0])
-                        iteration.set_attr("examples", self._last_batch_size)
-                        with span("fit.dispatch", attrs={"batch": k}):
-                            (self.params, self.states, self._opt_state, loss,
-                             gstats, self._host_key) = step_fn(
-                                self.params, self.states, self._opt_state, x,
-                                y, self._host_key, fmask, lmask)
-                        self._step_count += 1
-                        n_batches.inc()
-                        if anomaly_check is not None and gstats is not None:
-                            anomaly_check.push(gstats, self._step_count)
-                        last = loss
-                        try:
-                            # batch k+1 crosses to the device while step k runs
-                            staged = stage_batch(batches, k + 1, to_device)
-                            if staged is None:
-                                iteration.set_attr("end", True)
-                            else:
-                                n_ahead.inc()
-                        finally:
-                            # step k's report, also where the iterator raised
-                            if self.listeners:
-                                if defer_ok:
-                                    # step k-1's loss, while step k is in flight
-                                    flush_pending()
-                                    pending = (loss, self._step_count,
-                                               self.epoch_count, k)
-                                else:
-                                    report(loss, self._step_count,
-                                           self.epoch_count, k)
-                        k += 1
-                self.epoch_count += 1
-                if e < epochs - 1:
-                    if hasattr(run_iter, "reset"):
-                        run_iter.reset()
-                elif wrapped is not None:
-                    # final epoch: close the wrapper FIRST so reset doesn't
-                    # spin up a producer whose prefetch is thrown away
-                    wrapped.close()
-                    wrapped = None
-                    if hasattr(iterator, "reset"):
-                        iterator.reset()
-                elif hasattr(run_iter, "reset"):
-                    run_iter.reset()
-                flush_pending()   # all iteration_done before on_epoch_end
-                for listener in self.listeners:
-                    if hasattr(listener, "on_epoch_end"):
-                        listener.on_epoch_end(self)
-        finally:
-            # a mid-epoch exception must still deliver the completed step's
-            # deferred callback (scores would end one step short) — but it
-            # must never MASK the original error, and runs before close()
-            try:
-                flush_pending()
-            except Exception:  # noqa: BLE001 — original exception wins
-                pass
-            if wrapped is not None:
-                wrapped.close()
-        return last
+        return fit_epochs(self, iterator, epochs, self._get_train_step(),
+                          to_device)
 
     def fit_scanned(self, data, *, epochs: int = 1):
         """TPU-idiomatic epoch loop: ONE jit dispatch per epoch.
@@ -620,7 +444,6 @@ class MultiLayerNetwork:
         if len(shapes) > 1:
             raise ValueError(f"fit_scanned needs equally-shaped batches, "
                              f"got {sorted(shapes)}; use fit()")
-        from ._scan_common import check_scan_listeners
         check_scan_listeners(self)
         if not self.initialized:
             self.init(tuple(np.asarray(batches[0].features).shape[1:]))
@@ -629,31 +452,8 @@ class MultiLayerNetwork:
             self._build_optimizer(self._iters_per_epoch)
         xs = jnp.stack([jnp.asarray(b.features) for b in batches])
         ys = jnp.stack([jnp.asarray(b.labels) for b in batches])
-        step_fn = self._get_train_step()
-
-        if self._scan_epoch is None:
-            def scan_epoch(params, states, opt_state, rng, xs, ys):
-                def body(carry, xy):
-                    p, s, o, k = carry
-                    x, y = xy
-                    p, s, o, loss, _, k = step_fn.__wrapped__(
-                        p, s, o, x, y, k, None, None)
-                    return (p, s, o, k), loss
-                (params, states, opt_state, rng), losses = lax.scan(
-                    body, (params, states, opt_state, rng), (xs, ys))
-                return params, states, opt_state, rng, losses
-            self._scan_epoch = jax.jit(scan_epoch, donate_argnums=(0, 1, 2))
-        losses = None
-        for _ in range(epochs):
-            (self.params, self.states, self._opt_state, self._host_key,
-             losses) = self._scan_epoch(self.params, self.states,
-                                        self._opt_state, self._host_key,
-                                        xs, ys)
-            self._step_count += len(batches)
-            self.epoch_count += 1
-            from ._scan_common import replay_scan_listeners
-            replay_scan_listeners(self, losses, len(batches))
-        return float(np.asarray(losses)[-1])
+        return fit_scanned_epochs(
+            self, self, self._get_train_step().__wrapped__, xs, ys, epochs)
 
     # ---------------------------------------------------------------- score
     def score(self, dataset=None):

@@ -21,10 +21,10 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
-from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..nn._fit_common import build_train_step, fit_epochs
+from ..nn._scan_common import check_scan_listeners, fit_scanned_epochs
 from ..obs import get_registry
 from .mesh import data_parallel_mesh, shard_params_fsdp
 
@@ -69,7 +69,7 @@ class ParallelWrapper:
     """Data-parallel trainer over a mesh's 'dp' (and optional 'fsdp') axis."""
 
     def __init__(self, net, mesh: Optional[Mesh] = None, use_fsdp: bool = False,
-                 prefetch_buffer: int = 2, drift_audit: bool = True):
+                 drift_audit: bool = True):
         if not net.initialized:
             raise ValueError("initialize the network first (net.init(...))")
         self.net = net
@@ -79,6 +79,7 @@ class ParallelWrapper:
         # each fit call (dl4j_replica_* — the dp lockstep audit)
         self.drift_audit = bool(drift_audit)
         self._step = None
+        self._scan_epoch = None
         self._rep = NamedSharding(self.mesh, P())
         batch_axes = tuple(a for a in ("dp", "fsdp") if a in self.mesh.axis_names)
         self._batch_sh = NamedSharding(self.mesh, P(batch_axes or None))
@@ -115,68 +116,63 @@ class ParallelWrapper:
         return self.mesh.size
 
     def _build_step(self):
-        if self.net._optimizer is None:
-            self.net._build_optimizer(1)
-            # re-place fresh opt state
-            self.net._opt_state = jax.tree_util.tree_map(
-                lambda a: jax.device_put(a, self._rep), self.net._opt_state)
-        optimizer = self.net._optimizer
         net = self.net
-        with_stats = getattr(net, "_anomaly_detector", None) is not None
-        # numerics sentinel (ISSUE 13) — see MLN._get_train_step
-        gate = with_stats and getattr(net._anomaly_detector,
-                                      "gate_updates", True)
-        self._step_with_stats = (with_stats, gate)
-        # the compiled step traced net._loss, which routes on the net's
-        # remat policy — record it so a later toggle forces a rebuild
-        self._built_remat = getattr(net, "remat_segments", None)
-
-        def step(params, states, opt_state, x, y, rng, fmask, lmask):
-            # split inside jit; next key rides the outputs (no separate
-            # host-side split dispatch per batch — see MLN._get_train_step)
-            use_rng, next_rng = jax.random.split(rng)
-            (loss, new_states), grads = jax.value_and_grad(
-                net._loss, has_aux=True)(params, states, x, y, use_rng,
-                                         fmask, lmask)
-            updates, new_opt_state = optimizer.update(grads, opt_state, params)
-            new_params = optax.apply_updates(params, updates)
-            stats = None
-            if with_stats:  # same failure-detection path as single-device fit
-                from ..train.anomaly import maybe_stats_and_gate
-                stats, new_params, new_opt_state, new_states = \
-                    maybe_stats_and_gate(
-                        gate, grads, params, new_params, opt_state,
-                        new_opt_state, states, new_states)
-            return new_params, new_states, new_opt_state, loss, stats, next_rng
-
-        self._step_raw = step    # unjitted: fit_scanned scans over it
-        from ..obs.compiles import CompileSentinel
-        self._step = CompileSentinel("pw_train_step", jax.jit(
-            step, donate_argnums=(0, 1, 2),
+        if net._optimizer is None:
+            net._build_optimizer(1)
+            # re-place fresh opt state
+            net._opt_state = jax.tree_util.tree_map(
+                lambda a: jax.device_put(a, self._rep), net._opt_state)
+        # the compiled step traced the net's detector, gate and remat policy
+        # (net._loss routes on it) — record them so a later toggle forces a
+        # rebuild
+        self._built_for = self._step_key()
+        # the single-device step (stats, gate, constraints and all), compiled
+        # over the mesh; `_step_raw`, unjitted, is what fit_scanned scans over
+        self._step, self._step_raw = build_train_step(
+            net, "pw_train_step",
             in_shardings=(self._param_sh,
                           jax.tree_util.tree_map(lambda _: self._rep, net.states),
                           None,  # opt state: let the compiler propagate
                           self._batch_sh, self._batch_sh, self._rep,
-                          self._batch_sh, self._batch_sh),
-            ))
+                          self._batch_sh, self._batch_sh))
         return self._step
 
-    def fit(self, iterator, *, epochs: int = 1):
-        net = self.net
-        want_stats = getattr(net, "_anomaly_detector", None) is not None
-        want = (want_stats, want_stats and getattr(
-            net._anomaly_detector, "gate_updates", True))
-        if self._step is not None and getattr(self, "_step_with_stats", None) != want:
-            self._step = None  # detector/gate toggled since compile — rebuild
+    def _step_key(self):
+        """What of the net a compiled step depends on beyond its shapes."""
+        det = getattr(self.net, "_anomaly_detector", None)
+        return (det is not None,
+                det is not None and getattr(det, "gate_updates", True),
+                getattr(self.net, "remat_segments", None))
+
+    def _current_step(self):
+        if self._step is not None and self._built_for != self._step_key():
+            self._step = None        # toggled since compile — rebuild
             self._scan_epoch = None  # scans over _step_raw — same staleness
-        if self._step is not None and getattr(self, "_built_remat", None) != \
-                getattr(net, "remat_segments", None):
-            self._step = None            # remat policy toggled — retrace
-            self._scan_epoch = None
-        step_fn = self._step or self._build_step()
-        m_batches = get_registry().counter(
-            "dl4j_parallel_fit_batches_total",
-            "Batches stepped through ParallelWrapper.fit")
+        return self._step or self._build_step()
+
+    def _to_device(self, ds):
+        """A batch as the step's arguments; a final partial batch is padded
+        to divide the mesh's batch axes, and counts the rows it came with."""
+        x, y, fmask, lmask = _unpack_batch(ds)
+        rows = (x[0] if isinstance(x, tuple) else x).shape[0]
+        if rows % self._batch_div:
+            # padding is host work — device-resident arrays fetch once here
+            # (partial final batch only); full batches pass straight through
+            # without a host bounce
+            pad = self._batch_div - rows % self._batch_div
+            x = jax.tree_util.tree_map(_padder(pad), x)
+            y = jax.tree_util.tree_map(_padder(pad), y)
+            # padded rows masked out entirely
+            fmask, lmask = (None if m is None else jax.tree_util.tree_map(
+                _padder(pad, zero=True), m) for m in (fmask, lmask))
+        # placed by the step's in_shardings, not here
+        return rows, jax.tree_util.tree_map(jnp.asarray, (x, y, fmask, lmask))
+
+    def fit(self, iterator, *, epochs: int = 1):
+        """``nn._fit_common.fit_epochs`` on the mesh-compiled step, between
+        the per-replica memory census and the replica drift audit."""
+        net = self.net
+        step_fn = self._current_step()
         # memory census (ISSUE 12), per replica: an fsdp-sharded param
         # tree reports what EACH device holds — the gauge the ZeRO
         # update-sharding PR (ROADMAP item 4) reads for its per-chip
@@ -192,52 +188,7 @@ class ParallelWrapper:
                                    per_replica=True)
         except Exception:  # noqa: BLE001 — census is decoration
             pass
-        last = None
-        n = self._batch_div
-        anomaly_check = None
-        if getattr(net, "_anomaly_detector", None) is not None:
-            from ..train.anomaly import DelayedAnomalyCheck
-            anomaly_check = DelayedAnomalyCheck(net._anomaly_detector)
-        for _ in range(epochs):
-            for ds in iterator:
-                x, y, fmask, lmask = _unpack_batch(ds)
-                multi = isinstance(x, tuple)
-                rows = (x[0] if multi else x).shape[0]
-                if rows % n:     # pad final partial batch to divide mesh
-                    # padding is host work — device-resident arrays fetch
-                    # once here (partial final batch only); full batches
-                    # pass straight through without a host bounce
-                    pad = n - rows % n
-                    x = jax.tree_util.tree_map(_padder(pad), x)
-                    y = jax.tree_util.tree_map(_padder(pad), y)
-                    if fmask is not None:  # padded rows masked out entirely
-                        fmask = jax.tree_util.tree_map(_padder(pad, zero=True),
-                                                       fmask)
-                    if lmask is not None:
-                        lmask = jax.tree_util.tree_map(_padder(pad, zero=True),
-                                                       lmask)
-                net._last_batch_size = rows  # telemetry: pre-pad rows
-                as_dev = lambda t: jax.tree_util.tree_map(jnp.asarray, t)
-                (net.params, net.states, net._opt_state, loss, gstats,
-                 net._host_key) = step_fn(
-                    net.params, net.states, net._opt_state,
-                    as_dev(x), as_dev(y), net._host_key,
-                    None if fmask is None else as_dev(fmask),
-                    None if lmask is None else as_dev(lmask))
-                net._step_count += 1
-                m_batches.inc()
-                if anomaly_check is not None and gstats is not None:
-                    anomaly_check.push(gstats, net._step_count)
-                last = loss
-                if net.listeners:
-                    lv = float(loss)
-                    for listener in net.listeners:
-                        listener.iteration_done(net, net._step_count, net.epoch_count, lv)
-            net.epoch_count += 1
-            if hasattr(iterator, "reset"):
-                iterator.reset()
-        if anomaly_check is not None:
-            anomaly_check.flush()
+        last = fit_epochs(net, iterator, epochs, step_fn, self._to_device)
         # drift audit (ISSUE 13): per-device checksums over the
         # replicated params at the end of every fit call — the dp
         # replicas hold COPIES of the same logical array and must be
@@ -250,7 +201,7 @@ class ParallelWrapper:
                 self.audit_drift()
             except Exception:  # noqa: BLE001 — audit is decoration
                 pass
-        return None if last is None else float(last)
+        return last
 
     def audit_drift(self):
         """Checksum every device's copy of the replicated params NOW
@@ -294,56 +245,20 @@ class ParallelWrapper:
                 f"batch size {batches[0].features.shape[0]} must divide the "
                 f"mesh batch axes ({self._batch_div}) — fit_scanned does "
                 "not pad")
-        from ..nn._scan_common import check_scan_listeners
         check_scan_listeners(net)
         if epochs <= 0:
             return None
-        if self._step is not None and (
-                getattr(self, "_built_remat", None) !=
-                getattr(net, "remat_segments", None)
-                or (getattr(self, "_step_with_stats", None)
-                    or (False,))[0]):
-            # remat policy toggled, or the cached step was compiled with
-            # anomaly-stats gating (detector since disabled) — retrace
-            self._step = None
-            self._scan_epoch = None
-        if self._step is None:
-            self._build_step()
-        step_raw = self._step_raw
+        self._current_step()
         xs = jnp.stack([jnp.asarray(b.features) for b in batches])
         ys = jnp.stack([jnp.asarray(b.labels) for b in batches])
-        if getattr(self, "_scan_epoch", None) is None:
-            def scan_epoch(params, states, opt_state, rng, xs, ys):
-                def body(carry, xy):
-                    p, s, o, k = carry
-                    x, y = xy
-                    p, s, o, loss, _, k = step_raw(p, s, o, x, y, k,
-                                                   None, None)
-                    return (p, s, o, k), loss
-                (params, states, opt_state, rng), losses = lax.scan(
-                    body, (params, states, opt_state, rng), (xs, ys))
-                return params, states, opt_state, rng, losses
-
-            # stacked batches: leading K axis replicated, batch axes sharded
-            stacked_sh = NamedSharding(self.mesh,
-                                       P(None, *self._batch_sh.spec))
-            self._scan_epoch = jax.jit(
-                scan_epoch, donate_argnums=(0, 1, 2),
-                in_shardings=(self._param_sh,
-                              jax.tree_util.tree_map(lambda _: self._rep,
-                                                     net.states),
-                              None, self._rep, stacked_sh, stacked_sh))
-        losses = None
-        for _ in range(epochs):
-            (net.params, net.states, net._opt_state, net._host_key,
-             losses) = self._scan_epoch(net.params, net.states,
-                                        net._opt_state, net._host_key,
-                                        xs, ys)
-            net._step_count += len(batches)
-            net.epoch_count += 1
-            from ..nn._scan_common import replay_scan_listeners
-            replay_scan_listeners(net, losses, len(batches))
-        return float(np.asarray(losses)[-1])
+        # stacked batches: leading K axis replicated, batch axes sharded
+        stacked_sh = NamedSharding(self.mesh, P(None, *self._batch_sh.spec))
+        return fit_scanned_epochs(
+            self, net, self._step_raw, xs, ys, epochs,
+            in_shardings=(self._param_sh,
+                          jax.tree_util.tree_map(lambda _: self._rep,
+                                                 net.states),
+                          None, self._rep, stacked_sh, stacked_sh))
 
 
 class ParallelInference:

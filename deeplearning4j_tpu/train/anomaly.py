@@ -78,8 +78,8 @@ def maybe_stats_and_gate(gate, grads, params, new_params, opt_state,
     that must leave a poisoned step bit-identical), plain
     :func:`grad_stats` with the step outputs passed through when it is
     not (observe-only detectors / sentinel policy "warn"). ``gate`` is
-    a trace-time Python bool — the three step builders resolve it from
-    the detector's ``gate_updates`` before compiling."""
+    a trace-time Python bool — the step builder (``nn/_fit_common.py``)
+    resolves it from the detector's ``gate_updates`` before compiling."""
     if gate:
         return stats_and_gate(grads, params, new_params, opt_state,
                               new_opt_state, states, new_states)

@@ -1,5 +1,8 @@
 """Spans and counters inside ``fit()`` and its async prefetch (ISSUE 26),
-and the loop's order since it stages one batch ahead (ISSUE 29).
+and the loop's order since it stages one batch ahead (ISSUE 29): one loop
+(``nn/_fit_common.py``) with three callers since ISSUE 30, so every case
+runs for ``MultiLayerNetwork``, ``ComputationGraph`` and ``ParallelWrapper``
+over either.
 
 One ``fit`` root per call; one ``fit.iteration`` per batch whose children
 lie inside it, in order, on one clock, the fetch and copy of batch k+1
@@ -27,7 +30,8 @@ from deeplearning4j_tpu.nn.layers.core import DenseLayer, OutputLayer
 from deeplearning4j_tpu.nn.listeners import TrainingListener
 from deeplearning4j_tpu.nn.multi_layer_network import MultiLayerNetwork
 
-N_BATCHES, BATCH = 5, 4
+#: a batch divides the 8-device mesh the ParallelWrapper cases run on
+N_BATCHES, BATCH = 5, 8
 #: the children of batch k's ``fit.iteration`` in the order the loop runs
 #: them, each with the batch it carries less k
 ORDER = [("fit.dispatch", 0), ("fit.next", 1), ("fit.h2d", 1),
@@ -74,9 +78,41 @@ def _mln():
     return MultiLayerNetwork(conf).init()
 
 
+class _Dp:
+    """``ParallelWrapper(net, dp=8).fit`` under the network's own names
+    (listeners, parameters and counts are the wrapped network's), so that
+    one test body drives all three callers of the loop."""
+
+    def __init__(self, net):
+        import jax
+        from deeplearning4j_tpu.parallel import ParallelWrapper, make_mesh
+        if len(jax.devices()) < 8:
+            pytest.skip("needs 8 virtual devices")
+        self.net = net
+        self.wrapper = ParallelWrapper(net, mesh=make_mesh(dp=8))
+
+    def __getattr__(self, name):
+        return getattr(self.net, name)
+
+    def fit(self, data, epochs=1):
+        from deeplearning4j_tpu.data.dataset import DataSet
+        return self.wrapper.fit([data] if isinstance(data, DataSet) else data,
+                                epochs=epochs)
+
+
+def _dp_graph():
+    return _Dp(_graph())
+
+
+def _dp_mln():
+    return _Dp(_mln())
+
+
 NETS = pytest.mark.parametrize(
-    "make_net", [_graph, _mln],
-    ids=["ComputationGraph", "MultiLayerNetwork"])
+    "make_net", [_graph, _mln, _dp_graph, _dp_mln],
+    ids=["ComputationGraph", "MultiLayerNetwork",
+         "ParallelWrapper-ComputationGraph",
+         "ParallelWrapper-MultiLayerNetwork"])
 
 
 def _fit_and_collect(net, epochs=1):
@@ -180,11 +216,11 @@ def test_fit_root_per_call_and_epochs():
                   if "batch" in s.attrs) == batches
 
 
-def test_mln_deferred_scores_keep_their_batch():
+@NETS
+def test_deferred_scores_keep_their_batch(make_net):
     """Logging listeners get step k-1's score while step k is in flight:
     the sync and listener spans say whose they are."""
-    net = _mln()
-
+    net = make_net()
     net.set_listeners(_Deferred())
     x, y = _data()
     tracer = obs.get_tracer()
@@ -206,12 +242,18 @@ class _Deferred(_Scores):
     deferred_score_ok = True
 
 
-#: both loops, and both branches of MultiLayerNetwork's
+#: the loop's three callers, and both of its branches under each
 LOOPS = pytest.mark.parametrize(
     "make_net, listener",
-    [(_graph, _Scores), (_mln, _Scores), (_mln, _Deferred)],
+    [(_graph, _Scores), (_mln, _Scores), (_mln, _Deferred),
+     (_graph, _Deferred), (_dp_graph, _Scores), (_dp_mln, _Scores),
+     (_dp_graph, _Deferred), (_dp_mln, _Deferred)],
     ids=["ComputationGraph", "MultiLayerNetwork",
-         "MultiLayerNetwork-deferred"])
+         "MultiLayerNetwork-deferred", "ComputationGraph-deferred",
+         "ParallelWrapper-ComputationGraph",
+         "ParallelWrapper-MultiLayerNetwork",
+         "ParallelWrapper-ComputationGraph-deferred",
+         "ParallelWrapper-MultiLayerNetwork-deferred"])
 
 
 def _leaves(net):
@@ -269,6 +311,44 @@ def test_listener_of_step_k_sees_the_parameters_of_step_k(make_net):
     assert [k for k, _ in want] == list(range(1, 2 * N_BATCHES + 1))
     assert net.listeners[0].seen == want
     assert len({leaf for _, leaf in want}) == len(want)     # each step moved it
+
+
+@LOOPS
+def test_on_epoch_end_follows_the_epochs_last_report(make_net, listener):
+    """Once an epoch, after that epoch's last ``iteration_done``, also
+    where the report is one step late."""
+    class Epochs(listener):
+        def __init__(self):
+            super().__init__()
+            self.ends = []
+
+        def on_epoch_end(self, model):
+            self.ends.append((len(self.scores), model.epoch_count))
+
+    x, y = _data()
+    net = make_net()
+    net.set_listeners(Epochs())
+    net.fit(ArrayDataSetIterator(x, y, BATCH), epochs=3)
+    assert net.listeners[0].ends \
+        == [(N_BATCHES, 1), (2 * N_BATCHES, 2), (3 * N_BATCHES, 3)]
+
+
+def test_parallel_partial_last_batch_counts_its_rows_before_padding():
+    """3 rows on the 8-device mesh are padded to 8 for the step;
+    ``examples`` and ``_last_batch_size`` say 3, ``bytes`` what was sent."""
+    net = _dp_mln()
+    x, y = _data()
+    rows = BATCH + 3
+    tracer = obs.get_tracer()
+    before = {id(s) for s in tracer.spans()}
+    net.fit(ArrayDataSetIterator(x[:rows], y[:rows], BATCH))
+    mine = sorted((s for s in tracer.spans() if id(s) not in before),
+                  key=lambda s: s.t0_ns)
+    assert [s.attrs["examples"] for s in mine if s.name == "fit.iteration"] \
+        == [BATCH, 3]
+    assert net._last_batch_size == 3 and net._step_count == 2
+    assert [s.attrs["bytes"] for s in mine if s.name == "fit.h2d"] \
+        == [BATCH * (6 + 3) * 4] * 2
 
 
 class _Breaks:

@@ -1045,6 +1045,80 @@ def test_parallel_wrapper_fit_scanned_matches_fit(devices8):
     assert pw_b.fit_scanned(dss, epochs=0) is None
 
 
+def _constrained_mlp():
+    from deeplearning4j_tpu.nn import (DenseLayer, MultiLayerNetwork,
+                                       NeuralNetConfiguration, OutputLayer)
+    from deeplearning4j_tpu.train import MaxNormConstraint, Sgd
+    conf = (NeuralNetConfiguration.builder().seed(7).updater(Sgd(0.5))
+            .constrain_weights(MaxNormConstraint(0.5, dims=0))
+            .list()
+            .layer(DenseLayer(n_in=6, n_out=12, activation="tanh"))
+            .layer(OutputLayer(n_in=12, n_out=3, activation="softmax",
+                               loss="mcxent"))
+            .build())
+    return MultiLayerNetwork(conf).init((6,))
+
+
+def _mlp_batches(n=4, rows=16):
+    from deeplearning4j_tpu.data.dataset import DataSet
+    rng = np.random.default_rng(2)
+    return [DataSet(jnp.asarray(rng.standard_normal((rows, 6)).astype(np.float32)),
+                    jnp.asarray(np.eye(3, dtype=np.float32)[
+                        rng.integers(0, 3, rows)]))
+            for _ in range(n)]
+
+
+def test_parallel_wrapper_applies_weight_constraints(devices8):
+    """The dp step is the net's own step (ISSUE 30): a max-norm constraint
+    holds under ParallelWrapper.fit, and the parameters end where the
+    net's own fit leaves them."""
+    from deeplearning4j_tpu.parallel import ParallelWrapper, make_mesh
+
+    dss = _mlp_batches()
+    single = _constrained_mlp()
+    single.fit(dss, epochs=3)
+    par = _constrained_mlp()
+    ParallelWrapper(par, mesh=make_mesh(dp=8)).fit(dss, epochs=3)
+    for key in ("layer_0", "layer_1"):
+        w = np.asarray(par.params[key]["W"])
+        assert np.linalg.norm(w, axis=0).max() <= 0.5 + 1e-5
+        # the constraint binds: the test would pass without it otherwise
+        assert np.linalg.norm(w, axis=0).max() >= 0.5 - 1e-4
+        for pk, v in single.params[key].items():
+            np.testing.assert_allclose(np.asarray(par.params[key][pk]),
+                                       np.asarray(v), rtol=2e-4, atol=1e-5)
+
+
+def test_parallel_wrapper_calls_on_epoch_end(devices8):
+    """Once an epoch, after that epoch's last iteration_done, with the
+    wrapped net as the model, like the net's own fit."""
+    from deeplearning4j_tpu.nn.listeners import TrainingListener
+    from deeplearning4j_tpu.parallel import ParallelWrapper, make_mesh
+
+    class Order(TrainingListener):
+        def __init__(self):
+            self.events = []
+
+        def iteration_done(self, model, iteration, epoch, score):
+            self.events.append(("iteration", iteration, epoch))
+
+        def on_epoch_end(self, model):
+            self.events.append(("epoch_end", model._step_count,
+                                model.epoch_count))
+
+    dss = _mlp_batches(n=3)
+    want = []
+    for fit_of in (lambda net: net.fit,
+                   lambda net: ParallelWrapper(net, mesh=make_mesh(dp=8)).fit):
+        net = _constrained_mlp()
+        net.set_listeners(Order())
+        fit_of(net)(dss, epochs=2)
+        want.append(net.listeners[0].events)
+    assert want[0] == want[1] == (
+        [("iteration", i, 0) for i in (1, 2, 3)] + [("epoch_end", 3, 1)]
+        + [("iteration", i, 1) for i in (4, 5, 6)] + [("epoch_end", 6, 2)])
+
+
 def test_generic_pipeline_dropout_rng(devices8):
     """Dropout in the generic pipeline: rng engages per-microbatch masks
     (loss changes vs rng=None and varies across keys); rng=None keeps the
