@@ -513,10 +513,10 @@ def build_transformer(batch, cfg):
     # dq pass 3 + dkv pass 4 = 9 matmuls of 2*B*H*T*T*D, halved causal)
     # so flash-row MFU counts the T^2 work actually done. The engagement
     # test is the model's own gate (tfm.flash_engages), not a copy.
-    # Known asymmetry (ADVICE r5 #2): under remat the pallas fwd re-runs
-    # to rebuild vjp residuals (~2 extra matmuls/layer for save_attn and
-    # full alike), which this top-up does NOT count — while the XLA
-    # path's remat recompute IS in the jaxpr and counted. Flash rows'
+    # Known asymmetry (ADVICE r5 #2): under remat "full" the pallas fwd
+    # re-runs to rebuild vjp residuals (~2 extra matmuls/layer; "save_attn"
+    # keeps them and does not), which this top-up does NOT count — while
+    # the XLA path's remat recompute IS in the jaxpr and counted. Flash rows'
     # MFU is therefore slightly UNDERstated relative to XLA rows when
     # cfg.remat is on; left uncounted deliberately (conservative skew —
     # the flash wins in PERF.md survive the handicap).

@@ -151,8 +151,9 @@ def _run_lm_steps(cfg, params, ids, tgt, steps):
     opt_state = opt.init(params)
     step = jax.jit(tfm.make_train_step(cfg, opt), donate_argnums=(0, 1))
     t0 = time.perf_counter()
-    lowered = step.lower(params, opt_state, ids, tgt)   # flash blocks are
-    t1 = time.perf_counter()                            # raced in here
+    traced = step.trace(params, opt_state, ids, tgt)    # flash blocks are
+    lowered = traced.lower()                            # raced in here
+    t1 = time.perf_counter()
     compiled = lowered.compile()
     t2 = time.perf_counter()
     losses, step_s = [], []
@@ -169,6 +170,11 @@ def _run_lm_steps(cfg, params, ids, tgt, steps):
         "steady_step_ms": _ms(statistics.median(step_s[1:] or step_s)),
         "attention_path": tfm.attention_path(cfg, cfg.max_seq, cfg.dtype),
         "flash_in_program": "tpu_custom_call" in compiled.as_text(),
+        # Pallas calls a layer in the step as traced (the scan's bodies hold
+        # one period of layers): forward, dq and dkv are 3; 4 means the
+        # backward pass runs the forward kernel again (remat "full")
+        "flash_calls_per_layer": str(traced.jaxpr).count("pallas_call[")
+        / len(cfg.layer_kinds),
     }
     return obs, losses, params
 
@@ -195,6 +201,11 @@ def phase_train_lm(cfg, batch, steps=4, seed=0, require_flash=False):
                "train_lm: the flash kernel is not in the compiled program "
                f"(path {obs['attention_path']}, tpu_custom_call "
                f"{obs['flash_in_program']})")
+        if cfg.remat and cfg.remat_policy == "save_attn":
+            _check(obs["flash_calls_per_layer"] == 3,
+                   "train_lm: save_attn keeps the kernel's residuals, so a "
+                   "layer runs forward, dq and dkv once each; the step holds "
+                   f"{obs['flash_calls_per_layer']} Pallas calls a layer")
     return {"phase": "train_lm", "batch": batch, "seq": cfg.max_seq,
             "d_model": cfg.d_model, "n_layers": cfg.n_layers,
             "vocab": cfg.vocab_size, "flash_blocks": blocks, **obs,

@@ -62,9 +62,10 @@ class TransformerConfig:
     #           cheap recompute only)
     # "dots_no_batch" — dots_with_no_batch_dims_saveable (saves the
     #           small contraction results, not the big batched ones)
-    # "save_attn" — save only the attention outputs (checkpoint_name
-    #           "attn_out"), recompute the rest: remat-full's HBM saving
-    #           without re-running the T² attention op in backward
+    # "save_attn" — keep what attention produced (checkpoint_name
+    #           "attn_out"; on the flash path also the kernel's
+    #           log-sum-exp, "attn_lse"), recompute the rest: remat-full's
+    #           HBM saving with attention run once a layer
     remat_policy: str = "full"
     use_ring_attention: bool = False
     # True = always pallas flash kernel (TPU single-chip); False = XLA fused
@@ -356,7 +357,8 @@ def _attention(cfg, q, k, v, mask_bias=None, positions="learned", window=0):
         out = jax.nn.dot_product_attention(
             q, k, v, is_causal=True,
             local_window_size=(window - 1, 0) if window else None)
-    out = checkpoint_name(out, "attn_out")  # remat_policy="save_attn" hook
+    if path != "flash":     # the flash kernel names its own output and lse
+        out = checkpoint_name(out, "attn_out")  # remat_policy="save_attn"
     return out.reshape(b, t, cfg.n_heads * cfg.head_dim)
 
 
@@ -404,16 +406,21 @@ def _remat_wrap(fn, policy: str):
         "dots": jax.checkpoint_policies.dots_saveable,
         "dots_no_batch":
             jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
-        # "save_attn": save ONLY the attention outputs (B·T·D bf16 — tiny,
-        # ~16 MB/layer at T=4096 b4) and recompute everything else. This
-        # spares the block's DOWNSTREAM recompute (mlp/norms feeding the
-        # loss side) from re-running attention; the gradient THROUGH
-        # attention still re-executes the kernel forward to rebuild its
-        # unsaved vjp residuals, so the win over remat-full is the
-        # downstream share only (measured ~2-3% tokens/s at T=1024-8192,
-        # scripts/diag_attn_r5_out.json — consistent, not dramatic).
+        # "save_attn": keep what attention produced and recompute everything
+        # else (norms, projections, the MLP) from the block's input. The
+        # XLA and ring paths name their output "attn_out" in `_attention`.
+        # The flash kernel names the two residuals of its custom_vjp that
+        # only it can rebuild (kernels/flash_attention.py::_flash_fwd): its
+        # output, "attn_out" (ONE saved copy, as (B, T, H, Dh): the same
+        # tensor the other paths name), and the (B, H, T) f32 log-sum-exp,
+        # "attn_lse". With both saved the backward scan runs the two
+        # backward kernels only: three Pallas calls a layer and step where
+        # "full" runs four (the forward twice); q, k and v are recomputed
+        # from the block's input either way. B*T*D bf16 + B*H*T f32 a
+        # layer (32 + 1 MiB at b16 T=1024 d1024).
         "save_attn":
-            jax.checkpoint_policies.save_only_these_names("attn_out"),
+            jax.checkpoint_policies.save_only_these_names("attn_out",
+                                                          "attn_lse"),
     }
     if policy not in policies:
         raise ValueError(f"Unknown remat_policy {policy!r}; "
@@ -937,8 +944,8 @@ class BertConfig:
     # r5: the transformer-LM sweep's two HBM cuts, applied to the encoder
     # (VERDICT r4 item 5). Defaults off = r4 behavior; bench flips both.
     remat: bool = False
-    # "full" | "dots" | "dots_no_batch" | "save_attn" (pin the attention
-    # outputs via checkpoint_name — see _remat_wrap)
+    # "full" | "dots" | "dots_no_batch" | "save_attn" (keep what attention
+    # produced, recompute the rest — see _remat_wrap)
     remat_policy: str = "full"
     attn_scores_bf16: bool = False
 

@@ -50,7 +50,24 @@ def test_phase_train_lm_tiny():
     assert rec["losses"][-1] < rec["losses"][0]
     assert rec["attention_path"] == "xla_sdpa"      # no flash off the chip
     assert rec["flash_in_program"] is False and rec["flash_blocks"] is None
+    assert rec["flash_calls_per_layer"] == 0
     assert rec["compile_s"] > 0 and rec["steady_step_ms"] > 0
+
+
+@pytest.mark.parametrize("policy,calls", [("save_attn", 3), ("full", 4)])
+def test_phase_train_lm_counts_flash_calls_per_layer(monkeypatch, policy,
+                                                     calls):
+    """With the kernels in the step (interpret mode here) the record says
+    how many Pallas calls a layer holds: under ``save_attn`` the backward
+    pass reads the saved output and lse, under ``full`` it runs the forward
+    kernel again."""
+    monkeypatch.setattr(jax, "device_count", lambda: 1)   # as on one chip
+    rec = chip_smoke.phase_train_lm(
+        _tiny_lm(fused_loss=True, remat=True, remat_policy=policy,
+                 use_flash_attention=True), batch=2, steps=2)
+    assert rec["attention_path"] == "flash"
+    assert rec["flash_calls_per_layer"] == calls
+    assert rec["losses"][-1] < rec["losses"][0]
 
 
 def test_phase_train_lm_requires_flash_when_asked():

@@ -60,6 +60,106 @@ def test_flash_kernels_carry_stable_names():
         assert name in jaxpr, name
 
 
+def _leaves_equal(a, b):
+    return all(bool(jnp.array_equal(x, y)) for x, y in
+               zip(jax.tree_util.tree_leaves(a), jax.tree_util.tree_leaves(b)))
+
+
+@pytest.mark.parametrize("kind,hkv,window", [
+    ("plain", 4, None), ("plain", 4, 20), ("plain", 2, 20), ("lse", 4, None),
+], ids=["causal", "causal_window", "grouped_kv_window", "with_lse"])
+def test_save_attn_runs_the_flash_forward_once_a_block(kind, hkv, window):
+    """The kernel names its output and log-sum-exp; the policy that
+    ``_remat_wrap("save_attn")`` builds keeps both, so a scanned block's
+    gradient holds forward, dq and dkv once each. ``"full"`` saves nothing
+    and runs the forward kernel again. Either way the gradient is the one
+    that no ``jax.checkpoint`` gives, to the bit."""
+    from deeplearning4j_tpu.kernels.flash_attention import flash_attention_lse
+    from deeplearning4j_tpu.zoo.transformer import _remat_wrap
+
+    b, h, t, d, width = 1, 4, 32, 8, 24
+
+    def attn(q, k, v):
+        if kind == "lse":
+            o, lse = flash_attention_lse(q, k, v, None, True, 16, 16, True)
+            return o * jnp.tanh(lse)[..., None]     # the lse has a cotangent
+        return flash_attention(q, k, v, None, True, 16, 16, True, window)
+
+    def block(x, w):
+        xn = jnp.tanh(x)
+        heads = lambda y, n: y.reshape(b, t, n, d).transpose(0, 2, 1, 3)  # noqa: E731,E501
+        o = attn(heads(xn @ w["q"], h), heads(xn @ w["k"], hkv),
+                 heads(xn @ w["v"], hkv))
+        return x + o.transpose(0, 2, 1, 3).reshape(b, t, h * d) @ w["o"], None
+
+    ks = jax.random.split(jax.random.PRNGKey(3), 5)
+    shapes = {"q": (width, h * d), "k": (width, hkv * d),
+              "v": (width, hkv * d), "o": (h * d, width)}
+    ws = {n: 0.2 * jax.random.normal(k_, (2, *shp))      # two scanned blocks
+          for k_, (n, shp) in zip(ks, shapes.items())}
+    x = jax.random.normal(ks[4], (b, t, width))
+
+    def grad_of(policy):
+        fn = block if policy is None else _remat_wrap(block, policy)
+        g = jax.grad(lambda ws_, x_: jnp.sum(jnp.sin(
+            jax.lax.scan(fn, x_, ws_)[0])), argnums=(0, 1))
+        return str(jax.make_jaxpr(g)(ws, x)).count("pallas_call["), g(ws, x)
+
+    n_plain, g_plain = grad_of(None)
+    n_attn, g_attn = grad_of("save_attn")
+    n_full, g_full = grad_of("full")
+    assert (n_plain, n_attn, n_full) == (3, 3, 4)
+    assert _leaves_equal(g_attn, g_plain)
+    assert _leaves_equal(g_full, g_plain)
+
+
+def test_save_attn_through_the_transformer_saves_one_output_and_one_lse(
+        monkeypatch, capsys):
+    """``zoo.transformer`` with the kernels in the path (interpret mode), a
+    stack of two kinds of layer, ``remat_policy="save_attn"``: the loss and
+    every gradient equal the ones without rematerialization, and what the
+    backward pass is handed per layer is ONE copy of the attention output,
+    as (B, T, H, Dh), and the kernel's (B, H, T) lse."""
+    from deeplearning4j_tpu.zoo import transformer as tfm
+    monkeypatch.setattr(jax, "device_count", lambda: 1)   # as on one chip
+
+    def cfg_of(**over):
+        kw = dict(vocab_size=50, d_model=48, n_heads=4, n_kv_heads=2,
+                  head_size=8, n_layers=4, d_ff=40, max_seq=32,
+                  dtype=jnp.float32, layer_positions=("none", "rope"),
+                  layer_windows=(0, 20), use_flash_attention=True,
+                  fused_loss=False, remat=True, remat_policy="save_attn")
+        return tfm.TransformerConfig(**{**kw, **over})
+
+    cfg = cfg_of()
+    assert tfm.attention_path(cfg, 32, jnp.float32) == "flash"
+    params = tfm.init_params(jax.random.PRNGKey(0), cfg)
+    ids = jax.random.randint(jax.random.PRNGKey(1), (2, 32), 0, 50)
+    tgt = jnp.roll(ids, -1, 1)
+    loss = lambda p, c: tfm.lm_loss(p, c, ids, tgt)       # noqa: E731
+    got, g_got = jax.value_and_grad(loss)(params, cfg)
+    want, g_want = jax.value_and_grad(loss)(params, cfg_of(remat=False))
+    assert float(got) == float(want)
+    assert _leaves_equal(g_got, g_want)
+
+    def saved(c):
+        capsys.readouterr()
+        jax.ad_checkpoint.print_saved_residuals(lambda p: loss(p, c), params)
+        shapes = [line.split()[0] for line in
+                  capsys.readouterr().out.splitlines() if " scan " in line]
+        # stacked over the 2 periods; one line per layer of the period:
+        # the output as (B, T, H, Dh), as (B, H, T, Dh), as (B, T, H*Dh),
+        # and the lse
+        return tuple(shapes.count(f"f32[2,{shape}]") for shape in
+                     ("2,32,4,8", "2,4,32,8", "2,32,32", "2,4,32"))
+
+    assert saved(cfg) == (2, 0, 0, 2)
+    assert saved(cfg_of(remat_policy="full")) == (0, 0, 0, 0)
+    # without the kernel the XLA path's output keeps its name, and no lse
+    assert saved(cfg_of(use_flash_attention=False,
+                        attn_scores_bf16=False)) == (2, 0, 0, 0)
+
+
 def test_flash_odd_seq_falls_back_to_smaller_blocks():
     # t=48 not divisible by 32 → block sizes shrink to 16
     q, k, v = _qkv(t=48)
