@@ -5,11 +5,14 @@ The train step computes, on the device and beside the loss, one float32
 row per layer: assignments in all (tokens x top-k), assignments to the
 experts held here, assignments dropped (always 0: the layer's buffer has a
 row for every assignment), and the held experts' largest load over their
-mean. Whoever fetches the loss fetches that small array with it and hands
+mean; where the router has a skip output, a fifth number: the tokens that
+took it. Whoever fetches the loss fetches that small array with it and hands
 it here; nothing in the step syncs for it.
 
 - ``dl4j_moe_assignments_total``, ``dl4j_moe_local_assignments_total``,
   ``dl4j_moe_dropped_total``: summed over the layers of every recorded step;
+- ``dl4j_moe_skipped_total``: tokens that took the router's skip, summed
+  likewise (registered only by rows of five);
 - ``dl4j_moe_load_max_over_mean``: the newest recorded step's worst layer
   (1.0 = the held experts are loaded evenly).
 """
@@ -20,10 +23,15 @@ import numpy as np
 
 
 def record_expert_load(stats) -> dict:
-    """Count one fetched step's ``(layers, 4)`` expert-load array into the
-    process-wide registry; returns what it read as a dict."""
+    """Count one fetched step's ``(layers, 4)`` or ``(layers, 5)``
+    expert-load array (or the train step's fourth output, which holds it
+    under ``"load"``) into the process-wide registry; returns what it read
+    as a dict (``skipped`` only for rows of five)."""
     from . import get_registry
-    stats = np.asarray(stats, np.float64).reshape(-1, 4)
+    if isinstance(stats, dict):
+        stats = stats["load"]
+    stats = np.asarray(stats, np.float64)
+    stats = stats.reshape(-1, stats.shape[-1] if stats.ndim > 1 else 4)
     total, local, dropped = (float(v) for v in stats[:, :3].sum(axis=0))
     worst = float(stats[:, 3].max())
     reg = get_registry()
@@ -37,6 +45,12 @@ def record_expert_load(stats) -> dict:
     reg.gauge("dl4j_moe_load_max_over_mean",
               "largest held expert's load over the held experts' mean, worst "
               "layer of the newest recorded step").set(worst)
-    return {"assignments": total, "local": local, "dropped": dropped,
+    read = {"assignments": total, "local": local, "dropped": dropped,
             "max_over_mean": worst,
             "local_share": local / total if total else 0.0}
+    if stats.shape[1] > 4:
+        read["skipped"] = float(stats[:, 4].sum())
+        reg.counter("dl4j_moe_skipped_total",
+                    "tokens that took the router's skip output and got "
+                    "nothing from the expert layer").inc(read["skipped"])
+    return read

@@ -28,6 +28,11 @@ from ..zoo import transformer as tfm
 
 def _stage_loss_fn(cfg, n_stages, other_axes=(), aux_weight=1e-2):
     """Builds the per-device pipelined loss, to run inside shard_map."""
+    if getattr(cfg, "router", "linear") == "mlp":
+        raise NotImplementedError(
+            "a stage hands the next the residual stream alone: the mlp "
+            "router's state would start from zeros at every stage (ROADMAP "
+            "Reach B9)")
 
     def fn(params, ids_mb, tgt_mb):
         # params['blocks'] leaves: (L/P, ...) local; embed/head replicated

@@ -132,6 +132,18 @@ class GenerationEngine:
                  paged_kernel: Optional[str] = None,
                  quant_kv: Optional[str] = None,
                  quant_weights: Optional[str] = None):
+        if (getattr(cfg, "attention", "mha") != "mha"
+                or getattr(cfg, "router", "linear") != "linear"
+                or getattr(cfg, "scaled_residuals", False)
+                or getattr(cfg, "rotary_share", 1.0) != 1.0
+                or getattr(cfg, "norm_eps", 1e-6) != 1e-6):
+            raise NotImplementedError(
+                "GenerationEngine cannot decode this block: compressed "
+                "convolutional attention (attention='cca') needs the "
+                "convolutions' last tokens and the shifted value beside the "
+                "KV pages, the mlp router its state from layer to layer, and "
+                "the decode step knows no scaled residuals, no partial "
+                "rotary and one norm_eps (ROADMAP Reach B9)")
         if getattr(cfg, "n_experts", 0):
             raise NotImplementedError(
                 "GenerationEngine is dense-only: MoE expert dispatch has "

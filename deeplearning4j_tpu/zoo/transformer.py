@@ -107,7 +107,8 @@ class TransformerConfig:
     embed_scale: bool = True    # token embedding times sqrt(d_model)
     mlp: str = "gelu"           # "gelu": gelu(x W_in) W_out | "reglu":
     #                             (relu(x Wg) * (x Wu)) W_out, Wg|Wu side by
-    #                             side in one (d, 2·d_ff) matrix
+    #                             side in one (d, 2·d_ff) matrix | "swiglu":
+    #                             the same with silu for relu
     # The share of the n_experts published experts this program holds:
     # (first id, count). The router keeps n_experts outputs and top-k over
     # all of them; the layer computes the held experts' part of the result
@@ -118,6 +119,32 @@ class TransformerConfig:
     # what the router reads: the block's normed input ("pre_attention", what
     # attention reads) or the normed input of the MLP ("post_attention")
     router_input: str = "post_attention"
+    # ---- PR 32. As above: what the block is, never how it is computed.
+    norm_eps: float = 1e-6      # of every RMS norm
+    # "mha": q, k, v = split(h Wqkv), heads as n_heads / n_kv_heads say.
+    # "cca": compressed convolutional attention in a latent of n_heads ·
+    # head_size channels (arXiv:2510.04476): q and k are mixed along the
+    # sequence by a depthwise and then a per-head causal convolution of
+    # cca_taps = (taps, taps), a mean of q and k is added to both, the second
+    # half of the value channels is the token before's, every head of q and
+    # k is scaled to norm sqrt(head size) (k times a learned temperature a
+    # K/V head), and only then come rotary and the causal softmax.
+    attention: str = "mha"
+    cca_taps: tuple = (2, 2)
+    rotary_share: float = 1.0   # the share of a head "rope" rotates, from
+    #                             dimension 0; the rest passes through
+    # "linear": logits = x W, top-k, weights = softmax over the kept logits.
+    # "mlp" (arXiv:2511.17127): a down-projection to router_hidden, the state
+    # of the layer before added (times a learned vector; carried down the
+    # stack beside x), an RMS norm and a three-matrix GELU MLP; the choice is
+    # the largest of probability plus a bias no gradient reaches, the weight
+    # that choice's probability over ALL outputs. router_skip: one more
+    # output, after the experts', whose tokens get nothing from the layer.
+    router: str = "linear"
+    router_hidden: int = 0
+    router_skip: bool = False
+    # x + f(x) -> (s x + b) + (s' f(x) + b'), learned vectors of d_model
+    scaled_residuals: bool = False
 
     @property
     def head_dim(self):
@@ -126,6 +153,11 @@ class TransformerConfig:
     @property
     def kv_heads(self):
         return self.n_kv_heads or self.n_heads
+
+    @property
+    def rotary_dims(self):
+        """Dimensions of a head that "rope" rotates."""
+        return int(round(self.head_dim * self.rotary_share))
 
     @property
     def layer_kinds(self):
@@ -148,8 +180,38 @@ def _check(cfg: TransformerConfig):
     if cfg.n_heads % cfg.kv_heads:
         raise ValueError(f"{cfg.n_heads} query heads cannot share "
                          f"{cfg.kv_heads} K/V heads")
-    if cfg.mlp not in ("gelu", "reglu"):
-        raise ValueError(f"Unknown mlp {cfg.mlp!r}; 'gelu' or 'reglu'")
+    if cfg.mlp not in ("gelu", "reglu", "swiglu"):
+        raise ValueError(f"Unknown mlp {cfg.mlp!r}; 'gelu', 'reglu' or "
+                         "'swiglu'")
+    if cfg.attention not in ("mha", "cca"):
+        raise ValueError(f"Unknown attention {cfg.attention!r}")
+    if cfg.router not in ("linear", "mlp"):
+        raise ValueError(f"Unknown router {cfg.router!r}")
+    if "rope" in cfg.layer_positions and (
+            cfg.rotary_dims < 2 or cfg.rotary_dims % 2
+            or cfg.rotary_dims > cfg.head_dim):
+        raise ValueError(f"rotary_share {cfg.rotary_share} of a head of "
+                         f"{cfg.head_dim} is not a whole number of pairs")
+    if cfg.attention == "cca":
+        if (cfg.kv_heads * cfg.head_dim) % 2 or len(cfg.cca_taps) != 2 \
+                or min(cfg.cca_taps) < 1:
+            raise ValueError(
+                f"cca wants two tap counts of at least 1 (cca_taps "
+                f"{cfg.cca_taps}) and an even number of value channels")
+        if cfg.use_ring_attention:
+            raise NotImplementedError(
+                "cca's convolutions read the tokens before: no ring step "
+                "hands them across the shards of the sequence")
+    if cfg.router == "mlp":
+        if not cfg.experts_held or cfg.expert_top_k != 1 \
+                or cfg.router_hidden < 1 \
+                or cfg.router_input != "post_attention":
+            raise NotImplementedError(
+                "the mlp router takes one expert a token (expert_top_k=1) "
+                "of the dropless layer (experts_held), reads the MLP's "
+                "input and needs router_hidden")
+    elif cfg.router_skip:
+        raise NotImplementedError("only the mlp router has a skip output")
     if cfg.router_input not in ("pre_attention", "post_attention"):
         raise ValueError(f"Unknown router_input {cfg.router_input!r}")
     if cfg.experts_held:
@@ -175,7 +237,7 @@ def init_params(key, cfg: TransformerConfig):
     k = jax.random.split(key, 12)
     d, f, h, L = cfg.d_model, cfg.d_ff, cfg.n_heads * cfg.head_dim, cfg.n_layers
     hkv = cfg.kv_heads * cfg.head_dim
-    f_in = 2 * f if cfg.mlp == "reglu" else f    # gate | up side by side
+    f_in = f if cfg.mlp == "gelu" else 2 * f     # gate | up side by side
     pd = cfg.param_dtype
 
     def norm(key, shape, fan_in):
@@ -194,10 +256,37 @@ def init_params(key, cfg: TransformerConfig):
     }
     if cfg.layer_positions:         # rotary or no positions: no table
         del params["pos_embed"]
+    blocks = params["blocks"]
+    kk = jax.random.split(k[10], 8)     # PR 32's leaves; k[0..9] as before
+    if cfg.attention == "cca":
+        k0, k1 = cfg.cca_taps
+        dh, hc = cfg.head_dim, cfg.n_heads + cfg.kv_heads
+        blocks.update(
+            cca_w0=norm(kk[0], (L, k0, hc * dh), k0),       # depthwise taps
+            cca_b0=jnp.zeros((L, hc * dh), pd),
+            cca_w1=norm(kk[1], (L, k1, hc, dh, dh), k1 * dh),   # per head
+            cca_b1=jnp.zeros((L, hc * dh), pd),
+            cca_tau=jnp.ones((L, cfg.kv_heads), pd))
+    if cfg.scaled_residuals:    # rows: s1, s2, s3, s4 and b1, b2, b3, b4
+        blocks.update(res_scale=jnp.ones((L, 4, d), pd),
+                      res_bias=jnp.zeros((L, 4, d), pd))
+    if cfg.router == "mlp":
+        R, out = cfg.router_hidden, cfg.n_experts + int(cfg.router_skip)
+        blocks.update(
+            router_down=norm(kk[2], (L, d, R), d),
+            router_down_b=jnp.zeros((L, R), pd),
+            router_gamma=jnp.ones((L, R), pd),
+            router_w1=norm(kk[3], (L, R, R), R),
+            router_c1=jnp.zeros((L, R), pd),
+            router_w2=norm(kk[4], (L, R, R), R),
+            router_c2=jnp.zeros((L, R), pd),
+            router_w3=norm(kk[5], (L, R, out), R),
+            router_beta=jnp.zeros((L, out), pd))    # no gradient reaches it
     if cfg.n_experts:
         E = cfg.n_experts           # the router's width, held or not
         held = cfg.experts_held[1] if cfg.experts_held else E
-        params["blocks"]["router"] = norm(k[4], (L, d, E), d)
+        if cfg.router == "linear":
+            params["blocks"]["router"] = norm(k[4], (L, d, E), d)
         params["blocks"]["we_in"] = norm(k[5], (L, held, d, f_in), d)
         params["blocks"]["we_out"] = norm(k[6], (L, held, f, d), f)
     else:
@@ -251,8 +340,19 @@ def param_pspecs(cfg: TransformerConfig):
     }
     if cfg.layer_positions:
         del specs["pos_embed"]
+    small = ()
+    if cfg.attention == "cca":
+        small += ("cca_w0", "cca_b0", "cca_w1", "cca_b1", "cca_tau")
+    if cfg.scaled_residuals:
+        small += ("res_scale", "res_bias")
+    if cfg.router == "mlp":
+        small += ("router_down", "router_down_b", "router_gamma", "router_w1",
+                  "router_c1", "router_w2", "router_c2", "router_w3",
+                  "router_beta")
+    specs["blocks"].update({name: P() for name in small})
     if cfg.n_experts:
-        specs["blocks"]["router"] = P()
+        if cfg.router == "linear":
+            specs["blocks"]["router"] = P()
         specs["blocks"]["we_in"] = P(None, "ep", None, "tp")
         specs["blocks"]["we_out"] = P(None, "ep", "tp", None)
     else:
@@ -318,9 +418,14 @@ def attention_path(cfg, t, dtype) -> str:
     return "xla_sdpa"
 
 
-def _rope(x, theta, pos_offset=0):
+def _rope(x, theta, pos_offset=0, rotary=None):
     """Rotary positions on (B, T, H, Dh): dimension i pairs with i + Dh/2
-    (the split-half layout), over the whole head, angles in float32."""
+    (the split-half layout), over the whole head, angles in float32. With
+    ``rotary`` < Dh only the first ``rotary`` dimensions are rotated (i pairs
+    with i + rotary/2) and the rest pass through."""
+    if rotary is not None and rotary != x.shape[-1]:
+        return jnp.concatenate(
+            [_rope(x[..., :rotary], theta, pos_offset), x[..., rotary:]], -1)
     t, half = x.shape[1], x.shape[-1] // 2
     inv = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
     ang = (pos_offset + jnp.arange(t, dtype=jnp.float32))[:, None] * inv
@@ -331,6 +436,59 @@ def _rope(x, theta, pos_offset=0):
                            -1).astype(x.dtype)
 
 
+def _shift(x, n=1):
+    """x[:, t - n] along the time axis of (B, T, ...), zeros on the left."""
+    if n == 0:
+        return x
+    pad = [(0, 0)] * x.ndim
+    pad[1] = (n, 0)
+    return jnp.pad(x[:, :-n], pad)
+
+
+def _cca_qkv(cfg, h, blk, positions):
+    """Compressed convolutional attention up to the softmax: the normed
+    input (B, T, d) -> q (B, T, H·Dh), k and v (B, T, J·Dh) in the latent,
+    q and k mixed, normed and (``positions == "rope"``) rotated. In float32
+    from the projections' output on."""
+    b, t, _ = h.shape
+    H, J, dh = cfg.n_heads, cfg.kv_heads, cfg.head_dim
+    hq, hk, f32 = H * dh, J * dh, jnp.float32
+    with jax.named_scope("cca_proj"):
+        qkv = jnp.einsum("btd,dz->btz", h, blk["wqkv"].astype(h.dtype))
+        qkv = _constrain(qkv, "dp", "sp", "tp")
+    with jax.named_scope("cca_mix"):
+        v = qkv[..., hq + hk:]      # second half: the token before's
+        v = jnp.concatenate([v[..., : hk // 2], _shift(v[..., hk // 2:])], -1)
+        c = qkv[..., : hq + hk].astype(f32)
+        q3 = c[..., :hq].reshape(b, t, J, H // J, dh)
+        k3 = c[..., hq:].reshape(b, t, J, 1, dh)
+        mq = ((q3 + k3) / 2).reshape(b, t, H, dh)
+        mk = (jnp.mean(q3, axis=3) + k3[:, :, :, 0]) / 2
+        k0, k1 = cfg.cca_taps
+        w0, w1 = blk["cca_w0"].astype(f32), blk["cca_w1"].astype(f32)
+        y = sum(_shift(c, k0 - 1 - a) * w0[a] for a in range(k0)) \
+            + blk["cca_b0"].astype(f32)
+        y = y.reshape(b, t, H + J, dh)
+        # float32 operands at the default precision: one bf16 pass of the
+        # MXU with a float32 result on the chip (XLA:CPU has no batched bf16
+        # x bf16 -> f32 product)
+        z = sum(jnp.einsum("bthd,hde->bthe", _shift(y, k1 - 1 - a), w1[a])
+                for a in range(k1)) \
+            + blk["cca_b1"].astype(f32).reshape(H + J, dh)
+
+        def unit(a):    # every head to norm sqrt(dh)
+            return a * lax.rsqrt(
+                jnp.mean(jnp.square(a), -1, keepdims=True) + 1e-12 / dh)
+
+        q = unit(z[:, :, :H] + mq)
+        k = unit(z[:, :, H:] + mk) * blk["cca_tau"].astype(f32)[:, None]
+        if positions == "rope":
+            q = _rope(q, cfg.rope_theta, rotary=cfg.rotary_dims)
+            k = _rope(k, cfg.rope_theta, rotary=cfg.rotary_dims)
+        q, k = q.astype(h.dtype), k.astype(h.dtype)
+    return q.reshape(b, t, hq), k.reshape(b, t, hk), v
+
+
 def _attention(cfg, q, k, v, mask_bias=None, positions="learned", window=0):
     """``positions`` and ``window`` are the layer's kind (static): rotary
     q and k or none; the keys a query sees (0 = every earlier one)."""
@@ -338,8 +496,9 @@ def _attention(cfg, q, k, v, mask_bias=None, positions="learned", window=0):
     q = q.reshape(b, t, cfg.n_heads, cfg.head_dim)
     k = k.reshape(b, t, cfg.kv_heads, cfg.head_dim)
     v = v.reshape(b, t, cfg.kv_heads, cfg.head_dim)
-    if positions == "rope":
-        q, k = _rope(q, cfg.rope_theta), _rope(k, cfg.rope_theta)
+    if positions == "rope" and cfg.attention != "cca":  # cca rotates itself
+        q = _rope(q, cfg.rope_theta, rotary=cfg.rotary_dims)
+        k = _rope(k, cfg.rope_theta, rotary=cfg.rotary_dims)
     path = attention_path(cfg, t, q.dtype)
     if path == "ring":
         if window or cfg.kv_heads != cfg.n_heads:
@@ -449,7 +608,7 @@ def _mlp_act(cfg, h):
     if cfg.mlp == "gelu":
         return jax.nn.gelu(h)
     gate, up = jnp.split(h, 2, axis=-1)
-    return jax.nn.relu(gate) * up
+    return (jax.nn.relu if cfg.mlp == "reglu" else jax.nn.silu)(gate) * up
 
 
 def _moe_mlp(cfg, x, router, we_in, we_out):
@@ -495,6 +654,40 @@ def _router_logits(x, router):
         return jnp.einsum("btd,de->bte", x, router.astype(x.dtype),
                           preferred_element_type=jnp.float32
                           ).reshape(-1, router.shape[-1])
+
+
+def _route_top_k(cfg, logits):
+    """The linear router's choice: top-k of the (N, E) logits and the softmax
+    over the kept ones, both (K, N)."""
+    with jax.named_scope("moe_router"):
+        top, chosen = lax.top_k(logits, cfg.expert_top_k)       # (N, K)
+        weight = jax.nn.softmax(top, axis=-1).T                 # (K, N)
+        return chosen.T, weight
+
+
+def _route_mlp(cfg, u, state, blk):
+    """The mlp router on the MLP's input ``u`` (B, T, d) with the state
+    ``state`` (B, T, R) float32 of the layer before (zeros for the first).
+    Returns (the new state, choice (1, N) int32, weight (1, N) float32): the
+    choice is the largest of probability plus ``router_beta``; an output of
+    ``n_experts`` is the skip. Float32 throughout, the three small products
+    at the highest precision: a token's choice is an argmax."""
+    f32, hi = jnp.float32, lax.Precision.HIGHEST
+    with jax.named_scope("moe_router"):
+        r = jnp.einsum("btd,dr->btr", u, blk["router_down"].astype(u.dtype),
+                       preferred_element_type=f32)
+        r = r + blk["router_down_b"].astype(f32) \
+            + blk["router_gamma"].astype(f32) * state
+        z = _rmsnorm(r, jnp.ones((), f32), cfg.norm_eps)
+        for w, c in (("router_w1", "router_c1"), ("router_w2", "router_c2")):
+            z = jax.nn.gelu(jnp.dot(z, blk[w].astype(f32), precision=hi)
+                            + blk[c].astype(f32), approximate=False)
+        logits = jnp.dot(z, blk["router_w3"].astype(f32), precision=hi)
+        p = jax.nn.softmax(logits.reshape(-1, logits.shape[-1]), axis=-1)
+        beta = lax.stop_gradient(blk["router_beta"].astype(f32))
+        chosen = jnp.argmax(p + beta, axis=-1).astype(jnp.int32)    # (N,)
+        weight = jnp.take_along_axis(p, chosen[:, None], axis=-1)[:, 0]
+        return r, chosen[None], weight[None]
 
 
 def _take_rows(x, idx):
@@ -554,29 +747,30 @@ def _rows_back_bwd(order, g):
 _rows_back.defvjp(_rows_back_fwd, _rows_back_bwd)
 
 
-def _moe_share(cfg, x, logits, we_in, we_out):
+def _moe_share(cfg, x, chosen, weight, we_in, we_out):
     """The held experts' part of a routed expert layer, dropless.
 
-    ``logits`` (N, n_experts) float32 are the router's, over every published
-    expert; ``we_in`` (held, d, f_in) and ``we_out`` (held, d_ff, d) are the
-    experts ``cfg.experts_held`` = (first, held) names. Top-k over all
-    experts, weights = softmax over the kept logits. The K·N assignments
-    are sorted by expert, the absent experts' last; one gather fills a
-    static (K·N, d) buffer (enough for EVERY assignment to be local, so no
-    routing can drop one); grouped products over the held experts' group
-    sizes compute exactly the rows routed here and leave the rest unset;
+    ``chosen`` (K, N) int32 are every token's K choices among the router's
+    outputs (every published expert and, where the router has one, the
+    skip) and ``weight`` (K, N) float32 what each choice's result is
+    multiplied by: the router's business (:func:`_route_top_k`,
+    :func:`_route_mlp`). ``we_in`` (held, d, f_in) and ``we_out`` (held,
+    d_ff, d) are the experts ``cfg.experts_held`` = (first, held) names; a
+    choice outside them, an absent expert's or the skip, is not local. The
+    K·N assignments are sorted by expert, the absent experts' last; one
+    gather fills a static (K·N, d) buffer (enough for EVERY assignment to be
+    local, so no routing can drop one); grouped products over the held
+    experts' group sizes compute exactly the rows routed here and leave the
+    rest unset;
     the rows go back to their tokens, where the local ones are weighted
     and summed. Returns (y, stats): stats = float32 [assignments,
     assignments to held experts, assignments dropped (rows the buffer
-    could not take: 0), largest held expert's load over their mean]."""
+    could not take: 0), largest held expert's load over their mean] and,
+    where the router has a skip, a fifth: tokens that took it."""
     b, t, d = x.shape
-    n, k = b * t, cfg.expert_top_k
+    n, k = b * t, chosen.shape[0]
     first, held = cfg.experts_held
     tokens = x.reshape(n, d)
-    with jax.named_scope("moe_router"):
-        top, chosen = lax.top_k(logits, k)                      # (N, K)
-        weight = jax.nn.softmax(top, axis=-1).T                 # (K, N)
-        chosen = chosen.T
     with jax.named_scope("moe_dispatch"):
         local = (chosen >= first) & (chosen < first + held)     # (K, N)
         slot = jnp.where(local, chosen - first, held).reshape(-1)    # (A,)
@@ -596,11 +790,13 @@ def _moe_share(cfg, x, logits, we_in, we_out):
         y = jnp.sum(parts.astype(jnp.float32) * weight[:, :, None], axis=0
                     ).astype(x.dtype)
     f32 = jnp.float32
-    stats = jnp.stack([
+    stats = [
         jnp.asarray(n * k, f32), n_local.astype(f32),
         jnp.maximum(n_local - rows.shape[0], 0).astype(f32),
-        jnp.max(sizes).astype(f32) * held / jnp.maximum(n_local, 1).astype(f32)])
-    return y.reshape(b, t, d), stats
+        jnp.max(sizes).astype(f32) * held / jnp.maximum(n_local, 1).astype(f32)]
+    if cfg.router_skip:
+        stats.append(jnp.sum(chosen == cfg.n_experts, dtype=f32))
+    return y.reshape(b, t, d), jnp.stack(stats)
 
 
 def embed(params, cfg: TransformerConfig, ids, pos_offset=0):
@@ -630,7 +826,7 @@ def _resolve_head(params, cfg: TransformerConfig):
 
 def head_logits(params, cfg: TransformerConfig, x):
     """Final norm + LM head → f32 logits."""
-    x = _rmsnorm(x, params["ln_f"])
+    x = _rmsnorm(x, params["ln_f"], cfg.norm_eps)
     head = _resolve_head(params, cfg)
     logits = jnp.einsum("btd,dv->btv", x, head.astype(x.dtype))
     return _constrain(logits, "dp", "sp", "tp").astype(jnp.float32)
@@ -641,7 +837,7 @@ def head_logits_rows(params, cfg: TransformerConfig, x):
     The serving engine's shape: one row per decode slot / per prefill's
     last position — never the (B, T, V) tensor a generation step doesn't
     need."""
-    x = _rmsnorm(x, params["ln_f"])
+    x = _rmsnorm(x, params["ln_f"], cfg.norm_eps)
     head = _resolve_head(params, cfg)
     return jnp.einsum("nd,dv->nv", x, head.astype(x.dtype)
                       ).astype(jnp.float32)
@@ -652,7 +848,7 @@ def hidden_rows(params, cfg: TransformerConfig, x):
     matmul. The EMBED workload's representation (ISSUE 20): the same
     post-``ln_f`` activations ``head_logits_rows`` projects, surfaced
     for pooling instead of next-token prediction."""
-    return _rmsnorm(x, params["ln_f"]).astype(jnp.float32)
+    return _rmsnorm(x, params["ln_f"], cfg.norm_eps).astype(jnp.float32)
 
 
 def generate(params, cfg: TransformerConfig, prompt_ids, max_new_tokens=32,
@@ -688,78 +884,106 @@ def apply_blocks(blocks, cfg: TransformerConfig, x, *, return_kv=False):
     return x, jnp.sum(auxes)
 
 
+def _residual(cfg, x, y, blk, i):
+    """The i-th residual merge of a block (0 after attention, 1 after the
+    MLP): x + y, or with learned scales and biases on both terms."""
+    y = _constrain(y, "dp", "sp", None)
+    if not cfg.scaled_residuals:
+        return x + y
+    s = blk["res_scale"].astype(jnp.float32)
+    b = blk["res_bias"].astype(jnp.float32)
+    return ((x.astype(jnp.float32) * s[2 * i] + b[2 * i])
+            + (y.astype(jnp.float32) * s[2 * i + 1] + b[2 * i + 1])
+            ).astype(x.dtype)
+
+
 def _run_blocks(blocks, cfg: TransformerConfig, x, return_kv=False):
-    """(x, auxes (L,), kvs, expert stats (L, 4) or None). The scan runs
+    """(x, auxes (L,), kvs, what the held experts' layers tell or None:
+    ``{"load": (L, 4 or 5) float32}`` of :func:`_moe_share` and, under the
+    mlp router, ``"choices"``: (L, 1, N) int32). The scan runs
     over PERIODS of the layer pattern (``cfg.layer_kinds``; a period of one
     layer for a uniform stack): inside a period the layers' kinds are
-    static, and each layer is rematerialized on its own."""
+    static, and each layer is rematerialized on its own. The scan carries
+    ``(x, state)``: ``state`` is the mlp router's (B, T, router_hidden)
+    float32, which every layer adds to its own and hands on, and None for
+    the linear router."""
     _check(cfg)
     hq, hkv = cfg.n_heads * cfg.head_dim, cfg.kv_heads * cfg.head_dim
 
-    def block(x, blk, positions, window):
-        h = _rmsnorm(x, blk["ln1"])
-        qkv = jnp.einsum("btd,dz->btz", h, blk["wqkv"].astype(h.dtype))
-        qkv = _constrain(qkv, "dp", "sp", "tp")
-        q, k, v = jnp.split(qkv, 3, axis=-1) if hkv == hq else \
-            jnp.split(qkv, (hq, hq + hkv), axis=-1)
+    def block(carry, blk, positions, window):
+        x, state = carry
+        h = _rmsnorm(x, blk["ln1"], cfg.norm_eps)
+        if cfg.attention == "cca":
+            q, k, v = _cca_qkv(cfg, h, blk, positions)
+        else:
+            qkv = jnp.einsum("btd,dz->btz", h, blk["wqkv"].astype(h.dtype))
+            qkv = _constrain(qkv, "dp", "sp", "tp")
+            q, k, v = jnp.split(qkv, 3, axis=-1) if hkv == hq else \
+                jnp.split(qkv, (hq, hq + hkv), axis=-1)
         routed = None
         if cfg.experts_held and cfg.router_input == "pre_attention":
             routed = _router_logits(h, blk["router"])
         a = _attention(cfg, q, k, v, positions=positions, window=window)
         a = jnp.einsum("bth,hd->btd", a, blk["wo"].astype(h.dtype))
-        x = x + _constrain(a, "dp", "sp", None)
-        h2 = _rmsnorm(x, blk["ln2"])
-        stats = None
+        x = _residual(cfg, x, a, blk, 0)
+        h2 = _rmsnorm(x, blk["ln2"], cfg.norm_eps)
+        told = None
         if cfg.experts_held:
-            if routed is None:
-                routed = _router_logits(h2, blk["router"])
-            m, stats = _moe_share(cfg, h2, routed, blk["we_in"],
-                                  blk["we_out"])
+            if cfg.router == "mlp":
+                state, chosen, weight = _route_mlp(cfg, h2, state, blk)
+            else:
+                if routed is None:
+                    routed = _router_logits(h2, blk["router"])
+                chosen, weight = _route_top_k(cfg, routed)
+            m, load = _moe_share(cfg, h2, chosen, weight, blk["we_in"],
+                                 blk["we_out"])
+            told = {"load": load}
+            if cfg.router == "mlp":     # an argmax: see make_train_step
+                told["choices"] = chosen
             aux = 0.0
         elif cfg.n_experts:
             m, aux = _moe_mlp(cfg, h2, blk["router"], blk["we_in"], blk["we_out"])
         else:
             m, aux = _dense_mlp(cfg, h2, blk["w_in"], blk["w_out"]), 0.0
-        x = x + _constrain(m, "dp", "sp", None)
+        x = _residual(cfg, x, m, blk, 1)
         kv = None
         if return_kv:
             b, t = x.shape[0], x.shape[1]
             kv = (k.reshape(b, t, cfg.kv_heads, cfg.head_dim),
                   v.reshape(b, t, cfg.kv_heads, cfg.head_dim))
-        return x, (aux, kv, stats)
+        return (x, state), (aux, kv, told)
 
     def of_kind(positions, window):
-        fn = lambda x, blk: block(x, blk, positions, window)   # noqa: E731
+        fn = lambda c, blk: block(c, blk, positions, window)   # noqa: E731
         return fn if (return_kv or not cfg.remat) \
             else _remat_wrap(fn, cfg.remat_policy)
 
     blk_fns = [of_kind(*kind) for kind in cfg.layer_kinds]
     period = len(blk_fns)
     if period == 1:     # a uniform stack keeps the scan it always had
-        blk_fn = blk_fns[0]
-
-        def scan_body(carry, blk):
-            x = carry
-            x, ys = blk_fn(x, blk)
-            return x, ys
+        scan_body = blk_fns[0]
     else:
         # (L, ...) → (L / period, period, ...): one scan step is one period
         blocks = jax.tree_util.tree_map(
             lambda w: w.reshape(w.shape[0] // period, period, *w.shape[1:]),
             blocks)
 
-        def scan_body(x, blks):
+        def scan_body(carry, blks):
             ys = []
             for i, blk_fn in enumerate(blk_fns):
-                x, y = blk_fn(x, jax.tree_util.tree_map(lambda w: w[i], blks))
+                carry, y = blk_fn(carry,
+                                  jax.tree_util.tree_map(lambda w: w[i], blks))
                 ys.append(y)
-            return x, jax.tree_util.tree_map(lambda *l: jnp.stack(l), *ys)
+            return carry, jax.tree_util.tree_map(lambda *l: jnp.stack(l), *ys)
 
-    x, (auxes, kvs, stats) = lax.scan(scan_body, x, blocks)
+    state = None
+    if cfg.router == "mlp":
+        state = jnp.zeros((*x.shape[:2], cfg.router_hidden), jnp.float32)
+    (x, _), (auxes, kvs, told) = lax.scan(scan_body, (x, state), blocks)
     if period > 1:      # (L / period, period, ...) → (L, ...)
-        auxes, kvs, stats = jax.tree_util.tree_map(
-            lambda y: y.reshape(-1, *y.shape[2:]), (auxes, kvs, stats))
-    return x, auxes, kvs, stats
+        auxes, kvs, told = jax.tree_util.tree_map(
+            lambda y: y.reshape(-1, *y.shape[2:]), (auxes, kvs, told))
+    return x, auxes, kvs, told
 
 
 def forward(params, cfg: TransformerConfig, ids, *, train=False, rng=None,
@@ -829,41 +1053,47 @@ def lm_loss(params, cfg: TransformerConfig, ids, targets, *, aux_weight=1e-2,
 
 def _lm_loss_stats(params, cfg: TransformerConfig, ids, targets, *,
                    aux_weight=1e-2, pos_offset=0):
-    """(loss, per-layer expert-load stats (L, 4) or None): see
-    :func:`_moe_share` for the four numbers."""
+    """(loss, what the held experts' layers tell, or None): see
+    :func:`_run_blocks`."""
     b, t = ids.shape
     x = embed(params, cfg, ids, pos_offset)
-    x, auxes, _, stats = _run_blocks(params["blocks"], cfg, x)
+    x, auxes, _, told = _run_blocks(params["blocks"], cfg, x)
     aux = jnp.sum(auxes)
     if _use_fused_loss(cfg, b * t):
-        x = _rmsnorm(x, params["ln_f"])
+        x = _rmsnorm(x, params["ln_f"], cfg.norm_eps)
         head = _resolve_head(params, cfg)
         nll = _chunked_ce(x.reshape(b * t, -1), head.astype(x.dtype),
                           targets.reshape(b * t), cfg.loss_chunk) / (b * t)
-        return nll + aux_weight * aux, stats
+        return nll + aux_weight * aux, told
     logits = head_logits(params, cfg, x)
     logp = jax.nn.log_softmax(logits, axis=-1)
     nll = -jnp.take_along_axis(logp, targets[..., None].astype(jnp.int32), -1)[..., 0]
-    return nll.mean() + aux_weight * aux, stats
+    return nll.mean() + aux_weight * aux, told
 
 
 def make_train_step(cfg: TransformerConfig, optimizer):
     """One jitted step: grads → optax update → new params. Shard via the
     caller's jit(in_shardings=...) or run as-is on one device. Returns
     (params, opt_state, loss) and, where the configuration holds a share of
-    routed experts (``experts_held``), a fourth output: the per-layer
-    expert-load stats (L, 4) float32 of :func:`_moe_share`, computed on the
-    device beside the loss (``obs.moe.record_expert_load`` counts them)."""
+    routed experts (``experts_held``), a fourth output, computed on the
+    device beside the loss: ``{"load": the per-layer expert-load stats (L, 4
+    or 5) float32 of :func:`_moe_share```} (``obs.moe.record_expert_load``
+    counts them) and, under the mlp router, ``"choices"``: the expert every
+    token took in every layer, (L, 1, B·T) int32. A choice is an argmax, and
+    a tie within the compute dtype's rounding falls the other way in another
+    precision: whoever compares the step with another computation of the
+    same model hands it these choices, so that both differentiate one
+    function."""
 
     def step(params, opt_state, ids, targets):
-        (loss, stats), grads = jax.value_and_grad(
+        (loss, told), grads = jax.value_and_grad(
             _lm_loss_stats, has_aux=True)(params, cfg, ids, targets)
         updates, opt_state = optimizer.update(grads, opt_state, params)
         import optax as _optax
         params = _optax.apply_updates(params, updates)
-        if stats is None:
+        if told is None:
             return params, opt_state, loss
-        return params, opt_state, loss, stats
+        return params, opt_state, loss, told
 
     return step
 

@@ -139,6 +139,26 @@ def test_flash_grouped_heads_compile_at_the_moe_cell_shape(
         "bf16[2,8192,128]" in text
 
 
+@pytest.mark.parametrize("blocks", [(512, 1024), (1024, 1024), (1024, 512)],
+                         ids=["512x1024", "1024x1024", "1024x512"])
+def test_flash_compiles_at_the_zaya_cell_shape(one_chip, no_persistent_cache,
+                                               blocks):
+    """zaya1-train-b1-t32768's attention in the latent: 8 query heads of 128
+    on 2 K/V heads over 32,768 positions, full causal (PR 32), at the larger
+    block pairs the race may pick there."""
+    from deeplearning4j_tpu.kernels.flash_attention import flash_attention
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, None, True, *blocks, False)
+                       .astype(jnp.float32))
+
+    q = _sds(one_chip, (1, 8, 32768, 128), jnp.bfloat16)
+    kv = _sds(one_chip, (1, 2, 32768, 128), jnp.bfloat16)
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert re.search(rf"%{name}(\.\d+)? = [^\n]*tpu_custom_call", text), name
+
+
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
                          ids=["bf16", "f32"])
 def test_paged_decode_kernel_compiles(one_chip, no_persistent_cache, dtype):

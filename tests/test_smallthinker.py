@@ -86,7 +86,8 @@ def test_loss_and_every_gradient_leaf_match_the_reference(case):
     for name, w in g_want.items():
         gap = float(jnp.max(jnp.abs(g_got[name] - w)) / jnp.max(jnp.abs(w)))
         assert gap <= 1e-5, (name, gap)
-    stats = np.asarray(stats)
+    assert sorted(stats) == ["load"]    # no choices from the linear router
+    stats = np.asarray(stats["load"])
     assert stats.shape == (sz["layers"], 4)
     assert (stats[:, 0] == ids.size * sz["top_k"]).all()
     assert (stats[:, 2] == 0).all()
@@ -103,8 +104,9 @@ def test_train_step_hands_back_the_expert_load_beside_the_loss():
     opt = optax.adamw(3e-4)
     out = jax.jit(tfm.make_train_step(cfg, opt))(params, opt.init(params),
                                                  ids, tgt)
-    assert len(out) == 4 and out[3].shape == (4, 4)
-    assert out[3].dtype == jnp.float32
+    assert len(out) == 4 and sorted(out[3]) == ["load"]
+    assert out[3]["load"].shape == (4, 4)
+    assert out[3]["load"].dtype == jnp.float32
     dense = tfm.TransformerConfig(vocab_size=50, d_model=32, n_heads=4,
                                   n_layers=2, d_ff=64, max_seq=16,
                                   dtype=jnp.float32)
@@ -154,8 +156,9 @@ def test_the_four_shares_parts_add_up_to_the_uncut_layer(layer):
         a = tfm._attention(cfg, q, k, v, positions=kind[0],
                            window=kind[1]) @ blk["wo"]
         u = tfm._rmsnorm(x, blk["ln2"])    # the same u for every share
-        y, stats = tfm._moe_share(cfg, u, tfm._router_logits(h, blk["router"]),
-                                  blk["we_in"], blk["we_out"])
+        y, stats = tfm._moe_share(
+            cfg, u, *tfm._route_top_k(cfg, tfm._router_logits(h, blk["router"])),
+            blk["we_in"], blk["we_out"])
         return a, y, stats
 
     a_full, y_full, _ = program_parts(full_cfg, full_blk)
@@ -200,7 +203,8 @@ def test_every_token_on_one_expert_loses_nothing(target, held):
     u = jax.random.normal(jax.random.PRNGKey(1), (1, n, 32), jnp.float32)
     logits = jax.random.normal(jax.random.PRNGKey(4), (n, 8), jnp.float32)
     logits = logits.at[:, target].set(50.0)     # all but the whole weight
-    y, stats = tfm._moe_share(cfg, u, logits, blk["we_in"], blk["we_out"])
+    y, stats = tfm._moe_share(cfg, u, *tfm._route_top_k(cfg, logits),
+                              blk["we_in"], blk["we_out"])
     want = ref.experts_part(u[0], logits, blk, sz)
     np.testing.assert_allclose(y[0], want, rtol=1e-5, atol=1e-6)
     stats = np.asarray(stats)
@@ -308,7 +312,7 @@ def test_program_through_the_flash_kernel_matches_the_reference(monkeypatch):
 
 @pytest.mark.parametrize("fields,error", [
     (dict(n_heads=4, n_kv_heads=3), ValueError),
-    (dict(mlp="swiglu"), ValueError),
+    (dict(mlp="geglu"), ValueError),
     (dict(layer_positions=("rope", "none"), layer_windows=(0,)), ValueError),
     (dict(layer_positions=("rope",) * 3), ValueError),      # 3 does not divide 4
     (dict(n_experts=8, experts_held=(6, 3)), ValueError),
