@@ -16,6 +16,10 @@ TPU-native redesign is a pure-functional GPT-style LM engineered for SPMD:
   einsum dispatch) with experts sharded over 'ep'.
 - bf16 activations/f32 params & optimizer; `jax.checkpoint` on each block
   (remat) so long sequences fit HBM.
+- The chunked head (`_chunked_ce`) has NO checkpoint: its `custom_vjp`
+  forms `dx` and the table's gradient beside each chunk's loss and keeps
+  those two (and the per-row loss), never a chunk's logits — three
+  vocabulary-wide products a chunk, none of them a recomputation.
 """
 
 from __future__ import annotations
@@ -48,10 +52,11 @@ class TransformerConfig:
     param_dtype: Any = jnp.float32
     remat: bool = True
     # Fused (chunked) LM cross-entropy: never materializes the full
-    # (B, T, V) f32 logits — per time-chunk the head matmul, logsumexp and
-    # target gather collapse into one rematerialized scan step. Cuts the
-    # dominant HBM traffic of a 32k-vocab loss (logits f32 write+read is
-    # ~4 GB/step at B16/T1024) for ~one extra head matmul in backward.
+    # (B, T, V) f32 logits — per row-chunk the head matmul, logsumexp,
+    # target gather and the chunk's gradient are one scan step
+    # (`_chunked_ce`). Cuts the dominant HBM traffic of a 32k-vocab loss
+    # (logits f32 write+read is ~4 GB/step at B16/T1024) at no extra head
+    # matmul: the backward only scales what the forward formed.
     # True | False | "auto" (fuse when B*T*V is large enough to matter).
     fused_loss: Any = "auto"
     loss_chunk: int = 1024      # rows (B*T) per chunk in the fused loss
@@ -1005,44 +1010,101 @@ def _use_fused_loss(cfg: TransformerConfig, n_rows: int) -> bool:
     return n_rows * cfg.vocab_size * 4 > 64 * 2 ** 20
 
 
+def _chunk_terms(xc, tc, head, bias):
+    """Of one chunk's float32 logits: ``exp(logits - row max)``, its row
+    sums, and the per-row NLL ``log(sum) + max - target logit``."""
+    logits = jnp.einsum("cd,dv->cv", xc, head).astype(jnp.float32)
+    if bias is not None:
+        logits = logits + bias.astype(jnp.float32)
+    top = logits.max(-1)
+    e = jnp.exp(logits - top[:, None])
+    total = e.sum(-1)
+    tl = jnp.take_along_axis(logits, tc[:, None], -1)[:, 0]
+    return e, total, jnp.log(total) + top - tl
+
+
+@jax.custom_vjp
+def _chunked_nll(xk, head, bias, wk, tk):
+    """sum(w * nll) over (chunks, chunk, d) rows: one product a chunk."""
+    def body(loss, sl):
+        xc, tc, wc = sl
+        with jax.named_scope("lm_head"):
+            nll = _chunk_terms(xc, tc, head, bias)[2]
+            return loss + (nll * wc).sum(), None
+
+    return lax.scan(body, jnp.zeros((), jnp.float32), (xk, tk, wk),
+                    reverse=True)[0]
+
+
+def _chunked_nll_fwd(xk, head, bias, wk, tk):
+    """The loss and, from the same logits, its whole gradient: per chunk
+    ``p = (softmax - onehot) * w``, ``dx_c = p @ head^T`` (stacked) and
+    ``dhead += x_c^T @ p`` (carried): three products a chunk. ``p`` is
+    formed as ``jax.scipy.special.logsumexp``'s own gradient forms it,
+    ``exp(logits - max) * (w / sum)``, in float32 before the cast, and the
+    chunks run LAST TO FIRST, the order in which a backward scan sums them:
+    a ``dhead`` carried in bf16 rounds by its order, and first to last read
+    the untied head's gradient norm 1.5e-4 further from a float32 one on
+    the chip."""
+    def body(carry, sl):
+        loss, dhead, dbias = carry
+        xc, tc, wc = sl
+        with jax.named_scope("lm_head"):
+            e, total, nll = _chunk_terms(xc, tc, head, bias)
+            hit = tc[:, None] == lax.broadcasted_iota(jnp.int32, e.shape, 1)
+            p = e * (wc / total)[:, None] - jnp.where(hit, wc[:, None], 0.0)
+            if bias is not None:
+                dbias = dbias + p.sum(0).astype(dbias.dtype)
+            p = p.astype(xc.dtype)
+            dx = jnp.einsum("cv,dv->cd", p, head)
+            dhead = dhead + jnp.einsum("cd,cv->dv", xc, p)
+            return (loss + (nll * wc).sum(), dhead, dbias), (dx, nll)
+
+    zeros = jax.tree_util.tree_map(jnp.zeros_like, (head, bias))
+    (loss, dhead, dbias), (dx, nll) = lax.scan(
+        body, (jnp.zeros((), jnp.float32), *zeros), (xk, tk, wk),
+        reverse=True)
+    return loss, (dx, dhead, dbias, nll)
+
+
+def _chunked_nll_bwd(res, g):
+    with jax.named_scope("lm_head"):
+        dx, dhead, dbias, nll = jax.tree_util.tree_map(
+            lambda r: (g * r).astype(r.dtype), res)
+    return dx, dhead, dbias, nll, None      # integer targets: no cotangent
+
+
+_chunked_nll.defvjp(_chunked_nll_fwd, _chunked_nll_bwd)
+
+
 def _chunked_ce(x, head, targets, chunk, weights=None, bias=None):
     """WEIGHTED-SUM NLL of (N, d) hidden rows against (N,) targets WITHOUT
-    materializing the (N, V) f32 logits: scan over row chunks; each step
-    is rematerialized so backward recomputes the chunk's logits from the
-    (small) saved hidden rows instead of saving V-wide activations.
-    Returns sum(w·nll) — the caller divides by its own denominator.
-    ``weights`` default to 1 per row; ``bias`` (V,) supports BERT's MLM
-    output bias."""
+    materializing the (N, V) f32 logits: a scan over row chunks. Returns
+    sum(w·nll) — the caller divides by its own denominator. ``weights``
+    default to 1 per row; ``bias`` (V,) supports BERT's MLM output bias.
+
+    There is no checkpoint (no remat) and no second loop for the backward: the
+    loss is the last thing a forward does, so ``softmax - onehot`` is known
+    the moment a chunk's logits are, and the forward rule of the
+    ``custom_vjp`` forms ``dx`` and ``dhead`` from it in the same scan step.
+    KEPT for the backward: ``dx`` (N, d), ``dhead`` (d, V) in ``head``'s
+    dtype, ``dbias`` and the per-row NLL (``weights``' cotangent) — what
+    the backward would hold anyway from its first moment — which it only
+    scales by the scalar cotangent. Nothing V-wide per row is kept, and a
+    chunk multiplies by the vocabulary three times (logits, dx, dhead), not
+    four. A call that nothing differentiates runs one product a chunk."""
     n, d = x.shape
     chunk = min(chunk, n)
     pad = (-n) % chunk
     w = (jnp.ones((n,), jnp.float32) if weights is None
          else weights.astype(jnp.float32))
-    if pad:
+    targets = targets.astype(jnp.int32)
+    if pad:     # pad rows carry weight 0
         x = jnp.concatenate([x, jnp.zeros((pad, d), x.dtype)])
-        targets = jnp.concatenate(
-            [targets, jnp.zeros((pad,), targets.dtype)])
+        targets = jnp.concatenate([targets, jnp.zeros((pad,), jnp.int32)])
         w = jnp.concatenate([w, jnp.zeros((pad,), jnp.float32)])
-    xk = x.reshape(-1, chunk, d)
-    tk = targets.reshape(-1, chunk)
-    wk = w.reshape(-1, chunk)
-
-    @jax.checkpoint
-    def chunk_nll(xc, tc, wc):
-        logits = jnp.einsum("cd,dv->cv", xc, head).astype(jnp.float32)
-        if bias is not None:
-            logits = logits + bias.astype(jnp.float32)
-        lse = jax.scipy.special.logsumexp(logits, axis=-1)
-        tl = jnp.take_along_axis(
-            logits, tc[:, None].astype(jnp.int32), -1)[:, 0]
-        return ((lse - tl) * wc).sum()      # pad rows weighted out
-
-    def body(carry, sl):
-        xc, tc, wc = sl
-        return carry + chunk_nll(xc, tc, wc), None
-
-    total, _ = lax.scan(body, jnp.zeros((), jnp.float32), (xk, tk, wk))
-    return total
+    return _chunked_nll(x.reshape(-1, chunk, d), head, bias,
+                        w.reshape(-1, chunk), targets.reshape(-1, chunk))
 
 
 def lm_loss(params, cfg: TransformerConfig, ids, targets, *, aux_weight=1e-2,

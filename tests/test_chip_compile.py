@@ -159,6 +159,29 @@ def test_flash_compiles_at_the_zaya_cell_shape(one_chip, no_persistent_cache,
         assert re.search(rf"%{name}(\.\d+)? = [^\n]*tpu_custom_call", text), name
 
 
+def test_chunked_head_compiles_at_the_zaya_cell_shape(one_chip,
+                                                      no_persistent_cache):
+    """zaya1-train-b1-t32768's head under ``value_and_grad``: 32,768 rows of
+    2,048 against the half table's 131,136 columns, bf16, chunks of 1,024
+    (PR 33). ONE loop with three products, and temporaries that hold a chunk's
+    logits, nothing (N, V)."""
+    from deeplearning4j_tpu.zoo.transformer import _chunked_ce
+
+    x = _sds(one_chip, (32768, 2048), jnp.bfloat16)
+    head = _sds(one_chip, (2048, 131136), jnp.bfloat16)
+    targets = _sds(one_chip, (32768,), jnp.int32)
+    compiled = jax.jit(jax.value_and_grad(
+        lambda x, h, t: _chunked_ce(x, h, t, 1024), argnums=(0, 1))).lower(
+            x, head, targets).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r" while\(", text)) == 1
+    assert len(re.findall(r" convolution\(", text)) == 3
+    # this tree reads 806,178,816 (the checkpointed form it replaced
+    # 806,340,096): a chunk's f32 logits, 537 MB, and their bf16 copy; dx
+    # and dhead are outputs. Every row's logits would be 17.2 GB
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.85e9
+
+
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
                          ids=["bf16", "f32"])
 def test_paged_decode_kernel_compiles(one_chip, no_persistent_cache, dtype):

@@ -1,6 +1,8 @@
 """Pallas kernel tests — run in interpreter mode on the CPU mesh, checked
 against plain-XLA oracles (SURVEY.md §7 R2 item, pulled into R1)."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -111,6 +113,61 @@ def test_save_attn_runs_the_flash_forward_once_a_block(kind, hkv, window):
     assert (n_plain, n_attn, n_full) == (3, 3, 4)
     assert _leaves_equal(g_attn, g_plain)
     assert _leaves_equal(g_full, g_plain)
+
+
+def _vocab_products_and_loops(text, vocab):
+    """(products with the vocabulary dimension in their result or in an
+    operand, loops that hold such a product) in a compiled module's text; a
+    loop's body counts with every computation it calls."""
+    comps = {m.group(1): m.group(2) for m in re.finditer(
+        r"^(?:ENTRY )?%?([\w.\-]+) \([^\n]*\{\n(.*?)^\}", text, re.M | re.S)}
+    wide = {name for name, dims in re.findall(
+        r"%([\w.\-]+) = \w+\[([\d,]*)\]", text)
+        if str(vocab) in dims.split(",")}
+
+    def products(body):
+        return sum(
+            name in wide or any(op in wide for op in
+                                re.findall(r"%([\w.\-]+)", operands))
+            for name, operands in re.findall(
+                r"%([\w.\-]+) = [^\n]*? dot\(([^)]*)\)", body))
+
+    def holds(name, seen):
+        if name in seen or name not in comps:
+            return False
+        seen.add(name)
+        return products(comps[name]) > 0 or any(
+            holds(callee, seen) for callee in re.findall(
+                r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)",
+                comps[name]))
+
+    loops = sum(holds(body, set()) for body in re.findall(
+        r" while\([^\n]*body=%?([\w.\-]+)", text))
+    return products(text), loops
+
+
+def test_chunked_head_multiplies_by_the_vocabulary_three_times_a_chunk():
+    """A tiny tied LM step under ``value_and_grad``, compiled: the chunked
+    head's loop holds the logits' product, ``dx`` and the table's gradient,
+    and there is no second head loop that forms the logits again (with a
+    ``jax.checkpoint`` around each chunk it was 4 products in 2 loops). The
+    loss alone, which nothing differentiates, multiplies once."""
+    from deeplearning4j_tpu.zoo import transformer as tfm
+    vocab = 509     # no other dimension of the step
+    cfg = tfm.TransformerConfig(vocab_size=vocab, d_model=32, n_heads=2,
+                                n_layers=2, d_ff=64, max_seq=16,
+                                dtype=jnp.float32, tie_embeddings=True,
+                                fused_loss=True, loss_chunk=16)
+    params = tfm.init_params(jax.random.PRNGKey(0), cfg)
+    ids = jnp.zeros((4, 16), jnp.int32)
+    loss = lambda p: tfm.lm_loss(p, cfg, ids, ids)         # noqa: E731
+
+    def compiled(fn):
+        return jax.jit(fn).lower(params).compile().as_text()
+
+    assert _vocab_products_and_loops(
+        compiled(jax.value_and_grad(loss)), vocab) == (3, 1)
+    assert _vocab_products_and_loops(compiled(loss), vocab) == (1, 1)
 
 
 def test_save_attn_through_the_transformer_saves_one_output_and_one_lse(

@@ -929,19 +929,24 @@ def test_ring_attention_xla_path_grads(devices8):
         assert float(jnp.abs(a - b).max()) < 5e-5
 
 
-def test_ring_train_step_matches_monolithic(devices8):
+@pytest.mark.parametrize("fused", [False, True],
+                         ids=["unfused", "fused_loss"])
+def test_ring_train_step_matches_monolithic(devices8, fused):
     """MODEL-level ring sequence parallelism: tfm.make_ring_train_step
     (full train step under shard_map over dp2 x sp4 — ring attention,
     global position offsets per sequence shard, pmean'd loss/grads)
     matches the monolithic single-device step: same loss, same updated
-    params, for two consecutive steps."""
+    params, for two consecutive steps. ``fused_loss``: the chunked head's
+    ``custom_vjp`` inside ``shard_map`` (each shard's 16 rows in chunks of
+    6, a pad chunk among them) against the monolithic UNFUSED step."""
     import dataclasses
     mesh = make_mesh(dp=2, sp=4)
     cfg = tfm.TransformerConfig(
         vocab_size=61, d_model=32, n_heads=2, n_layers=2, d_ff=64,
-        max_seq=32, dtype=jnp.float32, remat=False, fused_loss=False,
-        use_ring_attention=True)
-    cfg_mono = dataclasses.replace(cfg, use_ring_attention=False)
+        max_seq=32, dtype=jnp.float32, remat=False, fused_loss=fused,
+        loss_chunk=6, use_ring_attention=True)
+    cfg_mono = dataclasses.replace(cfg, use_ring_attention=False,
+                                   fused_loss=False)
     rng = np.random.default_rng(11)
     ids = jnp.asarray(rng.integers(0, 61, (4, 32)))
     tgt = jnp.asarray(rng.integers(0, 61, (4, 32)))
@@ -974,6 +979,38 @@ def test_ring_train_step_matches_monolithic(devices8):
     with pytest.raises(ValueError, match="exceeds"):
         too_long = jnp.zeros((4, 64), jnp.int32)
         tfm.make_ring_train_step(cfg, opt, mesh)(p_r, o_r, too_long, too_long)
+
+
+@pytest.mark.parametrize("tied", [False, True], ids=["untied", "tied"])
+def test_fused_loss_with_the_head_sharded_by_rows(devices8, tied):
+    """The chunked head's ``custom_vjp`` under GSPMD: with the table's
+    vocabulary rows split over 'tp' (and the batch over dp, sp),
+    ``lm_loss(fused_loss=True)`` gives the single-device loss and
+    gradients."""
+    cfg = tfm.TransformerConfig(vocab_size=64, d_model=16, n_heads=2,
+                                n_layers=2, d_ff=32, max_seq=8,
+                                dtype=jnp.float32, remat=False,
+                                fused_loss=True, loss_chunk=8,
+                                tie_embeddings=tied)
+    params = tfm.init_params(jax.random.PRNGKey(0), cfg)
+    ids = jax.random.randint(jax.random.PRNGKey(1), (4, 8), 0, 64)
+    tgt = jax.random.randint(jax.random.PRNGKey(2), (4, 8), 0, 64)
+    fn = jax.value_and_grad(lambda p, i, t: tfm.lm_loss(p, cfg, i, t))
+    want, g_want = fn(params, ids, tgt)
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    mesh = make_mesh(dp=2, tp=2, sp=2)
+    sh = tfm.shardings_for(mesh, cfg)
+    assert sh["embed" if tied else "head"].spec in (P("tp", None),
+                                                    P(None, "tp"))
+    dsh = NamedSharding(mesh, P("dp", "sp"))
+    got, g_got = jax.jit(fn)(
+        jax.tree_util.tree_map(jax.device_put, params, sh),
+        jax.device_put(ids, dsh), jax.device_put(tgt, dsh))
+    assert abs(float(got) - float(want)) < 1e-5
+    jax.tree_util.tree_map(
+        lambda a, b: np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-6),
+        g_got, g_want)
 
 
 def test_param_averaging_computation_graph(devices8):

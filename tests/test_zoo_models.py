@@ -137,6 +137,78 @@ def test_transformer_fused_loss_matches_naive():
                - float(tfm.lm_loss(pt, cfg_tn, ids, tgt))) < 1e-5
 
 
+def _naive_ce(x, head, targets, weights, bias):
+    logits = x.astype(jnp.float32) @ head.astype(jnp.float32)
+    if bias is not None:
+        logits = logits + bias
+    nll = -jnp.take_along_axis(jax.nn.log_softmax(logits, -1),
+                               targets[:, None], -1)[:, 0]
+    return (nll if weights is None else nll * weights).sum()
+
+
+@pytest.mark.parametrize("case", [
+    "chunk_divides", "pad_path", "weights_with_zeros", "weights_and_bias",
+    "bf16_operands", "cotangent_of_3", "inside_checkpoint",
+    "jit_donated_arguments"])
+def test_chunked_ce_value_and_every_cotangent_match_naive(case):
+    """``_chunked_ce`` forms its gradient in its forward (a ``custom_vjp``):
+    the value and the cotangent of every float operand equal the naive f32
+    ``log_softmax`` form's, whatever wraps the call."""
+    from deeplearning4j_tpu.zoo import transformer as tfm
+
+    n, d, v = 64, 32, 100
+    dtype = jnp.bfloat16 if case == "bf16_operands" else jnp.float32
+    ks = jax.random.split(jax.random.PRNGKey(7), 5)
+    ops = {"x": jax.random.normal(ks[0], (n, d)).astype(dtype),
+           "head": (jax.random.normal(ks[1], (d, v)) / 6).astype(dtype)}
+    targets = jax.random.randint(ks[2], (n,), 0, v)
+    if case in ("weights_with_zeros", "weights_and_bias"):
+        ops["weights"] = 1.5 * (jax.random.uniform(ks[3], (n,)) > 0.3)
+        assert 0 < int((ops["weights"] == 0).sum()) < n
+    if case == "weights_and_bias":
+        ops["bias"] = jax.random.normal(ks[4], (v,))
+    chunk = 24 if case == "pad_path" else 16
+    scale = 3.0 if case == "cotangent_of_3" else 1.0
+
+    def chunked(ops):
+        return scale * tfm._chunked_ce(
+            ops["x"], ops["head"], targets, chunk,
+            weights=ops.get("weights"), bias=ops.get("bias"))
+
+    def naive(ops):
+        return scale * _naive_ce(ops["x"], ops["head"], targets,
+                                 ops.get("weights"), ops.get("bias"))
+
+    fn = jax.value_and_grad(
+        jax.checkpoint(chunked) if case == "inside_checkpoint" else chunked)
+    want, g_want = jax.value_and_grad(naive)(ops)
+    if case == "jit_donated_arguments":
+        fn = jax.jit(fn, donate_argnums=0)
+        got, g_got = fn(jax.tree_util.tree_map(jnp.copy, ops))
+    else:
+        got, g_got = fn(ops)
+    # the primal alone (nothing differentiates) is the same number
+    assert float(chunked(ops)) == pytest.approx(float(got), rel=1e-6)
+    # bf16: the gradients are rounded to bf16 once (2**-8 relative) and
+    # dhead is carried in bf16 over 4 chunks; measured 4.7e-3 of the largest
+    rel = 1e-2 if dtype == jnp.bfloat16 else 2e-6
+    assert float(got) == pytest.approx(float(want), rel=1e-6 if
+                                       dtype == jnp.float32 else 1e-3)
+    assert set(g_got) == set(ops)
+    for name in ops:
+        a, b = (np.asarray(g[name], np.float32) for g in (g_got, g_want))
+        assert g_got[name].dtype == ops[name].dtype, name
+        assert np.abs(b).max() > 0, name
+        assert np.abs(a - b).max() <= rel * np.abs(b).max(), name
+    if case == "cotangent_of_3":
+        _, g_one = jax.value_and_grad(
+            lambda o: tfm._chunked_ce(o["x"], o["head"], targets, chunk))(ops)
+        for name in ops:
+            np.testing.assert_allclose(np.asarray(g_got[name]),
+                                       3.0 * np.asarray(g_one[name]),
+                                       rtol=1e-6)
+
+
 def test_transformer_bf16_scores_attention_close_to_xla():
     """attn_scores_bf16: same math as the stock XLA path up to the bf16
     score quantization — outputs close, loss finite, grads flow."""
