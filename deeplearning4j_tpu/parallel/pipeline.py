@@ -34,6 +34,13 @@ def _stage_loss_fn(cfg, n_stages, other_axes=(), aux_weight=1e-2):
             "router's state would start from zeros at every stage (ROADMAP "
             "Reach B9)")
 
+    if getattr(cfg, "dense_layers", 0) or getattr(cfg, "predict_ahead", 0):
+        raise NotImplementedError(
+            "every stage holds a slice of ONE group of stacked blocks: "
+            "leading dense layers (dense_layers) are a group of another "
+            "shape, and the last stage runs no prediction module "
+            "(predict_ahead) behind its head (ROADMAP Reach B10)")
+
     def fn(params, ids_mb, tgt_mb):
         # params['blocks'] leaves: (L/P, ...) local; embed/head replicated
         stage = lax.axis_index("pp")

@@ -136,14 +136,23 @@ class GenerationEngine:
                 or getattr(cfg, "router", "linear") != "linear"
                 or getattr(cfg, "scaled_residuals", False)
                 or getattr(cfg, "rotary_share", 1.0) != 1.0
-                or getattr(cfg, "norm_eps", 1e-6) != 1e-6):
+                or getattr(cfg, "norm_eps", 1e-6) != 1e-6
+                or getattr(cfg, "dense_layers", 0)
+                or getattr(cfg, "shared_experts", 0)
+                or getattr(cfg, "predict_ahead", 0)):
             raise NotImplementedError(
                 "GenerationEngine cannot decode this block: compressed "
                 "convolutional attention (attention='cca') needs the "
                 "convolutions' last tokens and the shifted value beside the "
                 "KV pages, the mlp router its state from layer to layer, and "
                 "the decode step knows no scaled residuals, no partial "
-                "rotary and one norm_eps (ROADMAP Reach B9)")
+                "rotary and one norm_eps (ROADMAP Reach B9); latent "
+                "attention (attention='mla') needs a cache of the latent and "
+                "the shared rotary key with the up-projections absorbed into "
+                "the query and the output, and the decode step knows no "
+                "sigmoid router, no shared expert, no leading dense layers "
+                "(two groups of blocks) and no prediction module to draft "
+                "with (ROADMAP Reach B10)")
         if getattr(cfg, "n_experts", 0):
             raise NotImplementedError(
                 "GenerationEngine is dense-only: MoE expert dispatch has "

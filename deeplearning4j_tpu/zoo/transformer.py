@@ -134,6 +134,7 @@ class TransformerConfig:
     # half of the value channels is the token before's, every head of q and
     # k is scaled to norm sqrt(head size) (k times a learned temperature a
     # K/V head), and only then come rotary and the causal softmax.
+    # "mla": latent attention, with the fields of PR 34 below.
     attention: str = "mha"
     cca_taps: tuple = (2, 2)
     rotary_share: float = 1.0   # the share of a head "rope" rotates, from
@@ -145,14 +146,52 @@ class TransformerConfig:
     # the largest of probability plus a bias no gradient reaches, the weight
     # that choice's probability over ALL outputs. router_skip: one more
     # output, after the experts', whose tokens get nothing from the layer.
+    # "sigmoid": see router_scale below.
     router: str = "linear"
     router_hidden: int = 0
     router_skip: bool = False
     # x + f(x) -> (s x + b) + (s' f(x) + b'), learned vectors of d_model
     scaled_residuals: bool = False
+    # ---- PR 34. As above: what the model is, never how it is computed.
+    # attention="mla": multi-head latent attention (arXiv:2405.04434 §2.1).
+    # Queries go down to a latent of q_rank, through an RMS norm, and up to
+    # n_heads heads of nope_head_size + rope_head_size; keys and values come
+    # from ONE latent of kv_rank (an RMS norm, then up to n_heads x
+    # (nope_head_size + v_head_size)) beside ONE rotary key of rope_head_size
+    # that every head reads; a head's query and key are the part without
+    # positions joined to the rotated part (the LAST rope_head_size
+    # dimensions), its value v_head_size wide.
+    q_rank: int = 0
+    kv_rank: int = 0
+    nope_head_size: int = 0
+    rope_head_size: int = 0
+    v_head_size: int = 0
+    # the first dense_layers layers of the stack have a dense MLP of width
+    # d_ff, the layers after them the routed experts (their own group of
+    # stacked blocks, params["dense_blocks"] before params["blocks"])
+    dense_layers: int = 0
+    expert_ff: int = 0          # the experts' own width; 0 → d_ff
+    # experts every token takes with weight 1 beside the routed ones, as one
+    # MLP of shared_experts · (expert_ff or d_ff), held whole by every share
+    shared_experts: int = 0
+    # router="sigmoid" (arXiv:2412.19437 §2.1.2): scores = sigmoid(x W) in
+    # float32; the CHOICE is top-k of score plus a bias no gradient reaches
+    # (router_beta); the WEIGHT is the chosen scores, without the bias, over
+    # their sum, times router_scale
+    router_scale: float = 1.0
+    # multi-token prediction (arXiv:2412.19437 §2.2): predict_ahead modules
+    # after the stack (0 or 1), each [rmsnorm(hidden) | rmsnorm(embedding of
+    # the NEXT token)] through a 2·d_model -> d_model projection and one more
+    # block (an expert block where the stack has them), its own final norm,
+    # the SAME embedding and the SAME head, scored on the token after next;
+    # loss = main + predict_weight · that
+    predict_ahead: int = 0
+    predict_weight: float = 0.3
 
     @property
     def head_dim(self):
+        if self.attention == "mla":     # of q and k; _check holds v to it
+            return self.nope_head_size + self.rope_head_size
         return self.head_size or self.d_model // self.n_heads
 
     @property
@@ -188,10 +227,27 @@ def _check(cfg: TransformerConfig):
     if cfg.mlp not in ("gelu", "reglu", "swiglu"):
         raise ValueError(f"Unknown mlp {cfg.mlp!r}; 'gelu', 'reglu' or "
                          "'swiglu'")
-    if cfg.attention not in ("mha", "cca"):
+    if cfg.attention not in ("mha", "cca", "mla"):
         raise ValueError(f"Unknown attention {cfg.attention!r}")
-    if cfg.router not in ("linear", "mlp"):
+    if cfg.router not in ("linear", "mlp", "sigmoid"):
         raise ValueError(f"Unknown router {cfg.router!r}")
+    if cfg.attention == "mla":
+        if min(cfg.q_rank, cfg.kv_rank, cfg.nope_head_size,
+               cfg.rope_head_size, cfg.v_head_size) < 1 \
+                or cfg.rope_head_size % 2:
+            raise ValueError(
+                "mla wants q_rank, kv_rank, nope_head_size, v_head_size and "
+                f"an even rope_head_size (got {cfg.q_rank}, {cfg.kv_rank}, "
+                f"{cfg.nope_head_size}, {cfg.v_head_size}, "
+                f"{cfg.rope_head_size})")
+        if cfg.v_head_size != cfg.head_dim or cfg.kv_heads != cfg.n_heads \
+                or cfg.head_size not in (0, cfg.head_dim) \
+                or cfg.rotary_share != 1.0 or cfg.use_ring_attention:
+            raise NotImplementedError(
+                "mla hands the attention paths assembled heads of ONE size "
+                "(v_head_size = nope_head_size + rope_head_size), a K/V head "
+                "per query head, rotates all of its rotated part "
+                "(rotary_share=1) and has no ring step")
     if "rope" in cfg.layer_positions and (
             cfg.rotary_dims < 2 or cfg.rotary_dims % 2
             or cfg.rotary_dims > cfg.head_dim):
@@ -217,6 +273,35 @@ def _check(cfg: TransformerConfig):
                 "input and needs router_hidden")
     elif cfg.router_skip:
         raise NotImplementedError("only the mlp router has a skip output")
+    if cfg.router == "sigmoid" and (
+            not cfg.experts_held or cfg.router_input != "post_attention"):
+        raise NotImplementedError(
+            "the sigmoid router feeds the dropless layer (experts_held) and "
+            "reads the MLP's input")
+    if cfg.shared_experts and not cfg.experts_held:
+        raise NotImplementedError(
+            "shared experts stand beside the dropless layer (experts_held)")
+    if cfg.dense_layers:
+        if not 0 < cfg.dense_layers < cfg.n_layers or not cfg.experts_held:
+            raise ValueError(
+                f"dense_layers={cfg.dense_layers} are the leading layers of "
+                f"a stack of n_layers={cfg.n_layers} whose other layers hold "
+                "routed experts (experts_held)")
+        if cfg.router == "mlp" or len(cfg.layer_kinds) != 1:
+            raise NotImplementedError(
+                "the leading dense layers are a group of their own: the mlp "
+                "router's state and a period of several kinds of layer do "
+                "not cross from one group to the next")
+    if cfg.predict_ahead not in (0, 1):
+        raise NotImplementedError(
+            f"predict_ahead={cfg.predict_ahead}: one prediction module, one "
+            "token further ahead, is what the loss computes")
+    if cfg.predict_ahead and (cfg.router == "mlp" or cfg.use_ring_attention
+                              or len(cfg.layer_kinds) != 1):
+        raise NotImplementedError(
+            "the prediction module is one more block of the stack's one "
+            "kind, over whole rows (no ring step), and starts no router "
+            "state of its own")
     if cfg.router_input not in ("pre_attention", "post_attention"):
         raise ValueError(f"Unknown router_input {cfg.router_input!r}")
     if cfg.experts_held:
@@ -236,33 +321,42 @@ def _check(cfg: TransformerConfig):
 
 # ---------------------------------------------------------------- params
 
-def init_params(key, cfg: TransformerConfig):
-    """Stacked-block params. Names are stable for checkpoints/sharding."""
-    _check(cfg)
-    k = jax.random.split(key, 12)
+def _group_configs(cfg: TransformerConfig):
+    """The stack as groups of blocks of ONE shape each: (the leading dense
+    layers' configuration or None, the other layers', the prediction
+    module's one block's or None). Each is ``cfg`` with the other groups'
+    fields cleared, so that what draws, shards or runs a stack of one shape
+    serves all three."""
+    rest = dataclasses.replace(cfg, n_layers=cfg.n_layers - cfg.dense_layers,
+                               dense_layers=0, predict_ahead=0)
+    dense = module = None
+    if cfg.dense_layers:
+        dense = dataclasses.replace(
+            rest, n_layers=cfg.dense_layers, n_experts=0, experts_held=(),
+            shared_experts=0, expert_ff=0, router="linear")
+    if cfg.predict_ahead:
+        module = dataclasses.replace(rest, n_layers=1)
+    return dense, rest, module
+
+
+def _init_blocks(k, cfg: TransformerConfig):
+    """The stacked blocks of a stack of one shape, from 12 keys."""
     d, f, h, L = cfg.d_model, cfg.d_ff, cfg.n_heads * cfg.head_dim, cfg.n_layers
     hkv = cfg.kv_heads * cfg.head_dim
-    f_in = f if cfg.mlp == "gelu" else 2 * f     # gate | up side by side
+    gated = 1 if cfg.mlp == "gelu" else 2        # gate | up side by side
     pd = cfg.param_dtype
 
     def norm(key, shape, fan_in):
         return (jax.random.normal(key, shape, pd) / math.sqrt(fan_in))
 
-    params = {
-        "embed": norm(k[0], (cfg.vocab_size, d), d),  # scaled-init embedding
-        "pos_embed": 0.02 * jax.random.normal(k[1], (cfg.max_seq, d), pd),
-        "blocks": {
-            "ln1": jnp.ones((L, d), pd),
-            "wqkv": norm(k[2], (L, d, h + 2 * hkv), d),
-            "wo": norm(k[3], (L, h, d), h),
-            "ln2": jnp.ones((L, d), pd),
-        },
-        "ln_f": jnp.ones((d,), pd),
+    blocks = {
+        "ln1": jnp.ones((L, d), pd),
+        "wqkv": norm(k[2], (L, d, h + 2 * hkv), d),
+        "wo": norm(k[3], (L, h, d), h),
+        "ln2": jnp.ones((L, d), pd),
     }
-    if cfg.layer_positions:         # rotary or no positions: no table
-        del params["pos_embed"]
-    blocks = params["blocks"]
     kk = jax.random.split(k[10], 8)     # PR 32's leaves; k[0..9] as before
+    kn = jax.random.split(k[11], 8)     # PR 34's; [6] and [7]: init_params
     if cfg.attention == "cca":
         k0, k1 = cfg.cca_taps
         dh, hc = cfg.head_dim, cfg.n_heads + cfg.kv_heads
@@ -272,6 +366,17 @@ def init_params(key, cfg: TransformerConfig):
             cca_w1=norm(kk[1], (L, k1, hc, dh, dh), k1 * dh),   # per head
             cca_b1=jnp.zeros((L, hc * dh), pd),
             cca_tau=jnp.ones((L, cfg.kv_heads), pd))
+    if cfg.attention == "mla":      # no wqkv: two latents and a shared key
+        H, dn = cfg.n_heads, cfg.nope_head_size
+        dr, dv = cfg.rope_head_size, cfg.v_head_size
+        del blocks["wqkv"]
+        blocks.update(
+            wq_a=norm(kn[0], (L, d, cfg.q_rank), d),
+            q_norm=jnp.ones((L, cfg.q_rank), pd),
+            wq_b=norm(kn[1], (L, cfg.q_rank, H * (dn + dr)), cfg.q_rank),
+            wkv_a=norm(kn[2], (L, d, cfg.kv_rank + dr), d),
+            kv_norm=jnp.ones((L, cfg.kv_rank), pd),
+            wkv_b=norm(kn[3], (L, cfg.kv_rank, H * (dn + dv)), cfg.kv_rank))
     if cfg.scaled_residuals:    # rows: s1, s2, s3, s4 and b1, b2, b3, b4
         blocks.update(res_scale=jnp.ones((L, 4, d), pd),
                       res_bias=jnp.zeros((L, 4, d), pd))
@@ -290,13 +395,52 @@ def init_params(key, cfg: TransformerConfig):
     if cfg.n_experts:
         E = cfg.n_experts           # the router's width, held or not
         held = cfg.experts_held[1] if cfg.experts_held else E
-        if cfg.router == "linear":
-            params["blocks"]["router"] = norm(k[4], (L, d, E), d)
-        params["blocks"]["we_in"] = norm(k[5], (L, held, d, f_in), d)
-        params["blocks"]["we_out"] = norm(k[6], (L, held, f, d), f)
+        fe = cfg.expert_ff or f
+        if cfg.router != "mlp":
+            blocks["router"] = norm(k[4], (L, d, E), d)
+        if cfg.router == "sigmoid":
+            blocks["router_beta"] = jnp.zeros((L, E), pd)   # no gradient
+        blocks["we_in"] = norm(k[5], (L, held, d, gated * fe), d)
+        blocks["we_out"] = norm(k[6], (L, held, fe, d), fe)
+        if cfg.shared_experts:
+            fs = cfg.shared_experts * fe
+            blocks["ws_in"] = norm(kn[4], (L, d, gated * fs), d)
+            blocks["ws_out"] = norm(kn[5], (L, fs, d), fs)
     else:
-        params["blocks"]["w_in"] = norm(k[7], (L, d, f_in), d)
-        params["blocks"]["w_out"] = norm(k[8], (L, f, d), f)
+        blocks["w_in"] = norm(k[7], (L, d, gated * f), d)
+        blocks["w_out"] = norm(k[8], (L, f, d), f)
+    return blocks
+
+
+def init_params(key, cfg: TransformerConfig):
+    """Stacked-block params. Names are stable for checkpoints/sharding."""
+    _check(cfg)
+    k = jax.random.split(key, 12)
+    d, pd = cfg.d_model, cfg.param_dtype
+    dense, rest, module = _group_configs(cfg)
+
+    def norm(key, shape, fan_in):
+        return (jax.random.normal(key, shape, pd) / math.sqrt(fan_in))
+
+    params = {
+        "embed": norm(k[0], (cfg.vocab_size, d), d),  # scaled-init embedding
+        "pos_embed": 0.02 * jax.random.normal(k[1], (cfg.max_seq, d), pd),
+        "blocks": _init_blocks(k, rest),
+        "ln_f": jnp.ones((d,), pd),
+    }
+    if cfg.layer_positions:         # rotary or no positions: no table
+        del params["pos_embed"]
+    kn = jax.random.split(k[11], 8)
+    if dense is not None:           # the leading layers, stacked apart
+        params["dense_blocks"] = _init_blocks(jax.random.split(kn[6], 12),
+                                              dense)
+    if module is not None:
+        km = jax.random.split(kn[7], 13)
+        params["mtp"] = {
+            "ln_h": jnp.ones((d,), pd), "ln_e": jnp.ones((d,), pd),
+            "proj": norm(km[12], (2 * d, d), 2 * d),    # [hidden | embedding]
+            "block": _init_blocks(km[:12], module),
+            "ln_f": jnp.ones((d,), pd)}
     if not cfg.tie_embeddings:
         params["head"] = norm(k[9], (d, cfg.vocab_size), d)
     return params
@@ -330,39 +474,60 @@ def draft_params(params, cfg: TransformerConfig, n_layers: int = 2):
     return dcfg, out
 
 
-def param_pspecs(cfg: TransformerConfig):
-    """PartitionSpecs per param (tp/ep sharding; fsdp composes on top)."""
+def _block_pspecs(cfg: TransformerConfig):
+    """PartitionSpecs of the stacked blocks of a stack of one shape."""
     specs = {
-        "embed": P("tp", None),          # vocab-sharded embedding
-        "pos_embed": P(),
-        "blocks": {
-            "ln1": P(),
-            "wqkv": P(None, None, "tp"),   # column parallel
-            "wo": P(None, "tp", None),     # row parallel
-            "ln2": P(),
-        },
-        "ln_f": P(),
+        "ln1": P(),
+        "wqkv": P(None, None, "tp"),   # column parallel
+        "wo": P(None, "tp", None),     # row parallel
+        "ln2": P(),
     }
-    if cfg.layer_positions:
-        del specs["pos_embed"]
     small = ()
     if cfg.attention == "cca":
         small += ("cca_w0", "cca_b0", "cca_w1", "cca_b1", "cca_tau")
+    if cfg.attention == "mla":      # the latents whole, the heads by column
+        del specs["wqkv"]
+        small += ("wq_a", "q_norm", "wkv_a", "kv_norm")
+        specs.update(wq_b=P(None, None, "tp"), wkv_b=P(None, None, "tp"))
     if cfg.scaled_residuals:
         small += ("res_scale", "res_bias")
     if cfg.router == "mlp":
         small += ("router_down", "router_down_b", "router_gamma", "router_w1",
                   "router_c1", "router_w2", "router_c2", "router_w3",
                   "router_beta")
-    specs["blocks"].update({name: P() for name in small})
     if cfg.n_experts:
-        if cfg.router == "linear":
-            specs["blocks"]["router"] = P()
-        specs["blocks"]["we_in"] = P(None, "ep", None, "tp")
-        specs["blocks"]["we_out"] = P(None, "ep", "tp", None)
+        if cfg.router != "mlp":
+            small += ("router",)
+        if cfg.router == "sigmoid":
+            small += ("router_beta",)
+        specs["we_in"] = P(None, "ep", None, "tp")
+        specs["we_out"] = P(None, "ep", "tp", None)
+        if cfg.shared_experts:
+            specs["ws_in"] = P(None, None, "tp")
+            specs["ws_out"] = P(None, "tp", None)
     else:
-        specs["blocks"]["w_in"] = P(None, None, "tp")
-        specs["blocks"]["w_out"] = P(None, "tp", None)
+        specs["w_in"] = P(None, None, "tp")
+        specs["w_out"] = P(None, "tp", None)
+    specs.update({name: P() for name in small})
+    return specs
+
+
+def param_pspecs(cfg: TransformerConfig):
+    """PartitionSpecs per param (tp/ep sharding; fsdp composes on top)."""
+    dense, rest, module = _group_configs(cfg)
+    specs = {
+        "embed": P("tp", None),          # vocab-sharded embedding
+        "pos_embed": P(),
+        "blocks": _block_pspecs(rest),
+        "ln_f": P(),
+    }
+    if cfg.layer_positions:
+        del specs["pos_embed"]
+    if dense is not None:
+        specs["dense_blocks"] = _block_pspecs(dense)
+    if module is not None:
+        specs["mtp"] = {"ln_h": P(), "ln_e": P(), "proj": P(),
+                        "block": _block_pspecs(module), "ln_f": P()}
     if not cfg.tie_embeddings:
         specs["head"] = P(None, "tp")
     return specs
@@ -494,6 +659,41 @@ def _cca_qkv(cfg, h, blk, positions):
     return q.reshape(b, t, hq), k.reshape(b, t, hk), v
 
 
+def _mla_qkv(cfg, h, blk, positions):
+    """Multi-head latent attention up to the softmax, in the training form:
+    the normed input (B, T, d) -> q, k, v (B, T, H·Dh) with the heads
+    ASSEMBLED, a head of q and of k the nope_head_size dimensions without
+    positions and then the rope_head_size rotated ones (``positions ==
+    "rope"``), the rotated key ONE head that every head reads. The
+    up-projections are not absorbed into the query and the output: that
+    form serves a cache of the latent and is a decode step's."""
+    b, t, _ = h.shape
+    H, dn, dr = cfg.n_heads, cfg.nope_head_size, cfg.rope_head_size
+    with jax.named_scope("mla_q"):
+        cq = jnp.einsum("btd,dr->btr", h, blk["wq_a"].astype(h.dtype))
+        cq = _rmsnorm(cq, blk["q_norm"], cfg.norm_eps)
+        q = jnp.einsum("btr,rz->btz", cq, blk["wq_b"].astype(h.dtype))
+        q = _constrain(q, "dp", "sp", "tp").reshape(b, t, H, dn + dr)
+    with jax.named_scope("mla_kv"):
+        ckv = jnp.einsum("btd,dr->btr", h, blk["wkv_a"].astype(h.dtype))
+        c = _rmsnorm(ckv[..., :cfg.kv_rank], blk["kv_norm"], cfg.norm_eps)
+        kv = jnp.einsum("btr,rz->btz", c, blk["wkv_b"].astype(h.dtype))
+        kv = _constrain(kv, "dp", "sp", "tp").reshape(
+            b, t, H, dn + cfg.v_head_size)
+    q_rope, k_rope = q[..., dn:], ckv[:, :, None, cfg.kv_rank:]
+    if positions == "rope":
+        with jax.named_scope("mla_rope"):
+            q_rope = _rope(q_rope, cfg.rope_theta)
+            k_rope = _rope(k_rope, cfg.rope_theta)
+    with jax.named_scope("mla_q"):
+        q = jnp.concatenate([q[..., :dn], q_rope], -1)
+    with jax.named_scope("mla_kv"):
+        k = jnp.concatenate(
+            [kv[..., :dn], jnp.broadcast_to(k_rope, (b, t, H, dr))], -1)
+        v = kv[..., dn:]
+    return (q.reshape(b, t, -1), k.reshape(b, t, -1), v.reshape(b, t, -1))
+
+
 def _attention(cfg, q, k, v, mask_bias=None, positions="learned", window=0):
     """``positions`` and ``window`` are the layer's kind (static): rotary
     q and k or none; the keys a query sees (0 = every earlier one)."""
@@ -501,7 +701,7 @@ def _attention(cfg, q, k, v, mask_bias=None, positions="learned", window=0):
     q = q.reshape(b, t, cfg.n_heads, cfg.head_dim)
     k = k.reshape(b, t, cfg.kv_heads, cfg.head_dim)
     v = v.reshape(b, t, cfg.kv_heads, cfg.head_dim)
-    if positions == "rope" and cfg.attention != "cca":  # cca rotates itself
+    if positions == "rope" and cfg.attention == "mha":  # the others: theirs
         q = _rope(q, cfg.rope_theta, rotary=cfg.rotary_dims)
         k = _rope(k, cfg.rope_theta, rotary=cfg.rotary_dims)
     path = attention_path(cfg, t, q.dtype)
@@ -668,6 +868,21 @@ def _route_top_k(cfg, logits):
         top, chosen = lax.top_k(logits, cfg.expert_top_k)       # (N, K)
         weight = jax.nn.softmax(top, axis=-1).T                 # (K, N)
         return chosen.T, weight
+
+
+def _route_sigmoid(cfg, logits, beta):
+    """The sigmoid router's choice on the (N, E) float32 logits: top-k of
+    score plus ``beta`` (a bias no gradient reaches), weighted by the chosen
+    scores themselves over their sum, times ``router_scale``; both (K, N)."""
+    with jax.named_scope("moe_router"):
+        score = jax.nn.sigmoid(logits)
+        _, chosen = lax.top_k(
+            score + lax.stop_gradient(beta.astype(jnp.float32)),
+            cfg.expert_top_k)                                   # (N, K)
+        kept = jnp.take_along_axis(score, chosen, axis=-1)
+        weight = cfg.router_scale * kept / (
+            jnp.sum(kept, axis=-1, keepdims=True) + 1e-20)
+        return chosen.T.astype(jnp.int32), weight.T
 
 
 def _route_mlp(cfg, u, state, blk):
@@ -883,6 +1098,11 @@ def apply_blocks(blocks, cfg: TransformerConfig, x, *, return_kv=False):
     ``(x, aux_sum, (k, v))``. Remat is skipped on that path — prefill is
     forward-only, there are no residuals to trade for recompute — which
     keeps the captured k/v out of any checkpoint policy's hands."""
+    if cfg.dense_layers:
+        raise NotImplementedError(
+            "a stack with leading dense layers is two groups of blocks "
+            "(params['dense_blocks'], params['blocks']): forward() and "
+            "lm_loss() run both")
     x, auxes, kvs, _ = _run_blocks(blocks, cfg, x, return_kv)
     if return_kv:
         return x, jnp.sum(auxes), kvs
@@ -920,6 +1140,8 @@ def _run_blocks(blocks, cfg: TransformerConfig, x, return_kv=False):
         h = _rmsnorm(x, blk["ln1"], cfg.norm_eps)
         if cfg.attention == "cca":
             q, k, v = _cca_qkv(cfg, h, blk, positions)
+        elif cfg.attention == "mla":
+            q, k, v = _mla_qkv(cfg, h, blk, positions)
         else:
             qkv = jnp.einsum("btd,dz->btz", h, blk["wqkv"].astype(h.dtype))
             qkv = _constrain(qkv, "dp", "sp", "tp")
@@ -939,12 +1161,19 @@ def _run_blocks(blocks, cfg: TransformerConfig, x, return_kv=False):
             else:
                 if routed is None:
                     routed = _router_logits(h2, blk["router"])
-                chosen, weight = _route_top_k(cfg, routed)
+                if cfg.router == "sigmoid":
+                    chosen, weight = _route_sigmoid(cfg, routed,
+                                                    blk["router_beta"])
+                else:
+                    chosen, weight = _route_top_k(cfg, routed)
             m, load = _moe_share(cfg, h2, chosen, weight, blk["we_in"],
                                  blk["we_out"])
             told = {"load": load}
-            if cfg.router == "mlp":     # an argmax: see make_train_step
+            if cfg.router != "linear":  # an argmax: see make_train_step
                 told["choices"] = chosen
+            if cfg.shared_experts:      # every token's, once on every chip
+                with jax.named_scope("moe_shared"):
+                    m = m + _dense_mlp(cfg, h2, blk["ws_in"], blk["ws_out"])
             aux = 0.0
         elif cfg.n_experts:
             m, aux = _moe_mlp(cfg, h2, blk["router"], blk["we_in"], blk["we_out"])
@@ -991,12 +1220,23 @@ def _run_blocks(blocks, cfg: TransformerConfig, x, return_kv=False):
     return x, auxes, kvs, told
 
 
+def _run_stack(params, cfg: TransformerConfig, x):
+    """The whole stack on embedded activations: the leading dense layers'
+    group, where there is one, then the other layers'. Returns (x, auxes,
+    what the held experts' layers tell or None) as :func:`_run_blocks`."""
+    dense, rest, _ = _group_configs(cfg)
+    if dense is not None:
+        x = _run_blocks(params["dense_blocks"], dense, x)[0]
+    x, auxes, _, told = _run_blocks(params["blocks"], rest, x)
+    return x, auxes, told
+
+
 def forward(params, cfg: TransformerConfig, ids, *, train=False, rng=None,
             pos_offset=0):
     """ids (B, T) int32 → logits (B, T, vocab). Returns (logits, aux_loss)."""
     x = embed(params, cfg, ids, pos_offset)
-    x, aux = apply_blocks(params["blocks"], cfg, x)
-    return head_logits(params, cfg, x), aux
+    x, auxes, _ = _run_stack(params, cfg, x)
+    return head_logits(params, cfg, x), jnp.sum(auxes)
 
 
 def _use_fused_loss(cfg: TransformerConfig, n_rows: int) -> bool:
@@ -1115,22 +1355,57 @@ def lm_loss(params, cfg: TransformerConfig, ids, targets, *, aux_weight=1e-2,
 
 def _lm_loss_stats(params, cfg: TransformerConfig, ids, targets, *,
                    aux_weight=1e-2, pos_offset=0):
-    """(loss, what the held experts' layers tell, or None): see
-    :func:`_run_blocks`."""
+    """(loss, what the step tells, or None): what the held experts' layers
+    tell (see :func:`_run_blocks`) and, with a prediction module, its
+    block's row after the stack's and ``"losses"``: float32 [the main loss,
+    the predicted-token loss], apart."""
     b, t = ids.shape
     x = embed(params, cfg, ids, pos_offset)
-    x, auxes, _, told = _run_blocks(params["blocks"], cfg, x)
+    x, auxes, told = _run_stack(params, cfg, x)
     aux = jnp.sum(auxes)
-    if _use_fused_loss(cfg, b * t):
-        x = _rmsnorm(x, params["ln_f"], cfg.norm_eps)
-        head = _resolve_head(params, cfg)
-        nll = _chunked_ce(x.reshape(b * t, -1), head.astype(x.dtype),
-                          targets.reshape(b * t), cfg.loss_chunk) / (b * t)
-        return nll + aux_weight * aux, told
-    logits = head_logits(params, cfg, x)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    nll = -jnp.take_along_axis(logp, targets[..., None].astype(jnp.int32), -1)[..., 0]
-    return nll.mean() + aux_weight * aux, told
+    head = _resolve_head(params, cfg)
+    fused = _use_fused_loss(cfg, b * t)
+
+    def mean_nll(z, tgt, weights=None, rows=b * t):
+        """Mean NLL of the normed (B, T, d) ``z`` over ``rows`` rows."""
+        if fused:
+            w = None if weights is None else weights.reshape(b * t)
+            return _chunked_ce(z.reshape(b * t, -1), head.astype(z.dtype),
+                               tgt.reshape(b * t), cfg.loss_chunk,
+                               weights=w) / rows
+        logits = jnp.einsum("btd,dv->btv", z, head.astype(z.dtype))
+        logits = _constrain(logits, "dp", "sp", "tp").astype(jnp.float32)
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        nll = -jnp.take_along_axis(logp, tgt[..., None].astype(jnp.int32),
+                                   -1)[..., 0]
+        return nll.mean() if weights is None else (nll * weights).sum() / rows
+
+    main = mean_nll(_rmsnorm(x, params["ln_f"], cfg.norm_eps), targets)
+    loss = main + aux_weight * aux
+    if not cfg.predict_ahead:
+        return loss, told
+    with jax.named_scope("mtp"):
+        m, module = params["mtp"], _group_configs(cfg)[2]
+        # position i joins the stack's output (before the final norm) to the
+        # embedding of the NEXT token, targets_i, and is scored on the token
+        # after it, targets_{i+1}; the row's last position has none and
+        # weighs 0 (it is computed, for the shapes' sake: causal attention
+        # and per-token experts keep it from every other position)
+        e = embed(params, cfg, targets, pos_offset)
+        p = jnp.concatenate([_rmsnorm(x, m["ln_h"], cfg.norm_eps),
+                             _rmsnorm(e, m["ln_e"], cfg.norm_eps)], -1)
+        p = jnp.einsum("btz,zd->btd", p, m["proj"].astype(p.dtype))
+        p, _, _, told_m = _run_blocks(m["block"], module, p)
+        after = jnp.roll(targets, -1, axis=1)
+        has = jnp.broadcast_to(jnp.arange(t) < t - 1, (b, t))
+        ahead = mean_nll(_rmsnorm(p, m["ln_f"], cfg.norm_eps), after,
+                         has.astype(jnp.float32), rows=b * (t - 1))
+    loss = loss + cfg.predict_weight * ahead
+    if told is not None:    # the module's block counts as one more layer
+        told = jax.tree_util.tree_map(
+            lambda a, c: jnp.concatenate([a, c]), told, told_m)
+    told = dict(told or {}, losses=jnp.stack([main, ahead]))
+    return loss, told
 
 
 def make_train_step(cfg: TransformerConfig, optimizer):
@@ -1140,12 +1415,16 @@ def make_train_step(cfg: TransformerConfig, optimizer):
     routed experts (``experts_held``), a fourth output, computed on the
     device beside the loss: ``{"load": the per-layer expert-load stats (L, 4
     or 5) float32 of :func:`_moe_share```} (``obs.moe.record_expert_load``
-    counts them) and, under the mlp router, ``"choices"``: the expert every
-    token took in every layer, (L, 1, B·T) int32. A choice is an argmax, and
-    a tie within the compute dtype's rounding falls the other way in another
-    precision: whoever compares the step with another computation of the
-    same model hands it these choices, so that both differentiate one
-    function."""
+    counts them) and, under the mlp and the sigmoid router, ``"choices"``:
+    the experts every token took in every layer, (L, K, B·T) int32. A choice
+    is an argmax, and a tie within the compute dtype's rounding falls the
+    other way in another precision: whoever compares the step with another
+    computation of the same model hands it these choices, so that both
+    differentiate one function. L counts the layers that route: not the
+    leading dense ones, and a prediction module's block as one more, last.
+    With such a module (``predict_ahead``) the fourth output also holds
+    ``"losses"``: float32 [main, predicted-token], the two parts of the
+    loss apart (``obs.lm.record_losses`` keeps them)."""
 
     def step(params, opt_state, ids, targets):
         (loss, told), grads = jax.value_and_grad(

@@ -159,6 +159,40 @@ def test_flash_compiles_at_the_zaya_cell_shape(one_chip, no_persistent_cache,
         assert re.search(rf"%{name}(\.\d+)? = [^\n]*tpu_custom_call", text), name
 
 
+def _glm_flash_grad(blocks):
+    from deeplearning4j_tpu.kernels.flash_attention import flash_attention
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, None, True, *blocks, False)
+                       .astype(jnp.float32))
+
+    return jax.grad(loss, argnums=(0, 1, 2))
+
+
+@pytest.mark.parametrize("blocks", [(512, 1024), (1024, 512), (512, 512)],
+                         ids=["512x1024", "1024x512", "512x512"])
+def test_flash_compiles_at_the_glm_cell_shape(one_chip, no_persistent_cache,
+                                              blocks):
+    """glm47flash-train-b1-t8192's assembled heads: 20 heads, a K/V head
+    each, of 256 (192 + 64 for q and k, 256 for v) over 8,192 positions,
+    full causal (PR 34): the kernels' first head of 256."""
+    x = _sds(one_chip, (1, 20, 8192, 256), jnp.bfloat16)
+    text = _compile(_glm_flash_grad(blocks), x, x, x)
+    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert re.search(rf"%{name}(\.\d+)? = [^\n]*tpu_custom_call", text), name
+
+
+def test_the_largest_block_pair_is_refused_at_head_size_256(
+        one_chip, no_persistent_cache):
+    """1024 x 1024 does not fit VMEM at a head of 256 (the dkv kernel's
+    stack): the compiler raises an ordinary exception, which the block race
+    (``kernels/autotune.py::_race``) records for the candidate and goes on
+    from; the race does not die on it."""
+    x = _sds(one_chip, (1, 20, 8192, 256), jnp.bfloat16)
+    with pytest.raises(Exception, match="(?i)vmem"):
+        _compile(_glm_flash_grad((1024, 1024)), x, x, x)
+
+
 def test_chunked_head_compiles_at_the_zaya_cell_shape(one_chip,
                                                       no_persistent_cache):
     """zaya1-train-b1-t32768's head under ``value_and_grad``: 32,768 rows of
