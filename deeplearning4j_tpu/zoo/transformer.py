@@ -33,6 +33,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
+from jax.extend import core as jex_core
+from jax.interpreters import mlir
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
@@ -921,6 +923,20 @@ def _take_rows(x, idx):
 # token's K rows is a sum of K slabs; numbered token-major, (N, K, d) pads
 # its K = 6 rows to the chip's tile of 8 and every reshape is a copy
 # (1.8 ms each at 98,304 x 2560; my chip run, PR 28).
+#
+# A layer moves rows four times, and what each crosses differs (PR 36). The
+# way OUT (tokens to expert order) is one gather of A rows from the N tokens
+# and its gradient one gather of A rows by ``inv`` from the A rows of the
+# grouped products' gradient, summed over a token's local rows. The way BACK
+# (expert order to the tokens' weighted sum) is the same gather by ``inv``
+# from the grouped products' A rows; ITS gradient alone stops at
+# ``n_local``, the rows routed here: a pass of rows at a time, gathered from
+# the N rows of the sum's gradient and scaled (:func:`_scaled_rows`). The
+# two gathers by ``inv`` write every assignment's place, so the rows they
+# cross are not bounded by the rows in use: XLA's row scatter, which would
+# be, takes 137 to 540 ns a row on the v5e where its gather takes 41, and a
+# Pallas kernel cannot slice one row of an array tiled in HBM (Mosaic: a
+# slice along the second-minor dimension must be aligned to the tile's 8).
 
 @jax.custom_vjp
 def _rows_out(tokens, order, inv, local):
@@ -946,22 +962,105 @@ def _rows_out_bwd(res, g):
 _rows_out.defvjp(_rows_out_fwd, _rows_out_bwd)
 
 
+#: rows a pass of :func:`_scaled_rows` moves (256 to 1,024 read within a
+#: tenth of each other at 2,048 and 2,560 columns; my chip runs, PR 36)
+_ROWS_A_PASS = 512
+
+
+def _pass_rows(rows):
+    return min(_ROWS_A_PASS, rows)
+
+
+def _rows_crossed(count, rows):
+    """Rows the passes of :func:`_scaled_rows` cross for ``count`` of a
+    buffer's ``rows``: whole passes."""
+    chunk = _pass_rows(rows)
+    return jnp.minimum(-(-count // chunk) * chunk, rows)
+
+
+def _scaled_rows_loop(src, order, scale, count, into, *, chunk):
+    rows = order.shape[0]
+
+    def move(i, out):
+        # the last chunk of a buffer that is no multiple of it steps back
+        # and moves some rows twice
+        at = jnp.minimum(i * chunk, rows - chunk)
+        ids = lax.dynamic_slice(order, (at,), (chunk,))
+        part = _take_rows(src, ids % src.shape[0]).astype(jnp.float32) \
+            * _take_rows(scale, ids)[:, None]
+        return lax.dynamic_update_slice(out, part.astype(out.dtype), (at, 0))
+
+    return lax.fori_loop(0, -(-count // chunk), move, into)
+
+
+# A primitive of its own and not a ``jax.jit`` inside the step: a
+# primitive's lowering is emitted ONCE a module for each signature, as a
+# private function that every layer's backward calls (``inline=False``), and
+# no transformation looks inside it. A jitted callee under ``jax.checkpoint``
+# is copied for every call site: remat's partial evaluation writes each call
+# a new jaxpr, and a module's functions are cached by jaxpr.
+_scaled_rows_p = jex_core.Primitive("scaled_rows")
+_scaled_rows_p.def_impl(jax.jit(_scaled_rows_loop, static_argnames="chunk"))
+_scaled_rows_p.def_abstract_eval(
+    lambda src, order, scale, count, into, *, chunk: into)
+mlir.register_lowering(
+    _scaled_rows_p, mlir.lower_fun(_scaled_rows_loop, multiple_results=False),
+    inline=False)
+
+
+def _scaled_rows(src, order, scale, count, into):
+    """``into`` (A, d) with ``into[a] = src[order[a] % N] * scale[order[a]]``
+    (``src`` (N, d), ``scale`` (A,) float32, the product in float32) for the
+    first ``count`` of its A rows, ``_ROWS_A_PASS`` rows a pass and
+    ``ceil(count / that)`` passes; the rows after them keep what they held.
+    (A buffer of the mover's own, ``lax.empty``, has no operand, so the
+    compiler allocates every layer's at the start of the backward scan's
+    body: 0.8 GB more at the smallthinker cell's sizes.) On the v5e a pass
+    gathers at 27 to 52 ns a row, where one gather of all A rows takes 41 ns
+    a row from a source too large to wait in VMEM (8 where it fits and is
+    given the room, which a step does not always do; my chip runs, PR 36)."""
+    return _scaled_rows_p.bind(src, order, scale, count, into,
+                               chunk=_pass_rows(order.shape[0]))
+
+
 @jax.custom_vjp
-def _rows_back(rows, order, inv, local):
-    """(A, d) rows in expert order → (K, N, d) in assignment order, zero
-    where the assignment is not local (the absent experts' rows are
-    unset). The gradient is the gather by ``order``."""
+def _rows_back(rows, weight, order, inv, local, n_local):
+    """(A, d) rows in expert order → (N, d): every token's local rows, each
+    times its ``weight`` (K, N) float32, summed in float32 over the token's
+    K assignments (the absent experts' rows are unset and count as zero).
+
+    The gradient for ``rows`` is formed IN EXPERT ORDER, and only for the
+    ``n_local`` rows the grouped products read (:func:`_scaled_rows`): row a
+    is the gradient of token ``order[a] % N``'s sum times the weight of
+    assignment ``order[a]``. Transposing the forward instead broadcasts the
+    (N, d) gradient to a float32 (K, N, d), scales and rounds it in
+    assignment order and then gathers all K·N rows of that by ``order``: the
+    same numbers in the rows that are read, for two passes over K·N rows in
+    float32 and a gather of K·N rows where a quarter of them is used."""
+    return _rows_back_fwd(rows, weight, order, inv, local, n_local)[0]
+
+
+def _parts(gathered, local):
+    """(A, d) rows in assignment order → (K, N, d), zero where the
+    assignment is not local."""
     return jnp.where(local[:, :, None],
-                     _take_rows(rows, inv).reshape(*local.shape, -1), 0)
+                     gathered.reshape(*local.shape, -1), 0)
 
 
-def _rows_back_fwd(rows, order, inv, local):
-    return _rows_back(rows, order, inv, local), order
+def _rows_back_fwd(rows, weight, order, inv, local, n_local):
+    gathered = _take_rows(rows, inv)
+    y = jnp.sum(_parts(gathered, local).astype(jnp.float32)
+                * weight[:, :, None], axis=0).astype(rows.dtype)
+    return y, (gathered, weight, order, local, n_local)
 
 
-def _rows_back_bwd(order, g):
-    return (_take_rows(g.reshape(order.shape[0], -1), order),
-            None, None, None)
+def _rows_back_bwd(res, g):
+    gathered, weight, order, local, n_local = res
+    d_weight = jnp.sum(g.astype(jnp.float32)
+                       * _parts(gathered, local).astype(jnp.float32), axis=-1)
+    # into the forward's gathered rows, which nothing reads after d_weight
+    back = _scaled_rows(g, order, weight.reshape(-1), n_local, gathered)
+    return back, d_weight, None, None, None, None
 
 
 _rows_back.defvjp(_rows_back_fwd, _rows_back_bwd)
@@ -980,13 +1079,16 @@ def _moe_share(cfg, x, chosen, weight, we_in, we_out):
     K·N assignments are sorted by expert, the absent experts' last; one
     gather fills a static (K·N, d) buffer (enough for EVERY assignment to be
     local, so no routing can drop one); grouped products over the held
-    experts' group sizes compute exactly the rows routed here and leave the
-    rest unset;
-    the rows go back to their tokens, where the local ones are weighted
-    and summed. Returns (y, stats): stats = float32 [assignments,
+    experts' group sizes compute exactly the rows routed here, the first
+    ``n_local``, and leave the rest unset; the rows go back to their tokens,
+    where the local ones are weighted and summed (:func:`_rows_back`, whose
+    gradient fills the ``n_local`` rows in use and no others).
+    Returns (y, stats, moved): stats = float32 [assignments,
     assignments to held experts, assignments dropped (rows the buffer
     could not take: 0), largest held expert's load over their mean] and,
-    where the router has a skip, a fifth: tokens that took it."""
+    where the router has a skip, a fifth: tokens that took it; moved =
+    float32 rows that gradient's passes crossed, ``n_local`` and the last
+    pass's round-up."""
     b, t, d = x.shape
     n, k = b * t, chosen.shape[0]
     first, held = cfg.experts_held
@@ -1006,9 +1108,7 @@ def _moe_share(cfg, x, chosen, weight, we_in, we_out):
                                               sizes))
         out = lax.ragged_dot(hidden, we_out.astype(x.dtype), sizes)
     with jax.named_scope("moe_combine"):
-        parts = _rows_back(out, order, inv, local)              # (K, N, d)
-        y = jnp.sum(parts.astype(jnp.float32) * weight[:, :, None], axis=0
-                    ).astype(x.dtype)
+        y = _rows_back(out, weight, order, inv, local, n_local)  # (N, d)
     f32 = jnp.float32
     stats = [
         jnp.asarray(n * k, f32), n_local.astype(f32),
@@ -1016,7 +1116,8 @@ def _moe_share(cfg, x, chosen, weight, we_in, we_out):
         jnp.max(sizes).astype(f32) * held / jnp.maximum(n_local, 1).astype(f32)]
     if cfg.router_skip:
         stats.append(jnp.sum(chosen == cfg.n_experts, dtype=f32))
-    return y.reshape(b, t, d), jnp.stack(stats)
+    return (y.reshape(b, t, d), jnp.stack(stats),
+            _rows_crossed(n_local, n * k).astype(f32))
 
 
 def embed(params, cfg: TransformerConfig, ids, pos_offset=0):
@@ -1124,8 +1225,9 @@ def _residual(cfg, x, y, blk, i):
 
 def _run_blocks(blocks, cfg: TransformerConfig, x, return_kv=False):
     """(x, auxes (L,), kvs, what the held experts' layers tell or None:
-    ``{"load": (L, 4 or 5) float32}`` of :func:`_moe_share` and, under the
-    mlp router, ``"choices"``: (L, 1, N) int32). The scan runs
+    ``{"load": (L, 4 or 5) float32, "moved": (L,) float32}`` of
+    :func:`_moe_share` and, under the mlp router, ``"choices"``: (L, 1, N)
+    int32). The scan runs
     over PERIODS of the layer pattern (``cfg.layer_kinds``; a period of one
     layer for a uniform stack): inside a period the layers' kinds are
     static, and each layer is rematerialized on its own. The scan carries
@@ -1166,9 +1268,9 @@ def _run_blocks(blocks, cfg: TransformerConfig, x, return_kv=False):
                                                     blk["router_beta"])
                 else:
                     chosen, weight = _route_top_k(cfg, routed)
-            m, load = _moe_share(cfg, h2, chosen, weight, blk["we_in"],
-                                 blk["we_out"])
-            told = {"load": load}
+            m, load, moved = _moe_share(cfg, h2, chosen, weight,
+                                        blk["we_in"], blk["we_out"])
+            told = {"load": load, "moved": moved}
             if cfg.router != "linear":  # an argmax: see make_train_step
                 told["choices"] = chosen
             if cfg.shared_experts:      # every token's, once on every chip
@@ -1414,7 +1516,8 @@ def make_train_step(cfg: TransformerConfig, optimizer):
     (params, opt_state, loss) and, where the configuration holds a share of
     routed experts (``experts_held``), a fourth output, computed on the
     device beside the loss: ``{"load": the per-layer expert-load stats (L, 4
-    or 5) float32 of :func:`_moe_share```} (``obs.moe.record_expert_load``
+    or 5) float32 of :func:`_moe_share`, "moved": the rows its backward's
+    row movement crossed, (L,) float32}`` (``obs.moe.record_expert_load``
     counts them) and, under the mlp and the sigmoid router, ``"choices"``:
     the experts every token took in every layer, (L, K, B·T) int32. A choice
     is an argmax, and a tie within the compute dtype's rounding falls the

@@ -264,6 +264,81 @@ def test_kernel_program_is_the_same_from_any_call_site(one_chip, tmp_path,
     assert "tpu_custom_call" in here and here == there
 
 
+#: the expert cells' routed layers: tokens N, choices K, width d
+WAY_BACK_SHAPES = {"smallthinker": (16384, 6, 2560), "glm": (8192, 4, 2048),
+                   "zaya": (32768, 1, 2048)}
+
+
+@pytest.mark.parametrize("cell", sorted(WAY_BACK_SHAPES))
+def test_expert_layers_way_back_compiles_at_the_cells_shapes(
+        one_chip, no_persistent_cache, cell):
+    """``zoo.transformer._rows_back`` in bf16 with its own backward rule:
+    the rows' gradient is filled by ``_scaled_rows``' passes from the (N, d)
+    gradient of the sum, and no float32 (K, N, d) is ever held (JAX's own
+    transposition of the forward holds two, 1 GB each in the smallthinker
+    cell, and gathers all K·N rows it rounds them to)."""
+    from deeplearning4j_tpu.zoo import transformer as tfm
+    n, k, d = WAY_BACK_SHAPES[cell]
+    a = n * k
+
+    def plain(rows, weight, order, inv, local, n_local):
+        parts = jnp.where(local[:, :, None], rows[inv].reshape(k, n, d), 0)
+        return jnp.sum(parts.astype(jnp.float32) * weight[:, :, None],
+                       axis=0).astype(rows.dtype)
+
+    def both_gradients(back):
+        def f(rows, weight, order, inv, local, n_local, g):
+            _, pull = jax.vjp(
+                lambda r, w: back(r, w, order, inv, local, n_local),
+                rows, weight)
+            return pull(g)
+        return jax.jit(f).lower(
+            _sds(one_chip, (a, d), jnp.bfloat16),
+            _sds(one_chip, (k, n), jnp.float32),
+            _sds(one_chip, (a,), jnp.int32), _sds(one_chip, (a,), jnp.int32),
+            _sds(one_chip, (k, n), jnp.bool_), _sds(one_chip, (), jnp.int32),
+            _sds(one_chip, (n, d), jnp.bfloat16)).compile()
+
+    ours, theirs = both_gradients(tfm._rows_back), both_gradients(plain)
+    text = ours.as_text()
+    # the rows' gradient: ONE loop whose trip count is no constant
+    assert len(re.findall(r" while\(", text)) == 1
+    assert "known_trip_count" not in text
+    ours_temp = ours.memory_analysis().temp_size_in_bytes
+    theirs_temp = theirs.memory_analysis().temp_size_in_bytes
+    assert ours_temp <= 2.2 * a * d * 2      # the gathered rows, twice
+    if k > 1:
+        assert ours_temp < theirs_temp
+
+
+def test_row_mover_is_lowered_once_however_many_layers_call_it():
+    """The regression that refused PR 35 (set-up that grew with the mover's
+    call sites), held without a chip: a two-layer expert stack under
+    ``remat_policy="full"``, with one more expert block behind it as the
+    prediction module has, calls ``_scaled_rows`` from two backward passes,
+    and the step's module holds its loop ONCE."""
+    from deeplearning4j_tpu.zoo import transformer as tfm
+    cfg = tfm.TransformerConfig(
+        vocab_size=64, d_model=32, n_heads=2, n_layers=2, d_ff=16,
+        max_seq=16, n_experts=8, expert_top_k=2, experts_held=(0, 4),
+        mlp="reglu", dtype=jnp.float32, remat=True, remat_policy="full",
+        fused_loss=True)
+    params = jax.eval_shape(lambda k: tfm.init_params(k, cfg),
+                            jax.random.PRNGKey(0))
+    ids = jax.ShapeDtypeStruct((2, 16), jnp.int32)
+
+    def loss(p, i):
+        x = tfm.embed(p, cfg, i)
+        x, _, _, _ = tfm._run_blocks(p["blocks"], cfg, x)
+        x, _, _, _ = tfm._run_blocks(p["blocks"], cfg, x)   # a second stack
+        return jnp.sum(x.astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss)).lower(params, ids).as_text()
+    bodies = re.findall(r"func\.func private @(scaled_rows[^(]*)\(", text)
+    calls = re.findall(r"call @(scaled_rows[^(]*)\(", text)
+    assert len(bodies) == 1 and len(calls) == 2 and set(calls) == set(bodies)
+
+
 @pytest.mark.parametrize("batch", [1, 128], ids=["b1", "b128"])
 @pytest.mark.parametrize("side,channels", RESNET_BN_ACT,
                          ids=[f"{s}x{s}x{c}" for s, c in RESNET_BN_ACT])

@@ -182,7 +182,7 @@ def test_train_step_hands_back_load_choices_and_the_two_losses():
     opt = optax.adamw(1e-3)
     out = jax.jit(tfm.make_train_step(cfg, opt))(params, opt.init(params),
                                                  ids, tgt)
-    assert len(out) == 4 and sorted(out[3]) == ["choices", "load", "losses"]
+    assert len(out) == 4 and sorted(out[3]) == ["choices", "load", "losses", "moved"]
     load, choices, losses = (out[3][k] for k in ("load", "choices", "losses"))
     # two expert layers and the prediction module's block, not the dense one
     assert load.shape == (3, 4) and choices.shape == (3, 2, ids.size)
@@ -235,7 +235,7 @@ def _parts(cfg, blk, x):
     u = tfm._rmsnorm(x1, blk["ln2"], cfg.norm_eps)
     chosen, weight = tfm._route_sigmoid(
         cfg, tfm._router_logits(u, blk["router"]), blk["router_beta"])
-    y, stats = tfm._moe_share(cfg, u, chosen, weight, blk["we_in"],
+    y, stats, _ = tfm._moe_share(cfg, u, chosen, weight, blk["we_in"],
                               blk["we_out"])
     return a, x1, u, y, tfm._dense_mlp(cfg, u, blk["ws_in"], blk["ws_out"]), \
         stats

@@ -86,7 +86,7 @@ def test_loss_and_every_gradient_leaf_match_the_reference(case):
     for name, w in g_want.items():
         gap = float(jnp.max(jnp.abs(g_got[name] - w)) / jnp.max(jnp.abs(w)))
         assert gap <= 1e-5, (name, gap)
-    assert sorted(stats) == ["load"]    # no choices from the linear router
+    assert sorted(stats) == ["load", "moved"]   # no choices from the linear router
     stats = np.asarray(stats["load"])
     assert stats.shape == (sz["layers"], 4)
     assert (stats[:, 0] == ids.size * sz["top_k"]).all()
@@ -104,7 +104,7 @@ def test_train_step_hands_back_the_expert_load_beside_the_loss():
     opt = optax.adamw(3e-4)
     out = jax.jit(tfm.make_train_step(cfg, opt))(params, opt.init(params),
                                                  ids, tgt)
-    assert len(out) == 4 and sorted(out[3]) == ["load"]
+    assert len(out) == 4 and sorted(out[3]) == ["load", "moved"]
     assert out[3]["load"].shape == (4, 4)
     assert out[3]["load"].dtype == jnp.float32
     dense = tfm.TransformerConfig(vocab_size=50, d_model=32, n_heads=4,
@@ -156,7 +156,7 @@ def test_the_four_shares_parts_add_up_to_the_uncut_layer(layer):
         a = tfm._attention(cfg, q, k, v, positions=kind[0],
                            window=kind[1]) @ blk["wo"]
         u = tfm._rmsnorm(x, blk["ln2"])    # the same u for every share
-        y, stats = tfm._moe_share(
+        y, stats, _ = tfm._moe_share(
             cfg, u, *tfm._route_top_k(cfg, tfm._router_logits(h, blk["router"])),
             blk["we_in"], blk["we_out"])
         return a, y, stats
@@ -203,7 +203,7 @@ def test_every_token_on_one_expert_loses_nothing(target, held):
     u = jax.random.normal(jax.random.PRNGKey(1), (1, n, 32), jnp.float32)
     logits = jax.random.normal(jax.random.PRNGKey(4), (n, 8), jnp.float32)
     logits = logits.at[:, target].set(50.0)     # all but the whole weight
-    y, stats = tfm._moe_share(cfg, u, *tfm._route_top_k(cfg, logits),
+    y, stats, _ = tfm._moe_share(cfg, u, *tfm._route_top_k(cfg, logits),
                               blk["we_in"], blk["we_out"])
     want = ref.experts_part(u[0], logits, blk, sz)
     np.testing.assert_allclose(y[0], want, rtol=1e-5, atol=1e-6)

@@ -147,7 +147,7 @@ def test_train_step_hands_back_rows_of_five_beside_the_loss():
     out = jax.jit(tfm.make_train_step(cfg, opt))(params, opt.init(params),
                                                  ids, tgt)
     # four outputs, as from every configuration that holds experts
-    assert len(out) == 4 and sorted(out[3]) == ["choices", "load"]
+    assert len(out) == 4 and sorted(out[3]) == ["choices", "load", "moved"]
     load, choices = out[3]["load"], out[3]["choices"]
     assert load.shape == (3, 5)
     # the expert every token took in every layer
@@ -203,7 +203,7 @@ def test_the_two_shares_parts_add_up_to_the_uncut_layer():
         x1 = tfm._residual(cfg, x, a, blk, 0)
         u = tfm._rmsnorm(x1, blk["ln2"], cfg.norm_eps)
         _, chosen, weight = tfm._route_mlp(cfg, u, r_prev, blk)
-        y, stats = tfm._moe_share(cfg, u, chosen, weight, blk["we_in"],
+        y, stats, _ = tfm._moe_share(cfg, u, chosen, weight, blk["we_in"],
                                   blk["we_out"])
         return a, x1, u, y, stats
 
@@ -355,7 +355,7 @@ def test_a_token_on_the_skip_gets_nothing_and_is_counted():
     zero = jnp.zeros((1, n, 12), jnp.float32)
     _, chosen, weight = tfm._route_mlp(cfg, u, zero, blk_skip)
     assert (np.asarray(chosen) == 6).all()
-    y, stats = tfm._moe_share(cfg, u, chosen, weight, blk["we_in"],
+    y, stats, _ = tfm._moe_share(cfg, u, chosen, weight, blk["we_in"],
                               blk["we_out"])
     assert float(jnp.max(jnp.abs(y))) == 0.0
     assert list(np.asarray(stats)) == [n, 0, 0, 0, n]
@@ -364,7 +364,7 @@ def test_a_token_on_the_skip_gets_nothing_and_is_counted():
     chosen = jnp.where(chosen == 6, 0, chosen).at[0, ::2].set(6)
     _, p = ref.router(u[0], zero[0], blk, sz)
     weight = jnp.take_along_axis(p, chosen[0][:, None], axis=-1).T
-    y, stats = tfm._moe_share(cfg, u, chosen, weight, blk["we_in"],
+    y, stats, _ = tfm._moe_share(cfg, u, chosen, weight, blk["we_in"],
                               blk["we_out"])
     norms = np.asarray(jnp.linalg.norm(y[0], axis=-1))
     assert (norms[::2] == 0).all() and (norms[1::2] > 0).all()
