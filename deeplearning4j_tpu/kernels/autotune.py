@@ -229,10 +229,12 @@ def _time_once(run: Callable[[], object], reps: int = 8) -> float:
     return max((t_n - t_1) / (reps - 1), 1e-9)
 
 
-def _race(candidates, make_run, m_measure, m_time):
+def _race(candidates, make_run, m_measure, m_time, m_race):
     """Time every candidate; returns (best, best seconds, measurements)
     with one ``[candidate, seconds | None | "error text"]`` per candidate
-    (None: invalid for the shape)."""
+    (None: invalid for the shape). The race's own wall time, the candidates'
+    compiles included, is added to ``m_race``."""
+    t_race = time.perf_counter()
     best, best_t = None, float("inf")
     measurements = []   # per-candidate provenance for the disk record
     for cand in candidates:
@@ -253,6 +255,7 @@ def _race(candidates, make_run, m_measure, m_time):
         measurements.append([list(cand), t])
         if t < best_t:
             best, best_t = cand, t
+    m_race.inc(time.perf_counter() - t_race)
     return best, best_t, measurements
 
 
@@ -310,10 +313,12 @@ def autotune(key: str, candidates: Iterable[Tuple],
                             "Candidate configs timed on the device")
     m_time = reg.histogram("dl4j_autotune_candidate_seconds",
                            "Marginal per-call seconds of timed candidates")
+    from ..obs.compiles import phase_counters
+    m_race = phase_counters()[2]
     from concurrent.futures import ThreadPoolExecutor
     with ThreadPoolExecutor(max_workers=1) as pool:
         best, best_t, measurements = pool.submit(
-            _race, candidates, make_run, m_measure, m_time).result()
+            _race, candidates, make_run, m_measure, m_time, m_race).result()
     if best is None:
         raise RuntimeError(
             f"autotune {key!r}: no candidate could be timed: "

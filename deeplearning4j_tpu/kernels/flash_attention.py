@@ -22,6 +22,13 @@ i - j < window: key blocks that lie wholly outside the band, on either side,
 are skipped via pl.when and their fetches clamped away, in all three kernels.
 Falls back to interpreter mode off-TPU so the same code path is
 unit-testable on the CPU mesh.
+
+Names on the device: each kernel sits in a ``jax.named_scope`` of its own
+name (``flash_fwd``, ``flash_bwd_dq``, ``flash_bwd_dkv``, with ``_win`` under
+a window); what XLA runs around them (the transposes between the callers'
+(B, T, H, D) and the kernels' (B, H, T, D), the backward's row sums of dO·O,
+the 8-lane copies of lse and delta) sits in ``attn_core``, so that a profile
+tells the kernels' time from the layout's.
 """
 
 from __future__ import annotations
@@ -39,6 +46,8 @@ from ._common import interpret_default as _interpret_default
 from ._common import pltpu
 
 NEG_INF = -1e30
+#: the scope of what XLA runs around the kernels (see the module docstring)
+GLUE_SCOPE = "attn_core"
 
 
 def _block_sizes(t: int, d: int, block_q: int, block_k: int):
@@ -160,9 +169,10 @@ def _fwd(q, k, v, scale, causal, block_q, block_k, interpret, window=None):
     b, h, t, d = q.shape
     hkv = k.shape[1]
     bq, bk = _block_sizes(t, d, block_q, block_k)
-    qf = q.reshape(b * h, t, d)
-    kf = k.reshape(b * hkv, t, d)
-    vf = v.reshape(b * hkv, t, d)
+    with jax.named_scope(GLUE_SCOPE):
+        qf = q.reshape(b * h, t, d)
+        kf = k.reshape(b * hkv, t, d)
+        vf = v.reshape(b * hkv, t, d)
     kv_map = _causal_kv_map(bq, bk, causal, window, h // hkv)
     grid = (b * h, t // bq, t // bk)      # kv block = fastest dim (streamed)
     name = "flash_fwd" + _suffix(window)
@@ -184,7 +194,8 @@ def _fwd(q, k, v, scale, causal, block_q, block_k, interpret, window=None):
             interpret=interpret,
             name=name,
         )(qf, kf, vf)
-    return out.reshape(b, h, t, d), lse[:, :, 0].reshape(b, h, t)
+    with jax.named_scope(GLUE_SCOPE):
+        return out.reshape(b, h, t, d), lse[:, :, 0].reshape(b, h, t)
 
 
 # --------------------------------------------------------------- backward --
@@ -322,15 +333,18 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret,
     # reads (the transposes around it cancel). A copy saved in the kernel's
     # own (B, H, T, D) layout is padded from D = 64 to 128 lanes, twice the
     # bytes (v5e, compiled at b16 T1024 H16: +0.83 GB over 24 layers).
-    out_t = checkpoint_name(out.transpose(0, 2, 1, 3), "attn_out")
-    lse = checkpoint_name(lse, "attn_lse")
-    return out_t.transpose(0, 2, 1, 3), (q, k, v, out_t, lse)
+    with jax.named_scope(GLUE_SCOPE):
+        out_t = checkpoint_name(out.transpose(0, 2, 1, 3), "attn_out")
+        lse = checkpoint_name(lse, "attn_lse")
+        return out_t.transpose(0, 2, 1, 3), (q, k, v, out_t, lse)
 
 
 def _rowsum_do_o(g, out_t):
     """rowsum(dO * O), (B, H, T) f32, from O as `_flash_fwd` saved it."""
-    return jnp.sum(g.astype(jnp.float32)
-                   * out_t.transpose(0, 2, 1, 3).astype(jnp.float32), axis=-1)
+    with jax.named_scope(GLUE_SCOPE):
+        return jnp.sum(
+            g.astype(jnp.float32)
+            * out_t.transpose(0, 2, 1, 3).astype(jnp.float32), axis=-1)
 
 
 def _flash_bwd(scale, causal, block_q, block_k, interpret, window, res, g):
@@ -355,9 +369,12 @@ def _flash_bwd_impl(scale, causal, block_q, block_k, interpret,
     bq, bk = _block_sizes(t, d, block_q, block_k)
     nq = t // bq
     flat = lambda x: x.reshape(-1, t, x.shape[-1])
-    qf, kf, vf, dof = flat(q), flat(k), flat(v), flat(g)
-    lsef = jnp.broadcast_to(lse.reshape(b * h, t)[:, :, None], (b * h, t, 8))
-    deltaf = jnp.broadcast_to(delta.reshape(b * h, t)[:, :, None], (b * h, t, 8))
+    with jax.named_scope(GLUE_SCOPE):
+        qf, kf, vf, dof = flat(q), flat(k), flat(v), flat(g)
+        lsef = jnp.broadcast_to(lse.reshape(b * h, t)[:, :, None],
+                                (b * h, t, 8))
+        deltaf = jnp.broadcast_to(delta.reshape(b * h, t)[:, :, None],
+                                  (b * h, t, 8))
 
     kv_map = _causal_kv_map(bq, bk, causal, window, group)
     # dkv grid streams q blocks (of each query head of the group in turn);
@@ -424,7 +441,8 @@ def _flash_bwd_impl(scale, causal, block_q, block_k, interpret,
             name="flash_bwd_dkv" + sfx,
         )(qf, kf, vf, dof, lsef, deltaf)
 
-    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
+    with jax.named_scope(GLUE_SCOPE):
+        return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
 
 
 _flash_attention_pallas.defvjp(_flash_fwd, _flash_bwd)
@@ -539,10 +557,11 @@ def flash_attention_ntc(q, k, v, causal=False, interpret=None, window=None):
     b, t, h, d = q.shape
     bq, bk = _tuned_blocks(b, h, t, d, q.dtype, causal, interpret, window,
                            h // k.shape[2])
-    out = flash_attention(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-                          v.transpose(0, 2, 1, 3), None, causal, bq, bk,
-                          interpret, window)
-    return out.transpose(0, 2, 1, 3)
+    with jax.named_scope(GLUE_SCOPE):
+        q, k, v = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
+    out = flash_attention(q, k, v, None, causal, bq, bk, interpret, window)
+    with jax.named_scope(GLUE_SCOPE):
+        return out.transpose(0, 2, 1, 3)
 
 
 def mha_reference(q, k, v, scale=None, causal=False, window=None):

@@ -41,9 +41,13 @@ def build_train_step(net, name, **jit_kwargs):
         (loss, new_states), grads = jax.value_and_grad(
             net._loss, has_aux=True)(params, states, x, y, use_rng,
                                      fmask, lmask)
-        updates, new_opt_state = optimizer.update(grads, opt_state, params)
-        new_params = net._apply_constraints(
-            optax.apply_updates(params, updates))
+        # the layers' ops carry their node's scope (<node>.<Type>); this is
+        # the one name for what is no layer's
+        with jax.named_scope("optimizer"):
+            updates, new_opt_state = optimizer.update(grads, opt_state,
+                                                      params)
+            new_params = net._apply_constraints(
+                optax.apply_updates(params, updates))
         stats = None
         if with_stats:
             # A non-finite batch becomes a whole-step no-op (params, opt

@@ -29,11 +29,15 @@ callables that don't expose a cache. The wrapper is transparent:
 function, so floor probes (``.lower()``) and ``fit_scanned``
 (``step_fn.__wrapped__``) see the jit object they always saw.
 
-Timing caveat, documented rather than hidden: a "compile" observation
-spans the whole first call at that signature — trace + compile + first
-execution — because jax gives no host-side hook between them. For the
-retrace-storm failure mode that is the right number anyway (it is the
-latency the caller actually lost).
+Timing caveat, documented rather than hidden: a sentinel's "compile"
+observation spans the whole first call at that signature — trace + compile
++ first execution. For the retrace-storm failure mode that is the right
+number (it is the latency the caller actually lost). The parts are counted
+apart, for the whole process and whether or not a sentinel wraps the
+function, by :func:`listen_to_compile_phases`:
+``dl4j_compile_phase_seconds_total{phase="trace"|"lower"|"backend"}`` from
+JAX's own duration events, ``dl4j_compile_cache_misses_total``, and beside
+them the kernel races' ``dl4j_autotune_race_seconds_total``.
 
 Hot-path budget: a non-compiling call costs one ``_cache_size()`` read
 and two clock reads; the sentinel self-times into
@@ -42,9 +46,76 @@ and two clock reads; the sentinel self-times into
 
 from __future__ import annotations
 
+import threading
 import time
 import warnings
 from typing import Any, Callable, Dict, Optional, Tuple
+
+#: JAX's duration events -> the ``phase`` they are counted under: tracing a
+#: function to a jaxpr, lowering the jaxpr to a module, and the backend's
+#: compile OR the persistent cache's answer in its place
+COMPILE_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
+_listening = threading.Lock()
+_listens = False
+
+
+def phase_counters():
+    """``(phase seconds, cache misses, race seconds)``: the three counters a
+    set-up is split by. Fetched per event, so that a registry reset (tests)
+    cannot orphan them; the third is ``kernels/autotune.py``'s to add to and
+    is registered here as well, so that a process that never races reads 0
+    and not nothing."""
+    from . import get_registry
+    reg = get_registry()
+    return (reg.counter(
+                "dl4j_compile_phase_seconds_total",
+                "Seconds this process spent tracing, lowering and compiling "
+                "(or reading the persistent cache), by JAX's own events",
+                labelnames=("phase",)),
+            reg.counter(
+                "dl4j_compile_cache_misses_total",
+                "Programs compiled and written to the persistent cache: its "
+                "misses as JAX counts them (a compile under the cache's "
+                "least time is neither a hit nor a miss)"),
+            reg.counter(
+                "dl4j_autotune_race_seconds_total",
+                "Wall seconds spent racing kernel candidates, their "
+                "compiles included"))
+
+
+def _on_duration(event, secs, **_):
+    phase = COMPILE_PHASES.get(event)
+    if phase is not None:
+        phase_counters()[0].inc(max(secs, 0.0), phase=phase)
+
+
+def _on_event(event, **_):
+    if event == CACHE_MISS_EVENT:
+        phase_counters()[1].inc()
+
+
+def listen_to_compile_phases() -> None:
+    """Count every compile of this process by phase, from here on:
+    ``dl4j_compile_phase_seconds_total{phase=}`` and
+    ``dl4j_compile_cache_misses_total`` in ``obs.get_registry()``. Registered
+    with ``jax.monitoring`` once a process, however often it is called
+    (``utils.compile_cache.enable_compile_cache`` calls it); the listeners
+    run on compile events only, a dictionary lookup and an add each, and
+    nothing on a step's path."""
+    global _listens
+    with _listening:
+        if _listens:
+            return
+        import jax
+        phase_counters()    # registered from the start, at 0
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
+        jax.monitoring.register_event_listener(_on_event)
+        _listens = True
 
 
 def abstract_signature(args: tuple, kwargs: dict) -> Tuple:

@@ -53,13 +53,15 @@ def _xla_attn_lse(q, k, v, causal):
 
 def _flash_attn_lse(q, k, v, causal, interpret):
     """Flash-kernel local attention in ring layout (B,T,H,D)."""
-    from ..kernels.flash_attention import _tuned_blocks, flash_attention_lse
+    from ..kernels.flash_attention import (GLUE_SCOPE, _tuned_blocks,
+                                           flash_attention_lse)
     b, t, h, d = q.shape
     bq, bk = _tuned_blocks(b, h, t, d, q.dtype, causal, interpret)
-    out, lse = flash_attention_lse(
-        q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-        v.transpose(0, 2, 1, 3), None, causal, bq, bk, interpret)
-    return out.transpose(0, 2, 1, 3).astype(jnp.float32), lse
+    with jax.named_scope(GLUE_SCOPE):
+        q, k, v = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
+    out, lse = flash_attention_lse(q, k, v, None, causal, bq, bk, interpret)
+    with jax.named_scope(GLUE_SCOPE):
+        return out.transpose(0, 2, 1, 3).astype(jnp.float32), lse
 
 
 def _merge(acc, new):
@@ -89,9 +91,10 @@ def ring_attention_sharded(q, k, v, axis_name: str = "sp",
     flash = _use_flash(use_flash, t_local)
 
     def attn(q_, k_, v_, causal_):
-        if flash:
+        if flash:   # the kernels and the layout around them name themselves
             return _flash_attn_lse(q_, k_, v_, causal_, interpret)
-        return _xla_attn_lse(q_, k_, v_, causal_)
+        with jax.named_scope("attn_core"):
+            return _xla_attn_lse(q_, k_, v_, causal_)
 
     # hop 0: own k/v — the diagonal block is ALIGNED, plain causal applies
     acc = attn(q, k, v, causal)
@@ -110,7 +113,8 @@ def ring_attention_sharded(q, k, v, axis_name: str = "sp",
                            lambda ops: zero, kv)
         else:
             new = attn(q, kv[0], kv[1], False)
-        acc = _merge(acc, new)
+        with jax.named_scope("attn_core"):
+            acc = _merge(acc, new)
     out, _ = acc
     return out.astype(q.dtype)
 
@@ -126,7 +130,8 @@ def ring_attention_inner(q, k, v, causal: bool = True, axis_name: str = "sp",
     if in_ring:
         return ring_attention_sharded(q, k, v, axis_name, causal, use_flash,
                                       interpret)
-    return jax.nn.dot_product_attention(q, k, v, is_causal=causal)
+    with jax.named_scope("attn_core"):
+        return jax.nn.dot_product_attention(q, k, v, is_causal=causal)
 
 
 def ring_attention(mesh: Mesh, q, k, v, causal: bool = True,

@@ -14,6 +14,20 @@ site, and with what the process lowered before (a run that raced the flash
 blocks and a run that read the record produced different programs, and the
 first chip runs recompiled the LM train step every time). The helper turns
 those call stacks off, so the same kernel is the same program everywhere.
+
+**The key holds the metadata** (``jax_compilation_cache_include_metadata_in_key``
+on; JAX's default strips it). With the call stacks off a location is the
+operation's name stack and nothing else (``jit(step)/jvp(embed)/gather``): no
+path, no line. So the same tree still hits from any checkout, and two trees
+that differ in their ``jax.named_scope``s alone, whose programs are the same
+op for op, no longer share an entry. They must not: a profile reads the
+scopes out of the executable (each device event's ``tf_op``), and with the
+default key a traced run read the names of whichever tree had compiled that
+program first (PERF.md section 6, PR 33; measured again at PR 37, section 3).
+
+The helper also starts the process's compile account
+(``obs.compiles.listen_to_compile_phases``: seconds by phase and the cache's
+misses, in ``obs.get_registry()``), once, however often it is called.
 """
 
 from __future__ import annotations
@@ -30,7 +44,10 @@ def enable_compile_cache() -> str:
     """Turn the persistent cache on for this process; returns its directory."""
     import jax
 
+    from ..obs.compiles import listen_to_compile_phases
+    listen_to_compile_phases()
     jax.config.update("jax_traceback_in_locations_limit", 0)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env_dir:
         return env_dir

@@ -37,6 +37,35 @@ from jax.extend import core as jex_core
 from jax.interpreters import mlir
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+#: The training step's TOP-LEVEL ``jax.named_scope``s, name -> what it holds.
+#: Each is opened only where no other of them is open (at the call sites in
+#: ``block``, ``_lm_loss_stats``, ``embed`` and ``step``), so a device event
+#: belongs to at most one and their device times add up; ``mtp`` alone is a
+#: second cut, around a whole module. A name is read as the START of a path
+#: component of an op's name stack, so none is the start of another, of a
+#: component JAX makes (``jit(...)``, ``jvp``, ``while``, ``checkpoint``, a
+#: primitive's name) or of an entry of ``KERNEL_SCOPES``. The benchmark's
+#: ``*_time_share_pct.lm`` metrics read them (``tests/test_step_scopes.py``
+#: holds this table and those files together).
+STEP_SCOPES = (
+    ("embed", "the table's gather, its scaling and the learned positions; "
+              "backward: the scatter-add into the table's gradient"),
+    ("attn_qkv", "the plain wqkv product and its split (of latent attention, "
+                 "the rotary parts' split alone); in _attention the head "
+                 "reshapes and, for plain heads, the rotary positions"),
+    ("attn_wo", "attention's output projection, every attention kind"),
+    ("attn_core", "attention between q, k, v and its output where no Pallas "
+                  "kernel runs (XLA's or the ring's), and the layout changes "
+                  "around the kernel where one does"),
+    ("mlp", "the dense MLP of a layer without experts"),
+    ("resid_norm", "the norms ln1, ln2, ln_f, a prediction module's three, "
+                   "and the residual merges"),
+    ("optimizer", "optimizer.update and apply_updates"),
+)
+#: The older scopes, each a prefix: a part of the model or a kernel that
+#: names itself (``flash_fwd``, ``moe_dispatch``, ``cca_mix``, ``mla_kv``).
+KERNEL_SCOPES = ("flash_", "moe_", "cca_", "mla_", "lm_head", "mtp")
+
 
 @dataclass(frozen=True)
 class TransformerConfig:
@@ -682,7 +711,8 @@ def _mla_qkv(cfg, h, blk, positions):
         kv = jnp.einsum("btr,rz->btz", c, blk["wkv_b"].astype(h.dtype))
         kv = _constrain(kv, "dp", "sp", "tp").reshape(
             b, t, H, dn + cfg.v_head_size)
-    q_rope, k_rope = q[..., dn:], ckv[:, :, None, cfg.kv_rank:]
+    with jax.named_scope("attn_qkv"):   # a split, as the plain heads' is
+        q_rope, k_rope = q[..., dn:], ckv[:, :, None, cfg.kv_rank:]
     if positions == "rope":
         with jax.named_scope("mla_rope"):
             q_rope = _rope(q_rope, cfg.rope_theta)
@@ -700,13 +730,16 @@ def _attention(cfg, q, k, v, mask_bias=None, positions="learned", window=0):
     """``positions`` and ``window`` are the layer's kind (static): rotary
     q and k or none; the keys a query sees (0 = every earlier one)."""
     b, t = q.shape[0], q.shape[1]
-    q = q.reshape(b, t, cfg.n_heads, cfg.head_dim)
-    k = k.reshape(b, t, cfg.kv_heads, cfg.head_dim)
-    v = v.reshape(b, t, cfg.kv_heads, cfg.head_dim)
-    if positions == "rope" and cfg.attention == "mha":  # the others: theirs
-        q = _rope(q, cfg.rope_theta, rotary=cfg.rotary_dims)
-        k = _rope(k, cfg.rope_theta, rotary=cfg.rotary_dims)
+    with jax.named_scope("attn_qkv"):
+        q = q.reshape(b, t, cfg.n_heads, cfg.head_dim)
+        k = k.reshape(b, t, cfg.kv_heads, cfg.head_dim)
+        v = v.reshape(b, t, cfg.kv_heads, cfg.head_dim)
+        if positions == "rope" and cfg.attention == "mha":  # others: theirs
+            q = _rope(q, cfg.rope_theta, rotary=cfg.rotary_dims)
+            k = _rope(k, cfg.rope_theta, rotary=cfg.rotary_dims)
     path = attention_path(cfg, t, q.dtype)
+    # the flash kernels, alone or in the ring's hops, open scopes of their
+    # own (flash_*) and attn_core around them: attn_core is not opened here
     if path == "ring":
         if window or cfg.kv_heads != cfg.n_heads:
             raise NotImplementedError(
@@ -717,15 +750,18 @@ def _attention(cfg, q, k, v, mask_bias=None, positions="learned", window=0):
         from ..kernels.flash_attention import flash_attention_ntc
         out = flash_attention_ntc(q, k, v, causal=True,
                                   window=window or None)
-    elif path == "xla_bf16_scores":
-        out = _xla_attention_bf16_scores(q, k, v, window=window)
     else:
-        out = jax.nn.dot_product_attention(
-            q, k, v, is_causal=True,
-            local_window_size=(window - 1, 0) if window else None)
+        with jax.named_scope("attn_core"):
+            if path == "xla_bf16_scores":
+                out = _xla_attention_bf16_scores(q, k, v, window=window)
+            else:
+                out = jax.nn.dot_product_attention(
+                    q, k, v, is_causal=True,
+                    local_window_size=(window - 1, 0) if window else None)
     if path != "flash":     # the flash kernel names its own output and lse
         out = checkpoint_name(out, "attn_out")  # remat_policy="save_attn"
-    return out.reshape(b, t, cfg.n_heads * cfg.head_dim)
+    with jax.named_scope("attn_core"):
+        return out.reshape(b, t, cfg.n_heads * cfg.head_dim)
 
 
 def _xla_attention_bf16_scores(q, k, v, causal=True, bias=None, window=0):
@@ -1128,14 +1164,15 @@ def embed(params, cfg: TransformerConfig, ids, pos_offset=0):
     ring step): shard i holds global positions [i·T_local, (i+1)·T_local)
     but sees a local (B, T_local) slice."""
     t = ids.shape[1]
-    x = jnp.take(params["embed"], ids, axis=0).astype(cfg.dtype)
-    if cfg.embed_scale:
-        x = x * math.sqrt(cfg.d_model)
-    if not cfg.layer_positions:     # else the layers place their own
-        pos = lax.dynamic_slice_in_dim(params["pos_embed"],
-                                       pos_offset, t, axis=0)
-        x = x + pos.astype(cfg.dtype)
-    return _constrain(x, "dp", "sp", None)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], ids, axis=0).astype(cfg.dtype)
+        if cfg.embed_scale:
+            x = x * math.sqrt(cfg.d_model)
+        if not cfg.layer_positions:     # else the layers place their own
+            pos = lax.dynamic_slice_in_dim(params["pos_embed"],
+                                           pos_offset, t, axis=0)
+            x = x + pos.astype(cfg.dtype)
+        return _constrain(x, "dp", "sp", None)
 
 
 def _resolve_head(params, cfg: TransformerConfig):
@@ -1239,23 +1276,28 @@ def _run_blocks(blocks, cfg: TransformerConfig, x, return_kv=False):
 
     def block(carry, blk, positions, window):
         x, state = carry
-        h = _rmsnorm(x, blk["ln1"], cfg.norm_eps)
+        with jax.named_scope("resid_norm"):
+            h = _rmsnorm(x, blk["ln1"], cfg.norm_eps)
         if cfg.attention == "cca":
             q, k, v = _cca_qkv(cfg, h, blk, positions)
         elif cfg.attention == "mla":
             q, k, v = _mla_qkv(cfg, h, blk, positions)
         else:
-            qkv = jnp.einsum("btd,dz->btz", h, blk["wqkv"].astype(h.dtype))
-            qkv = _constrain(qkv, "dp", "sp", "tp")
-            q, k, v = jnp.split(qkv, 3, axis=-1) if hkv == hq else \
-                jnp.split(qkv, (hq, hq + hkv), axis=-1)
+            with jax.named_scope("attn_qkv"):
+                qkv = jnp.einsum("btd,dz->btz", h,
+                                 blk["wqkv"].astype(h.dtype))
+                qkv = _constrain(qkv, "dp", "sp", "tp")
+                q, k, v = jnp.split(qkv, 3, axis=-1) if hkv == hq else \
+                    jnp.split(qkv, (hq, hq + hkv), axis=-1)
         routed = None
         if cfg.experts_held and cfg.router_input == "pre_attention":
             routed = _router_logits(h, blk["router"])
         a = _attention(cfg, q, k, v, positions=positions, window=window)
-        a = jnp.einsum("bth,hd->btd", a, blk["wo"].astype(h.dtype))
-        x = _residual(cfg, x, a, blk, 0)
-        h2 = _rmsnorm(x, blk["ln2"], cfg.norm_eps)
+        with jax.named_scope("attn_wo"):
+            a = jnp.einsum("bth,hd->btd", a, blk["wo"].astype(h.dtype))
+        with jax.named_scope("resid_norm"):
+            x = _residual(cfg, x, a, blk, 0)
+            h2 = _rmsnorm(x, blk["ln2"], cfg.norm_eps)
         told = None
         if cfg.experts_held:
             if cfg.router == "mlp":
@@ -1278,10 +1320,14 @@ def _run_blocks(blocks, cfg: TransformerConfig, x, return_kv=False):
                     m = m + _dense_mlp(cfg, h2, blk["ws_in"], blk["ws_out"])
             aux = 0.0
         elif cfg.n_experts:
-            m, aux = _moe_mlp(cfg, h2, blk["router"], blk["we_in"], blk["we_out"])
+            with jax.named_scope("moe_capacity"):
+                m, aux = _moe_mlp(cfg, h2, blk["router"], blk["we_in"],
+                                  blk["we_out"])
         else:
-            m, aux = _dense_mlp(cfg, h2, blk["w_in"], blk["w_out"]), 0.0
-        x = _residual(cfg, x, m, blk, 1)
+            with jax.named_scope("mlp"):
+                m, aux = _dense_mlp(cfg, h2, blk["w_in"], blk["w_out"]), 0.0
+        with jax.named_scope("resid_norm"):
+            x = _residual(cfg, x, m, blk, 1)
         kv = None
         if return_kv:
             b, t = x.shape[0], x.shape[1]
@@ -1475,14 +1521,18 @@ def _lm_loss_stats(params, cfg: TransformerConfig, ids, targets, *,
             return _chunked_ce(z.reshape(b * t, -1), head.astype(z.dtype),
                                tgt.reshape(b * t), cfg.loss_chunk,
                                weights=w) / rows
-        logits = jnp.einsum("btd,dv->btv", z, head.astype(z.dtype))
-        logits = _constrain(logits, "dp", "sp", "tp").astype(jnp.float32)
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        nll = -jnp.take_along_axis(logp, tgt[..., None].astype(jnp.int32),
-                                   -1)[..., 0]
-        return nll.mean() if weights is None else (nll * weights).sum() / rows
+        with jax.named_scope("lm_head"):
+            logits = jnp.einsum("btd,dv->btv", z, head.astype(z.dtype))
+            logits = _constrain(logits, "dp", "sp", "tp").astype(jnp.float32)
+            logp = jax.nn.log_softmax(logits, axis=-1)
+            nll = -jnp.take_along_axis(
+                logp, tgt[..., None].astype(jnp.int32), -1)[..., 0]
+            return (nll.mean() if weights is None
+                    else (nll * weights).sum() / rows)
 
-    main = mean_nll(_rmsnorm(x, params["ln_f"], cfg.norm_eps), targets)
+    with jax.named_scope("resid_norm"):
+        z = _rmsnorm(x, params["ln_f"], cfg.norm_eps)
+    main = mean_nll(z, targets)
     loss = main + aux_weight * aux
     if not cfg.predict_ahead:
         return loss, told
@@ -1494,14 +1544,17 @@ def _lm_loss_stats(params, cfg: TransformerConfig, ids, targets, *,
         # weighs 0 (it is computed, for the shapes' sake: causal attention
         # and per-token experts keep it from every other position)
         e = embed(params, cfg, targets, pos_offset)
-        p = jnp.concatenate([_rmsnorm(x, m["ln_h"], cfg.norm_eps),
-                             _rmsnorm(e, m["ln_e"], cfg.norm_eps)], -1)
+        with jax.named_scope("resid_norm"):
+            p = jnp.concatenate([_rmsnorm(x, m["ln_h"], cfg.norm_eps),
+                                 _rmsnorm(e, m["ln_e"], cfg.norm_eps)], -1)
         p = jnp.einsum("btz,zd->btd", p, m["proj"].astype(p.dtype))
         p, _, _, told_m = _run_blocks(m["block"], module, p)
         after = jnp.roll(targets, -1, axis=1)
         has = jnp.broadcast_to(jnp.arange(t) < t - 1, (b, t))
-        ahead = mean_nll(_rmsnorm(p, m["ln_f"], cfg.norm_eps), after,
-                         has.astype(jnp.float32), rows=b * (t - 1))
+        with jax.named_scope("resid_norm"):
+            z = _rmsnorm(p, m["ln_f"], cfg.norm_eps)
+        ahead = mean_nll(z, after, has.astype(jnp.float32),
+                         rows=b * (t - 1))
     loss = loss + cfg.predict_weight * ahead
     if told is not None:    # the module's block counts as one more layer
         told = jax.tree_util.tree_map(
@@ -1532,9 +1585,10 @@ def make_train_step(cfg: TransformerConfig, optimizer):
     def step(params, opt_state, ids, targets):
         (loss, told), grads = jax.value_and_grad(
             _lm_loss_stats, has_aux=True)(params, cfg, ids, targets)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
         import optax as _optax
-        params = _optax.apply_updates(params, updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = _optax.apply_updates(params, updates)
         if told is None:
             return params, opt_state, loss
         return params, opt_state, loss, told
@@ -1576,8 +1630,9 @@ def make_ring_train_step(cfg: TransformerConfig, optimizer, mesh: Mesh):
         loss, grads = jax.value_and_grad(loss_fn)(params)
         loss = lax.pmean(loss, ("dp", "sp"))
         grads = lax.pmean(grads, ("dp", "sp"))
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = _optax.apply_updates(params, updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = _optax.apply_updates(params, updates)
         return params, opt_state, loss
 
     def step(params, opt_state, ids, targets):

@@ -64,8 +64,8 @@ NAMESPACE = "dl4j_"
 # is a deliberate act: each new label multiplies time series, and an
 # unbounded one (request id, trace id) melts the registry.
 ALLOWED_LABELS = {"backend", "component", "config", "direction", "kernel",
-                  "kind", "layer", "level", "mode", "reason", "replica",
-                  "row", "stat", "unit", "verdict"}
+                  "kind", "layer", "level", "mode", "phase", "reason",
+                  "replica", "row", "stat", "unit", "verdict"}
 # per-prefix restriction (ISSUE 12/13): each observability plane may
 # label ONLY from its own small fixed vocabulary — component names,
 # stat kinds and probe-pair kinds are bounded sets, never per-request
@@ -75,7 +75,8 @@ ALLOWED_LABELS = {"backend", "component", "config", "direction", "kernel",
 PLANE_LABELS = {
     "dl4j_mem_": {"component", "replica"},
     "dl4j_kv_": {"component", "replica"},
-    "dl4j_compile_": {"component", "replica"},
+    # phase (ISSUE 37): trace / lower / backend, JAX's three compile events
+    "dl4j_compile_": {"component", "phase", "replica"},
     # numerics & fidelity plane (ISSUE 13): layer/kind/replica only
     "dl4j_num_": {"kind", "layer", "replica"},
     "dl4j_fidelity_": {"kind", "layer", "replica"},
