@@ -183,7 +183,8 @@ def phase_train_lm(cfg, batch, steps=4, seed=0, require_flash=False):
     import jax
     import numpy as np
 
-    from deeplearning4j_tpu.kernels.flash_attention import _tuned_blocks
+    from deeplearning4j_tpu.kernels.flash_attention import (_tuned_blocks,
+                                                            ntc_layout)
     from deeplearning4j_tpu.zoo import transformer as tfm
 
     params = tfm.init_params(jax.random.PRNGKey(seed), cfg)
@@ -194,8 +195,10 @@ def phase_train_lm(cfg, batch, steps=4, seed=0, require_flash=False):
            f"train_lm: loss did not fall on a repeated batch: {losses}")
     blocks = None
     if obs["attention_path"] == "flash":
-        blocks = list(_tuned_blocks(batch, cfg.n_heads, cfg.max_seq,
-                                    cfg.head_dim, cfg.dtype, True, None))
+        blocks = list(_tuned_blocks(
+            batch, cfg.n_heads, cfg.max_seq, cfg.head_dim, cfg.dtype, True,
+            None, group=cfg.n_heads // cfg.kv_heads,
+            layout=ntc_layout(cfg.head_dim, cfg.n_heads, cfg.kv_heads)))
     if require_flash:
         _check(obs["attention_path"] == "flash" and obs["flash_in_program"],
                "train_lm: the flash kernel is not in the compiled program "

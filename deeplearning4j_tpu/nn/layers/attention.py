@@ -40,26 +40,30 @@ def multi_head_attention(params, q_in, kv_in, n_heads, head_dim, mask=None,
     b, tq, _ = q_in.shape
     tk = kv_in.shape[1]
     v_src = kv_in if v_in is None else v_in
-    q = (q_in @ params["Wq"].astype(dt)).reshape(b, tq, n_heads, head_dim)
-    k = (kv_in @ params["Wk"].astype(dt)).reshape(b, tk, n_heads, head_dim)
-    v = (v_src @ params["Wv"].astype(dt)).reshape(b, tk, n_heads, head_dim)
+    q = q_in @ params["Wq"].astype(dt)
+    k = kv_in @ params["Wk"].astype(dt)
+    v = v_src @ params["Wv"].astype(dt)
     # pallas kernel needs self-attention (Tq == Tk), no key mask, and real TPU
-    # hardware ("pallas_interpret" forces interpreter mode for tests/debug)
+    # hardware ("pallas_interpret" forces interpreter mode for tests/debug);
+    # it takes the projections as they are, (B, T, H·D)
     use_pallas = (impl == "pallas_interpret"
                   or (impl == "pallas" and jax.default_backend() == "tpu"))
     if use_pallas and mask is None and tq == tk:
         from ...kernels.flash_attention import flash_attention_ntc
         out = flash_attention_ntc(
-            q, k, v, causal=is_causal,
+            q, k, v, n_heads, causal=is_causal,
             interpret=True if impl == "pallas_interpret" else None)
     else:
+        q = q.reshape(b, tq, n_heads, head_dim)
+        k = k.reshape(b, tk, n_heads, head_dim)
+        v = v.reshape(b, tk, n_heads, head_dim)
         kw = {}
         if mask is not None:
             kw["key_value_seq_lengths"] = None
             amask = mask[:, None, None, :].astype(bool)  # (B,1,1,Tk) -> broadcast (B,H,Tq,Tk)
             kw["mask"] = jnp.broadcast_to(amask, (b, n_heads, tq, tk))
         out = jax.nn.dot_product_attention(q, k, v, is_causal=is_causal, **kw)
-    out = out.reshape(b, tq, n_heads * head_dim)
+        out = out.reshape(b, tq, n_heads * head_dim)
     return out @ params["Wo"].astype(dt)
 
 

@@ -728,28 +728,38 @@ def _mla_qkv(cfg, h, blk, positions):
 
 def _attention(cfg, q, k, v, mask_bias=None, positions="learned", window=0):
     """``positions`` and ``window`` are the layer's kind (static): rotary
-    q and k or none; the keys a query sees (0 = every earlier one)."""
+    q and k or none; the keys a query sees (0 = every earlier one). q, k and
+    v come and the output goes as (B, T, heads·Dh), the projections' own
+    layout, which the flash kernels read and write as they are."""
     b, t = q.shape[0], q.shape[1]
-    with jax.named_scope("attn_qkv"):
-        q = q.reshape(b, t, cfg.n_heads, cfg.head_dim)
-        k = k.reshape(b, t, cfg.kv_heads, cfg.head_dim)
-        v = v.reshape(b, t, cfg.kv_heads, cfg.head_dim)
-        if positions == "rope" and cfg.attention == "mha":  # others: theirs
-            q = _rope(q, cfg.rope_theta, rotary=cfg.rotary_dims)
-            k = _rope(k, cfg.rope_theta, rotary=cfg.rotary_dims)
+
+    def heads(x, n):
+        return x.reshape(b, t, n, cfg.head_dim)
+
+    if positions == "rope" and cfg.attention == "mha":  # others: theirs
+        with jax.named_scope("attn_qkv"):
+            q = _rope(heads(q, cfg.n_heads), cfg.rope_theta,
+                      rotary=cfg.rotary_dims).reshape(q.shape)
+            k = _rope(heads(k, cfg.kv_heads), cfg.rope_theta,
+                      rotary=cfg.rotary_dims).reshape(k.shape)
     path = attention_path(cfg, t, q.dtype)
-    # the flash kernels, alone or in the ring's hops, open scopes of their
-    # own (flash_*) and attn_core around them: attn_core is not opened here
+    if path == "flash":
+        # the kernels open scopes of their own (flash_*), name their output
+        # and lse for save_attn, and take (B, T, H·Dh) as it is
+        from ..kernels.flash_attention import flash_attention_ntc
+        return flash_attention_ntc(q, k, v, cfg.n_heads, causal=True,
+                                   window=window or None)
+    with jax.named_scope("attn_qkv"):
+        q = heads(q, cfg.n_heads)
+        k, v = heads(k, cfg.kv_heads), heads(v, cfg.kv_heads)
+    # the ring's flash hops open scopes of their own and attn_core around
+    # them: attn_core is not opened here
     if path == "ring":
         if window or cfg.kv_heads != cfg.n_heads:
             raise NotImplementedError(
                 "ring attention has no window and no grouped K/V heads")
         from ..parallel.ring_attention import ring_attention_inner
         out = ring_attention_inner(q, k, v, causal=True)
-    elif path == "flash":
-        from ..kernels.flash_attention import flash_attention_ntc
-        out = flash_attention_ntc(q, k, v, causal=True,
-                                  window=window or None)
     else:
         with jax.named_scope("attn_core"):
             if path == "xla_bf16_scores":
@@ -758,8 +768,7 @@ def _attention(cfg, q, k, v, mask_bias=None, positions="learned", window=0):
                 out = jax.nn.dot_product_attention(
                     q, k, v, is_causal=True,
                     local_window_size=(window - 1, 0) if window else None)
-    if path != "flash":     # the flash kernel names its own output and lse
-        out = checkpoint_name(out, "attn_out")  # remat_policy="save_attn"
+    out = checkpoint_name(out, "attn_out")  # remat_policy="save_attn"
     with jax.named_scope("attn_core"):
         return out.reshape(b, t, cfg.n_heads * cfg.head_dim)
 
@@ -813,11 +822,11 @@ def _remat_wrap(fn, policy: str):
         # XLA and ring paths name their output "attn_out" in `_attention`.
         # The flash kernel names the two residuals of its custom_vjp that
         # only it can rebuild (kernels/flash_attention.py::_flash_fwd): its
-        # output, "attn_out" (ONE saved copy, as (B, T, H, Dh): the same
-        # tensor the other paths name), and the (B, H, T) f32 log-sum-exp,
-        # "attn_lse". With both saved the backward scan runs the two
-        # backward kernels only: three Pallas calls a layer and step where
-        # "full" runs four (the forward twice); q, k and v are recomputed
+        # output, "attn_out" (ONE saved copy, as (B, T, H·Dh), what `wo`
+        # reads), and the (B·H, 1, T) f32 log-sum-exp, "attn_lse". With
+        # both saved the backward scan runs the two backward kernels only:
+        # three Pallas calls a layer and step where "full" runs four (the
+        # forward twice); q, k and v are recomputed
         # from the block's input either way. B*T*D bf16 + B*H*T f32 a
         # layer (32 + 1 MiB at b16 T=1024 d1024).
         "save_attn":

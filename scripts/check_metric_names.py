@@ -64,8 +64,9 @@ NAMESPACE = "dl4j_"
 # is a deliberate act: each new label multiplies time series, and an
 # unbounded one (request id, trace id) melts the registry.
 ALLOWED_LABELS = {"backend", "component", "config", "direction", "kernel",
-                  "kind", "layer", "level", "mode", "phase", "reason",
-                  "replica", "row", "stat", "unit", "verdict"}
+                  "kind", "layer", "layout", "level", "mode", "phase",
+                  "reason", "replica", "row", "stat", "unit", "verdict"}
+# layout (ISSUE 38): dl4j_flash_layout_total's three kernel layouts
 # per-prefix restriction (ISSUE 12/13): each observability plane may
 # label ONLY from its own small fixed vocabulary — component names,
 # stat kinds and probe-pair kinds are bounded sets, never per-request

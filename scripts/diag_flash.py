@@ -73,8 +73,8 @@ def run(train=True):
     causal = True
     for t, b in ((1024, 16), (2048, 8), (4096, 4), (8192, 2)):
         key = jax.random.PRNGKey(0)
-        q = jax.random.normal(key, (b, t, h, d), jnp.bfloat16)
-        qh = q.transpose(0, 2, 1, 3)
+        q = jax.random.normal(key, (b, t, h * d), jnp.bfloat16)
+        qh = q.reshape(b, t, h, d).transpose(0, 2, 1, 3)
 
         def xla_fn(q, k, v):
             return mha_reference(q, k, v, None, causal)
@@ -88,7 +88,7 @@ def run(train=True):
             return jnp.einsum("bhqk,bhkd->bhqd", p, v)
 
         def flash_fn(q, k, v):
-            return flash_attention_ntc(q, k, v, causal=causal)
+            return flash_attention_ntc(q, k, v, h, causal=causal)
 
         for name, fn, arg in (("xla", xla_fn, qh),
                               ("xla-bf16p", xla_bf16_fn, qh),

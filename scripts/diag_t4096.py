@@ -158,8 +158,7 @@ def block_sweep(tag_prefix, t, b, h=8, d=64):
 
     import jax
     import jax.numpy as jnp
-    from deeplearning4j_tpu.kernels.flash_attention import (
-        _flash_attention_pallas)
+    from deeplearning4j_tpu.kernels.flash_attention import flash_attention
 
     key = jax.random.PRNGKey(0)
     q = jax.random.normal(key, (b, h, t, d), jnp.bfloat16)
@@ -170,7 +169,7 @@ def block_sweep(tag_prefix, t, b, h=8, d=64):
             continue
         try:
             def loss(q_, k_, v_, _bq=bq, _bk=bk):
-                return jnp.sum(_flash_attention_pallas(
+                return jnp.sum(flash_attention(
                     q_, k_, v_, None, True, _bq, _bk, False
                 ).astype(jnp.float32))
 

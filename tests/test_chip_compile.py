@@ -115,6 +115,28 @@ def test_flash_fwd_bwd_compiles(one_chip, no_persistent_cache, shape,
         assert re.search(rf"%{name}(\.\d+)? = [^\n]*tpu_custom_call", text), name
 
 
+@pytest.mark.parametrize("b,t,h,hkv,d,window", [
+    (16, 1024, 16, 16, 64, None),           # gpt2m-train-b16: heads paired
+    (2, 8192, 7, 1, 128, 4096),             # smallthinker's window layers
+    (1, 8192, 20, 20, 256, None),           # glm47flash-train-b1-t8192
+], ids=["gpt2_pairs", "moe_window", "glm_d256"])
+def test_flash_ntc_compiles_with_no_layout_work(one_chip, no_persistent_cache,
+                                                b, t, h, hkv, d, window):
+    """The kernels on the cells' (B, T, H·D) projections, at the race's
+    first blocks: three custom calls and no transpose or copy round them."""
+    from deeplearning4j_tpu.kernels.flash_attention import _flash
+
+    def loss(q, k, v):
+        return jnp.sum(_flash(q, k, v, None, True, 512, 1024, False, window,
+                              (h, hkv)).astype(jnp.float32))
+
+    q = _sds(one_chip, (b, t, h * d), jnp.bfloat16)
+    kv = _sds(one_chip, (b, t, hkv * d), jnp.bfloat16)
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    assert text.count("tpu_custom_call") == 3
+    assert " transpose(" not in text and " copy(" not in text
+
+
 @pytest.mark.parametrize("window,suffix", [(4096, "_win"), (None, "")],
                          ids=["window_4096", "full"])
 def test_flash_grouped_heads_compile_at_the_moe_cell_shape(
@@ -182,15 +204,19 @@ def test_flash_compiles_at_the_glm_cell_shape(one_chip, no_persistent_cache,
         assert re.search(rf"%{name}(\.\d+)? = [^\n]*tpu_custom_call", text), name
 
 
-def test_the_largest_block_pair_is_refused_at_head_size_256(
+def test_a_block_pair_past_vmem_is_refused_at_head_size_256(
         one_chip, no_persistent_cache):
-    """1024 x 1024 does not fit VMEM at a head of 256 (the dkv kernel's
-    stack): the compiler raises an ordinary exception, which the block race
+    """2048 x 1024 does not fit VMEM at a head of 256: the compiler raises
+    an ordinary exception, which the block race
     (``kernels/autotune.py::_race``) records for the candidate and goes on
-    from; the race does not die on it."""
+    from; the race does not die on it. (Until PR 38 the race's own largest
+    pair, 1024 x 1024, was refused here: the dkv kernel held transposed
+    copies of its score blocks; since it works on scores as (keys,
+    queries) that pair fits, and is raced.)"""
     x = _sds(one_chip, (1, 20, 8192, 256), jnp.bfloat16)
+    _compile(_glm_flash_grad((1024, 1024)), x, x, x)
     with pytest.raises(Exception, match="(?i)vmem"):
-        _compile(_glm_flash_grad((1024, 1024)), x, x, x)
+        _compile(_glm_flash_grad((2048, 1024)), x, x, x)
 
 
 def test_chunked_head_compiles_at_the_zaya_cell_shape(one_chip,

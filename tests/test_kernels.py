@@ -1,6 +1,7 @@
 """Pallas kernel tests — run in interpreter mode on the CPU mesh, checked
 against plain-XLA oracles (SURVEY.md §7 R2 item, pulled into R1)."""
 
+import importlib
 import re
 
 import jax
@@ -170,19 +171,24 @@ def test_chunked_head_multiplies_by_the_vocabulary_three_times_a_chunk():
     assert _vocab_products_and_loops(compiled(loss), vocab) == (1, 1)
 
 
+@pytest.mark.parametrize("head_size,kv_heads,out_shape", [
+    (128, 2, "2,32,512"), (64, 4, "2,32,256"), (8, 2, "8,32,8"),
+], ids=["ntc", "ntc_pairs", "transposed"])
 def test_save_attn_through_the_transformer_saves_one_output_and_one_lse(
-        monkeypatch, capsys):
+        monkeypatch, capsys, head_size, kv_heads, out_shape):
     """``zoo.transformer`` with the kernels in the path (interpret mode), a
     stack of two kinds of layer, ``remat_policy="save_attn"``: the loss and
     every gradient equal the ones without rematerialization, and what the
     backward pass is handed per layer is ONE copy of the attention output,
-    as (B, T, H, Dh), and the kernel's (B, H, T) lse."""
+    the kernel's own ((B, T, H·Dh) where the kernels read the projections'
+    layout, (B·H, T, Dh) where they transpose), and the kernel's (B·H, 1, T)
+    lse."""
     from deeplearning4j_tpu.zoo import transformer as tfm
     monkeypatch.setattr(jax, "device_count", lambda: 1)   # as on one chip
 
     def cfg_of(**over):
-        kw = dict(vocab_size=50, d_model=48, n_heads=4, n_kv_heads=2,
-                  head_size=8, n_layers=4, d_ff=40, max_seq=32,
+        kw = dict(vocab_size=50, d_model=48, n_heads=4, n_kv_heads=kv_heads,
+                  head_size=head_size, n_layers=4, d_ff=40, max_seq=32,
                   dtype=jnp.float32, layer_positions=("none", "rope"),
                   layer_windows=(0, 20), use_flash_attention=True,
                   fused_loss=False, remat=True, remat_policy="save_attn")
@@ -205,16 +211,105 @@ def test_save_attn_through_the_transformer_saves_one_output_and_one_lse(
         shapes = [line.split()[0] for line in
                   capsys.readouterr().out.splitlines() if " scan " in line]
         # stacked over the 2 periods; one line per layer of the period:
-        # the output as (B, T, H, Dh), as (B, H, T, Dh), as (B, T, H*Dh),
-        # and the lse
+        # the kernel's output, the output as (B, T, H, Dh), and the lse
         return tuple(shapes.count(f"f32[2,{shape}]") for shape in
-                     ("2,32,4,8", "2,4,32,8", "2,32,32", "2,4,32"))
+                     (out_shape, f"2,32,4,{head_size}", "8,1,32"))
 
-    assert saved(cfg) == (2, 0, 0, 2)
-    assert saved(cfg_of(remat_policy="full")) == (0, 0, 0, 0)
+    assert saved(cfg) == (2, 0, 2)
+    assert saved(cfg_of(remat_policy="full")) == (0, 0, 0)
     # without the kernel the XLA path's output keeps its name, and no lse
     assert saved(cfg_of(use_flash_attention=False,
-                        attn_scores_bf16=False)) == (2, 0, 0, 0)
+                        attn_scores_bf16=False)) == (0, 2, 0)
+
+
+def _layout_count(layout):
+    from deeplearning4j_tpu.obs import get_registry
+    return get_registry().counter(
+        "dl4j_flash_layout_total", labelnames=("layout",)).value(layout=layout)
+
+
+@pytest.mark.parametrize("h,hkv,d,window,layout", [
+    (4, 4, 64, None, "ntc_pairs"),          # gpt2: two heads a tile
+    (4, 4, 32, 24, "ntc_pairs"),            # four heads a tile
+    (7, 1, 128, None, "ntc"),               # the moe cell's group of 7
+    (7, 1, 128, 24, "ntc"),
+    (8, 2, 128, None, "ntc"),               # zaya's group of 4
+    (8, 2, 128, 24, "ntc"),
+    (5, 5, 256, None, "ntc"),               # glm's head of 256
+    (3, 3, 64, None, "transposed"),         # odd heads cannot pair
+    (4, 2, 64, 24, "transposed"),           # nor grouped ones
+], ids=["d64_pairs", "d32_fours_window", "d128_group7", "d128_group7_window",
+        "d128_group4", "d128_group4_window", "d256", "d64_odd_heads",
+        "d64_grouped"])
+def test_flash_ntc_reads_the_projections_layout(monkeypatch, h, hkv, d,
+                                                 window, layout):
+    """``flash_attention_ntc`` on (B, T, H·D) operands, several blocks of
+    queries and keys (interpret mode): the output and all three gradients
+    are ``mha_reference``'s, and the call counts the layout it took."""
+    fa = importlib.import_module("deeplearning4j_tpu.kernels.flash_attention")
+    monkeypatch.setattr(fa, "_tuned_blocks", lambda *a, **k: (16, 32))
+    b, t = 2, 64
+    q, k, v, w = (jnp.asarray(RNG.standard_normal((b, t, n * d)), jnp.float32)
+                  for n in (h, hkv, hkv, h))
+
+    def heads(x):
+        return x.reshape(b, t, -1, d).transpose(0, 2, 1, 3)
+
+    def ref(q_, k_, v_):
+        o = mha_reference(heads(q_), heads(k_), heads(v_), None, True, window)
+        return o.transpose(0, 2, 1, 3).reshape(b, t, h * d)
+
+    def ntc(q_, k_, v_):
+        return fa.flash_attention_ntc(q_, k_, v_, h, causal=True,
+                                      interpret=True, window=window)
+
+    before = _layout_count(layout)
+    np.testing.assert_allclose(ntc(q, k, v), ref(q, k, v), atol=2e-5)
+    assert _layout_count(layout) == before + 1
+    got = jax.grad(lambda *a: jnp.sum(ntc(*a) * w), (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(ref(*a) * w), (0, 1, 2))(q, k, v)
+    for name, x, y in zip("qkv", got, want):
+        np.testing.assert_allclose(x, y, atol=2e-5, err_msg=name)
+
+
+def _eqns_outside_kernels(jaxpr):
+    """Every equation of a jaxpr and its sub-jaxprs, the pallas_calls' own
+    bodies left out."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (tuple, list)) else [param]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns_outside_kernels(sub)
+
+
+@pytest.mark.parametrize("h,hkv,d,layout", [
+    (4, 4, 64, "ntc_pairs"), (8, 2, 128, "ntc"),
+], ids=["d64_pairs", "d128_group4"])
+def test_flash_ntc_runs_no_layout_work_round_the_kernels(h, hkv, d, layout):
+    """Forward and backward through ``flash_attention_ntc`` traced: three
+    pallas_calls, and outside them no transpose and no broadcast to a
+    minor dimension of 8 (the old 8-lane copies of lse and delta)."""
+    b, t = 2, 256
+    q = jnp.zeros((b, t, h * d), jnp.bfloat16)
+    kv = jnp.zeros((b, t, hkv * d), jnp.bfloat16)
+    from deeplearning4j_tpu.kernels.flash_attention import flash_attention_ntc
+
+    def loss(q_, k_, v_):
+        return flash_attention_ntc(q_, k_, v_, h, causal=True, interpret=True
+                                   ).astype(jnp.float32).sum()
+
+    before = _layout_count(layout)
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, kv, kv)
+    assert _layout_count(layout) == before + 1
+    eqns = list(_eqns_outside_kernels(jaxpr.jaxpr))
+    assert [e.primitive.name for e in eqns].count("pallas_call") == 3
+    assert not [e for e in eqns if e.primitive.name == "transpose"]
+    assert not [e for e in eqns if e.primitive.name == "broadcast_in_dim"
+                and e.params["shape"][-1] == 8]
 
 
 def test_flash_odd_seq_falls_back_to_smaller_blocks():
@@ -406,15 +501,13 @@ def test_autotune_times_real_kernels_while_a_jit_is_tracing(tmp_path,
     traced (``program_id`` has no evaluation rule) — the first chip run of
     this kernel's tuner failed exactly so, for all seven candidates."""
     from deeplearning4j_tpu.kernels import autotune as at
-    from deeplearning4j_tpu.kernels.flash_attention import \
-        _flash_attention_pallas
     monkeypatch.setattr(at, "_CACHE_PATH", tmp_path / "autotune.json")
     at._memory_cache.clear()
 
     def make_run(cand):
         q = jax.random.normal(jax.random.PRNGKey(0), (1, 2, 128, 32))
         grad = jax.jit(jax.grad(lambda q_, k_, v_: jnp.sum(
-            _flash_attention_pallas(q_, k_, v_, None, True, *cand, True))))
+            flash_attention(q_, k_, v_, None, True, *cand, True))))
         return lambda: grad(q, q, q)
 
     def body(carry, _):

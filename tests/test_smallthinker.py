@@ -280,7 +280,9 @@ def test_flash_ntc_takes_fewer_kv_heads_and_a_window():
     q = jax.random.normal(ks[0], (1, 48, 7, 16), jnp.float32)
     k = jax.random.normal(ks[1], (1, 48, 1, 16), jnp.float32)
     v = jax.random.normal(ks[2], (1, 48, 1, 16), jnp.float32)
-    got = flash_attention_ntc(q, k, v, causal=True, interpret=True, window=20)
+    flat = lambda a: a.reshape(1, 48, -1)                       # noqa: E731
+    got = flash_attention_ntc(flat(q), flat(k), flat(v), 7, causal=True,
+                              interpret=True, window=20).reshape(q.shape)
     tr = lambda a: a.transpose(0, 2, 1, 3)                      # noqa: E731
     want = tr(mha_reference(tr(q), tr(k), tr(v), None, True, 20))
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
