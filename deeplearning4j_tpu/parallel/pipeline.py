@@ -34,6 +34,13 @@ def _stage_loss_fn(cfg, n_stages, other_axes=(), aux_weight=1e-2):
             "router's state would start from zeros at every stage (ROADMAP "
             "Reach B9)")
 
+    if "gated_deltanet" in getattr(cfg, "layer_mixers", ()):
+        raise NotImplementedError(
+            "a stage slices every leaf of params['blocks'] by layer, and "
+            "layer_mixers 'gated_deltanet' stacks its gdn_* leaves over its "
+            "own layers alone: a stage would have to hold whole periods and "
+            "slice each leaf by its mixer's share of them")
+
     if getattr(cfg, "dense_layers", 0) or getattr(cfg, "predict_ahead", 0):
         raise NotImplementedError(
             "every stage holds a slice of ONE group of stacked blocks: "

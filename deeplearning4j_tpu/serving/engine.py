@@ -132,6 +132,14 @@ class GenerationEngine:
                  paged_kernel: Optional[str] = None,
                  quant_kv: Optional[str] = None,
                  quant_weights: Optional[str] = None):
+        if "gated_deltanet" in getattr(cfg, "layer_mixers", ()):
+            raise NotImplementedError(
+                "GenerationEngine cannot decode layer_mixers "
+                "'gated_deltanet': such a layer carries a (key x value) "
+                "state per head and its convolution's last tokens from one "
+                "step to the next, which needs a state per slot beside the "
+                "KV pages, and a period whose layers hold trees of different "
+                "shapes needs a cache of each kind (ROADMAP Reach B8)")
         if (getattr(cfg, "attention", "mha") != "mha"
                 or getattr(cfg, "router", "linear") != "linear"
                 or getattr(cfg, "scaled_residuals", False)
@@ -139,7 +147,11 @@ class GenerationEngine:
                 or getattr(cfg, "norm_eps", 1e-6) != 1e-6
                 or getattr(cfg, "dense_layers", 0)
                 or getattr(cfg, "shared_experts", 0)
-                or getattr(cfg, "predict_ahead", 0)):
+                or getattr(cfg, "predict_ahead", 0)
+                or getattr(cfg, "qk_norm", False)
+                or getattr(cfg, "attn_output_gate", False)
+                or getattr(cfg, "norm_zero_centred", False)
+                or getattr(cfg, "shared_expert_gate", False)):
             raise NotImplementedError(
                 "GenerationEngine cannot decode this block: compressed "
                 "convolutional attention (attention='cca') needs the "
@@ -152,7 +164,8 @@ class GenerationEngine:
                 "the query and the output, and the decode step knows no "
                 "sigmoid router, no shared expert, no leading dense layers "
                 "(two groups of blocks) and no prediction module to draft "
-                "with (ROADMAP Reach B10)")
+                "with (ROADMAP Reach B10); nor q and k norms, an output "
+                "gate, zero-centred norms or a gated shared expert")
         if getattr(cfg, "n_experts", 0):
             raise NotImplementedError(
                 "GenerationEngine is dense-only: MoE expert dispatch has "

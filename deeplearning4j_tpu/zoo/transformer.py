@@ -51,12 +51,16 @@ STEP_SCOPES = (
     ("embed", "the table's gather, its scaling and the learned positions; "
               "backward: the scatter-add into the table's gradient"),
     ("attn_qkv", "the plain wqkv product and its split (of latent attention, "
-                 "the rotary parts' split alone); in _attention the head "
-                 "reshapes and, for plain heads, the rotary positions"),
-    ("attn_wo", "attention's output projection, every attention kind"),
+                 "the rotary parts' split alone), q and k's norms; in "
+                 "_attention the head reshapes and, for plain heads, the "
+                 "rotary positions; a gated DeltaNet layer's two products"),
+    ("attn_wo", "attention's output gate and projection, every attention "
+                "kind, and a gated DeltaNet layer's output projection"),
     ("attn_core", "attention between q, k, v and its output where no Pallas "
                   "kernel runs (XLA's or the ring's), and the layout changes "
-                  "around the kernel where one does"),
+                  "around the kernel where one does; a gated DeltaNet layer's "
+                  "convolution, beta and decays (gdn_conv), q and k's norms "
+                  "and the delta rule (gdn_rule) and gated norm (gdn_norm)"),
     ("mlp", "the dense MLP of a layer without experts"),
     ("resid_norm", "the norms ln1, ln2, ln_f, a prediction module's three, "
                    "and the residual merges"),
@@ -218,6 +222,36 @@ class TransformerConfig:
     # loss = main + predict_weight · that
     predict_ahead: int = 0
     predict_weight: float = 0.3
+    # ---- Recurrent layers and gates. As above: what the model is, never how
+    # it is computed.
+    # layer_mixers: what mixes the tokens in each layer of the period, beside
+    # layer_positions and layer_windows: "attention" (the attention kind
+    # above) | "gated_deltanet" (arXiv:2412.06464): [q | k | v | z] = h W and
+    # [b | a] = h W', q, k and v through a causal depthwise convolution of
+    # gdn_conv_taps and silu, q and k L2-normed per head (q over sqrt of the
+    # key size), beta = sigmoid(b), decay exp(g) with g = -exp(A_log) ·
+    # softplus(a + dt_bias); a (key size x value size) state per value head
+    # (key head j // (value heads / key heads)) carried along the sequence by
+    # the delta rule S <- exp(g) S; S <- S + k beta (v - S^T k)^T; o = S^T q;
+    # then an RMS norm per head times silu(z), and an output projection.
+    # Its layers have no positions and see every earlier token. () =
+    # attention in every layer. A leaf only one mixer's layers have is
+    # stacked over those layers alone (gdn_* over the gated DeltaNet layers).
+    layer_mixers: tuple = ()
+    gdn_key_heads: int = 0
+    gdn_value_heads: int = 0
+    gdn_key_size: int = 0
+    gdn_value_size: int = 0
+    gdn_conv_taps: int = 4
+    # attention's output times sigmoid of a gate that the q projection gives
+    # beside q (arXiv:2505.06708), per channel of the heads
+    attn_output_gate: bool = False
+    qk_norm: bool = False       # an RMS norm over every head of q and of k
+    # every RMS norm of the stack (ln1, ln2, ln_f, q and k's) stores its
+    # scale as w and multiplies by 1 + w, w drawn at zero
+    norm_zero_centred: bool = False
+    # the shared experts' output times sigmoid(h w), w a (d_model, 1) vector
+    shared_expert_gate: bool = False
 
     @property
     def head_dim(self):
@@ -236,18 +270,27 @@ class TransformerConfig:
 
     @property
     def layer_kinds(self):
-        """((positions, window), ...) for the layers of one period."""
-        n = max(len(self.layer_positions), len(self.layer_windows), 1)
+        """((positions, window, mixer), ...) for the layers of one period."""
+        n = max(len(self.layer_positions), len(self.layer_windows),
+                len(self.layer_mixers), 1)
         pos = self.layer_positions or ("learned",) * n
         win = self.layer_windows or (0,) * n
-        if len(pos) != len(win) or self.n_layers % n \
+        mix = self.layer_mixers or ("attention",) * n
+        if len(pos) != len(win) or len(mix) != n or self.n_layers % n \
                 or set(pos) - {"rope", "none", "learned"} \
-                or ("learned" in pos and set(pos) != {"learned"}):
+                or ("learned" in pos and set(pos) != {"learned"}) \
+                or set(mix) - {"attention", "gated_deltanet"}:
             raise ValueError(
-                f"layer_positions {self.layer_positions} and layer_windows "
-                f"{self.layer_windows} must describe one period that "
-                f"divides n_layers={self.n_layers}")
-        return tuple(zip(pos, (int(w) for w in win)))
+                f"layer_positions {self.layer_positions}, layer_windows "
+                f"{self.layer_windows} and layer_mixers {self.layer_mixers} "
+                f"must describe one period that divides "
+                f"n_layers={self.n_layers}")
+        return tuple(zip(pos, (int(w) for w in win), mix))
+
+    def layers_of(self, mixer: str) -> int:
+        """How many layers of the stack ``mixer`` mixes."""
+        kinds = self.layer_kinds
+        return self.n_layers // len(kinds) * sum(k[2] == mixer for k in kinds)
 
 
 def _check(cfg: TransformerConfig):
@@ -347,7 +390,31 @@ def _check(cfg: TransformerConfig):
             "the capacity expert layer (experts_held=()) has GELU experts "
             "and routes on the MLP's input; give experts_held=(0, n_experts)"
             " for the dropless layer")
-    cfg.layer_kinds
+    kinds = cfg.layer_kinds
+    if any(m == "gated_deltanet" for _, _, m in kinds):
+        if min(cfg.gdn_key_heads, cfg.gdn_value_heads, cfg.gdn_key_size,
+               cfg.gdn_value_size, cfg.gdn_conv_taps) < 1 \
+                or cfg.gdn_value_heads % cfg.gdn_key_heads \
+                or any(p == "rope" or w for p, w, m in kinds
+                       if m == "gated_deltanet"):
+            raise ValueError(
+                "gated_deltanet wants key and value heads (value heads a "
+                "multiple of key heads), their sizes and conv taps, and its "
+                "layers place no positions and see every earlier token")
+        if cfg.attention != "mha" or cfg.use_ring_attention:
+            raise NotImplementedError(
+                "gated DeltaNet layers stand beside plain attention layers "
+                "(attention='mha'), and no ring step hands their state "
+                "along the shards of the sequence")
+    if (cfg.qk_norm or cfg.attn_output_gate) and cfg.attention != "mha":
+        raise NotImplementedError(
+            "qk_norm and attn_output_gate act on plain heads (attention="
+            "'mha'); cca and mla norm their own")
+    if cfg.shared_expert_gate and (not cfg.shared_experts
+                                   or cfg.router != "linear"):
+        raise NotImplementedError(
+            "the shared experts' gate stands beside the linear router's "
+            "shared experts (shared_experts, router='linear')")
 
 
 # ---------------------------------------------------------------- params
@@ -370,24 +437,53 @@ def _group_configs(cfg: TransformerConfig):
     return dense, rest, module
 
 
+def _mixer_of(name: str):
+    """The mixer whose layers alone have the block leaf ``name``, or None for
+    a leaf of every layer (norms, router, MLP, experts)."""
+    if name.startswith("gdn_"):
+        return "gated_deltanet"
+    if name in ("wqkv", "wo", "q_norm", "k_norm"):
+        return "attention"
+    return None
+
+
 def _init_blocks(k, cfg: TransformerConfig):
-    """The stacked blocks of a stack of one shape, from 12 keys."""
+    """The stacked blocks of a stack of one shape, from 12 keys. A leaf that
+    only one mixer's layers have is stacked over those layers alone."""
     d, f, h, L = cfg.d_model, cfg.d_ff, cfg.n_heads * cfg.head_dim, cfg.n_layers
     hkv = cfg.kv_heads * cfg.head_dim
     gated = 1 if cfg.mlp == "gelu" else 2        # gate | up side by side
     pd = cfg.param_dtype
+    La, Lg = cfg.layers_of("attention"), cfg.layers_of("gated_deltanet")
+    scale0 = jnp.zeros if cfg.norm_zero_centred else jnp.ones
 
     def norm(key, shape, fan_in):
         return (jax.random.normal(key, shape, pd) / math.sqrt(fan_in))
 
-    blocks = {
-        "ln1": jnp.ones((L, d), pd),
-        "wqkv": norm(k[2], (L, d, h + 2 * hkv), d),
-        "wo": norm(k[3], (L, h, d), h),
-        "ln2": jnp.ones((L, d), pd),
-    }
+    blocks = {"ln1": scale0((L, d), pd), "ln2": scale0((L, d), pd)}
+    if La:      # [q | k | v] and, gated, [... | the gate]
+        blocks.update(
+            wqkv=norm(k[2], (La, d, h * (1 + cfg.attn_output_gate)
+                             + 2 * hkv), d),
+            wo=norm(k[3], (La, h, d), h))
     kk = jax.random.split(k[10], 8)     # PR 32's leaves; k[0..9] as before
     kn = jax.random.split(k[11], 8)     # PR 34's; [6] and [7]: init_params
+    kg = jax.random.split(kk[6], 8)     # the recurrent layers' and gates'
+    if cfg.qk_norm:
+        blocks.update(q_norm=scale0((La, cfg.head_dim), pd),
+                      k_norm=scale0((La, cfg.head_dim), pd))
+    if Lg:
+        Hv, nk = cfg.gdn_value_heads, cfg.gdn_key_heads * cfg.gdn_key_size
+        nv, taps = Hv * cfg.gdn_value_size, cfg.gdn_conv_taps
+        blocks.update(
+            gdn_wqkvz=norm(kg[0], (Lg, d, 2 * nk + 2 * nv), d),
+            gdn_wba=norm(kg[1], (Lg, d, 2 * Hv), d),
+            gdn_conv=norm(kg[2], (Lg, taps, 2 * nk + nv), taps),
+            gdn_a_log=jnp.log(jax.random.uniform(kg[3], (Lg, Hv), pd,
+                                                 0.0, 16.0)),
+            gdn_dt_bias=jnp.ones((Lg, Hv), pd),
+            gdn_norm=jnp.ones((Lg, cfg.gdn_value_size), pd),
+            gdn_wo=norm(kg[4], (Lg, nv, d), nv))
     if cfg.attention == "cca":
         k0, k1 = cfg.cca_taps
         dh, hc = cfg.head_dim, cfg.n_heads + cfg.kv_heads
@@ -437,6 +533,8 @@ def _init_blocks(k, cfg: TransformerConfig):
             fs = cfg.shared_experts * fe
             blocks["ws_in"] = norm(kn[4], (L, d, gated * fs), d)
             blocks["ws_out"] = norm(kn[5], (L, fs, d), fs)
+        if cfg.shared_expert_gate:
+            blocks["ws_gate"] = norm(kg[5], (L, d, 1), d)
     else:
         blocks["w_in"] = norm(k[7], (L, d, gated * f), d)
         blocks["w_out"] = norm(k[8], (L, f, d), f)
@@ -457,7 +555,7 @@ def init_params(key, cfg: TransformerConfig):
         "embed": norm(k[0], (cfg.vocab_size, d), d),  # scaled-init embedding
         "pos_embed": 0.02 * jax.random.normal(k[1], (cfg.max_seq, d), pd),
         "blocks": _init_blocks(k, rest),
-        "ln_f": jnp.ones((d,), pd),
+        "ln_f": (jnp.zeros if cfg.norm_zero_centred else jnp.ones)((d,), pd),
     }
     if cfg.layer_positions:         # rotary or no positions: no table
         del params["pos_embed"]
@@ -520,6 +618,15 @@ def _block_pspecs(cfg: TransformerConfig):
         del specs["wqkv"]
         small += ("wq_a", "q_norm", "wkv_a", "kv_norm")
         specs.update(wq_b=P(None, None, "tp"), wkv_b=P(None, None, "tp"))
+    if not cfg.layers_of("attention"):
+        del specs["wqkv"], specs["wo"]
+    if cfg.qk_norm:
+        small += ("q_norm", "k_norm")
+    if cfg.layers_of("gated_deltanet"):     # every chip holds them whole
+        small += ("gdn_wqkvz", "gdn_wba", "gdn_conv", "gdn_a_log",
+                  "gdn_dt_bias", "gdn_norm", "gdn_wo")
+    if cfg.shared_expert_gate:
+        small += ("ws_gate",)
     if cfg.scaled_residuals:
         small += ("res_scale", "res_bias")
     if cfg.router == "mlp":
@@ -844,6 +951,132 @@ def _rmsnorm(x, scale, eps=1e-6):
     xf = x.astype(jnp.float32)
     ms = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
     return (xf * lax.rsqrt(ms + eps) * scale.astype(jnp.float32)).astype(x.dtype)
+
+
+def _norm(cfg, x, w):
+    """One of the stack's RMS norms with its stored scale ``w``: times w, or
+    times 1 + w where the configuration's norms are zero-centred."""
+    if cfg.norm_zero_centred:
+        w = 1.0 + w.astype(jnp.float32)
+    return _rmsnorm(x, w, cfg.norm_eps)
+
+
+#: positions a chunk of :func:`_delta_rule` takes in a gated DeltaNet layer
+GDN_CHUNK = 64
+
+
+def _delta_rule(q, k, v, g, beta, chunk):
+    """The gated delta rule over a sequence, chunk by chunk (the WY form of
+    arXiv:2412.06464 §3): per value head ``S <- exp(g_t) S; S <- S + k_t
+    beta_t (v_t - S^T k_t)^T; o_t = S^T q_t`` from a zero state, q and k
+    L2-normed per head here (q then over sqrt(dk)). q, k (B, T, Hk, dk); v
+    (B, T, Hv, dv); g (log decay, <= 0) and beta (B, T, Hv); value head j
+    reads key head j // (Hv / Hk), which is never written out: q and k stay at
+    their Hk heads, the products with them broadcast over a key head's value
+    heads. q, k and v may come in the compute dtype and are taken to float32
+    after their layout changes; the norms, every product, the decays and the
+    state are float32. Returns o (B, T, Hv, dv).
+
+    Inside a chunk every product that needs no state is formed at once, the
+    decays only as differences of cumulative sums of g, masked where they
+    would grow before they are exponentiated; the inverse of the chunk's
+    unit-triangular (I + a) gives its corrections u (values) and w (keys) as
+    products; one scan over the chunks carries the (dk, dv) state. A
+    sequence that is no multiple of ``chunk`` is padded with beta 0 and g 0,
+    which write nothing and decay nothing."""
+    b, t, hv, dv = v.shape
+    hk = q.shape[2]
+    pad = (-t) % chunk
+    n = (t + pad) // chunk
+    f32 = jnp.float32
+
+    def chunks(x, heads):   # (B, T, *heads, ...) -> (N, B, *heads, C, ...)
+        x = jnp.pad(x, [(0, 0), (0, pad)] + [(0, 0)] * (x.ndim - 2))
+        x = x.reshape(b, n, chunk, *x.shape[2:])
+        # the layout change in the dtype it came in, then float32: the
+        # barrier keeps the compiler from widening before it moves the data
+        x = lax.optimization_barrier(jnp.moveaxis(x, (1, 2), (0, 2 + heads)))
+        return x.astype(f32)
+
+    def grouped(x):         # value heads as (key head G, its R value heads)
+        return x.reshape(b, t, hk, hv // hk, *x.shape[3:])
+
+    def unit(x):            # L2 norm per head
+        return x * lax.rsqrt(jnp.sum(jnp.square(x), -1, keepdims=True) + 1e-6)
+
+    q = unit(chunks(q, 1)) / math.sqrt(q.shape[-1])     # (N, B, G, C, dk)
+    k = unit(chunks(k, 1))
+    v = chunks(grouped(v), 2)                           # (N, B, G, R, C, dv)
+    g, beta = (chunks(grouped(a), 2) for a in (g, beta))   # (N, B, G, R, C)
+    cum = jnp.cumsum(g, axis=-1)
+    i = jnp.arange(chunk)
+    decay = jnp.exp(jnp.where(i[:, None] >= i[None, :],
+                              cum[..., :, None] - cum[..., None, :], -jnp.inf))
+    kk = jnp.einsum("nbgid,nbgjd->nbgij", k, k)[:, :, :, None]
+    a = jnp.where(i[:, None] > i[None, :], beta[..., None] * kk * decay, 0.0)
+    # (I + a)^-1 once, by a unit-triangular solve against the identity,
+    # then the corrected values u and keys w as products with it
+    inv = lax.linalg.triangular_solve(
+        a, jnp.broadcast_to(jnp.eye(chunk, dtype=f32), a.shape),
+        left_side=True, lower=True, unit_diagonal=True)
+    u = jnp.einsum("nbgrij,nbgrjv->nbgriv", inv, v * beta[..., None])
+    w = jnp.einsum("nbgrij,nbgjk->nbgrik", inv * (beta * jnp.exp(cum))[
+        ..., None, :], k)
+    last = cum[..., -1]                                 # (N, B, G, R)
+    k_end = k[:, :, :, None] * jnp.exp(last[..., None] - cum)[..., None]
+
+    def step(s, xs):
+        u_c, w_c, k_c, last_c = xs
+        new = u_c - jnp.einsum("bgrck,bgrkv->bgrcv", w_c, s)
+        s_next = s * jnp.exp(last_c)[..., None, None] \
+            + jnp.einsum("bgrck,bgrcv->bgrkv", k_c, new)
+        return s_next, (s, new)
+
+    s0 = jnp.zeros((b, hk, hv // hk, q.shape[-1], dv), f32)
+    _, (states, new) = lax.scan(step, s0, (u, w, k_end, last))
+    qk = jnp.einsum("nbgid,nbgjd->nbgij", q, k)[:, :, :, None] * decay
+    o = jnp.exp(cum)[..., None] * jnp.einsum("nbgck,nbgrkv->nbgrcv", q, states) \
+        + jnp.einsum("nbgrij,nbgrjv->nbgriv", qk, new)
+    o = jnp.moveaxis(o, (0, 4), (1, 2))                 # (B, N, C, G, R, dv)
+    return o.reshape(b, n * chunk, hv, dv)[:, :t]
+
+
+def _gated_deltanet(cfg, h, blk):
+    """A gated DeltaNet layer's mixer (``layer_mixers``) on the normed input
+    h (B, T, d): its output projection's result (B, T, d). The convolution,
+    beta, the decays, the rule's arithmetic and the gated norm in float32; q,
+    k and v cross into the rule's chunked layout in the compute dtype."""
+    b, t, _ = h.shape
+    Hk, Hv = cfg.gdn_key_heads, cfg.gdn_value_heads
+    dk, dv = cfg.gdn_key_size, cfg.gdn_value_size
+    nk, nv, f32 = Hk * dk, Hv * dv, jnp.float32
+    with jax.named_scope("attn_qkv"):
+        qkvz = jnp.einsum("btd,dz->btz", h, blk["gdn_wqkvz"].astype(h.dtype))
+        ba = jnp.einsum("btd,dz->btz", h, blk["gdn_wba"].astype(h.dtype),
+                        preferred_element_type=f32)
+        qkv, z = qkvz[..., :2 * nk + nv], qkvz[..., 2 * nk + nv:]
+    with jax.named_scope("attn_core"):
+        with jax.named_scope("gdn_conv"):
+            c, wc = qkv.astype(f32), blk["gdn_conv"].astype(f32)
+            taps = cfg.gdn_conv_taps
+            c = jax.nn.silu(sum(_shift(c, taps - 1 - a) * wc[a]
+                                for a in range(taps)))
+            c = c.astype(h.dtype)   # the rule's layout changes in bf16
+            q = c[..., :nk].reshape(b, t, Hk, dk)
+            k = c[..., nk:2 * nk].reshape(b, t, Hk, dk)
+            v = c[..., 2 * nk:].reshape(b, t, Hv, dv)
+            beta = jax.nn.sigmoid(ba[..., :Hv])
+            g = -jnp.exp(blk["gdn_a_log"].astype(f32)) * jax.nn.softplus(
+                ba[..., Hv:] + blk["gdn_dt_bias"].astype(f32))
+        with jax.named_scope("gdn_rule"):
+            o = _delta_rule(q, k, v, g, beta, GDN_CHUNK)
+        with jax.named_scope("gdn_norm"):
+            o = o * lax.rsqrt(jnp.mean(jnp.square(o), -1, keepdims=True)
+                              + cfg.norm_eps) * blk["gdn_norm"].astype(f32)
+            o = o * jax.nn.silu(z.astype(f32).reshape(b, t, Hv, dv))
+            o = o.reshape(b, t, nv).astype(h.dtype)
+    with jax.named_scope("attn_wo"):
+        return jnp.einsum("bth,hd->btd", o, blk["gdn_wo"].astype(h.dtype))
 
 
 def _dense_mlp(cfg, x, w_in, w_out):
@@ -1193,7 +1426,7 @@ def _resolve_head(params, cfg: TransformerConfig):
 
 def head_logits(params, cfg: TransformerConfig, x):
     """Final norm + LM head → f32 logits."""
-    x = _rmsnorm(x, params["ln_f"], cfg.norm_eps)
+    x = _norm(cfg, x, params["ln_f"])
     head = _resolve_head(params, cfg)
     logits = jnp.einsum("btd,dv->btv", x, head.astype(x.dtype))
     return _constrain(logits, "dp", "sp", "tp").astype(jnp.float32)
@@ -1272,8 +1505,7 @@ def _residual(cfg, x, y, blk, i):
 def _run_blocks(blocks, cfg: TransformerConfig, x, return_kv=False):
     """(x, auxes (L,), kvs, what the held experts' layers tell or None:
     ``{"load": (L, 4 or 5) float32, "moved": (L,) float32}`` of
-    :func:`_moe_share` and, under the mlp router, ``"choices"``: (L, 1, N)
-    int32). The scan runs
+    :func:`_moe_share` and ``"choices"``: (L, K, N) int32). The scan runs
     over PERIODS of the layer pattern (``cfg.layer_kinds``; a period of one
     layer for a uniform stack): inside a period the layers' kinds are
     static, and each layer is rematerialized on its own. The scan carries
@@ -1283,11 +1515,20 @@ def _run_blocks(blocks, cfg: TransformerConfig, x, return_kv=False):
     _check(cfg)
     hq, hkv = cfg.n_heads * cfg.head_dim, cfg.kv_heads * cfg.head_dim
 
-    def block(carry, blk, positions, window):
+    def heads_normed(a, w, n):
+        b, t = a.shape[0], a.shape[1]
+        return _norm(cfg, a.reshape(b, t, n, cfg.head_dim), w).reshape(a.shape)
+
+    def block(carry, blk, positions, window, mixer):
         x, state = carry
         with jax.named_scope("resid_norm"):
-            h = _rmsnorm(x, blk["ln1"], cfg.norm_eps)
-        if cfg.attention == "cca":
+            h = _norm(cfg, x, blk["ln1"])
+        gate = None
+        if mixer == "gated_deltanet":
+            if return_kv:
+                raise NotImplementedError(
+                    "a gated DeltaNet layer keeps a state, not keys and values")
+        elif cfg.attention == "cca":
             q, k, v = _cca_qkv(cfg, h, blk, positions)
         elif cfg.attention == "mla":
             q, k, v = _mla_qkv(cfg, h, blk, positions)
@@ -1296,17 +1537,30 @@ def _run_blocks(blocks, cfg: TransformerConfig, x, return_kv=False):
                 qkv = jnp.einsum("btd,dz->btz", h,
                                  blk["wqkv"].astype(h.dtype))
                 qkv = _constrain(qkv, "dp", "sp", "tp")
-                q, k, v = jnp.split(qkv, 3, axis=-1) if hkv == hq else \
-                    jnp.split(qkv, (hq, hq + hkv), axis=-1)
+                if cfg.attn_output_gate:
+                    q, k, v, gate = jnp.split(
+                        qkv, (hq, hq + hkv, hq + 2 * hkv), axis=-1)
+                else:
+                    q, k, v = jnp.split(qkv, 3, axis=-1) if hkv == hq else \
+                        jnp.split(qkv, (hq, hq + hkv), axis=-1)
+                if cfg.qk_norm:
+                    q = heads_normed(q, blk["q_norm"], cfg.n_heads)
+                    k = heads_normed(k, blk["k_norm"], cfg.kv_heads)
         routed = None
         if cfg.experts_held and cfg.router_input == "pre_attention":
             routed = _router_logits(h, blk["router"])
-        a = _attention(cfg, q, k, v, positions=positions, window=window)
-        with jax.named_scope("attn_wo"):
-            a = jnp.einsum("bth,hd->btd", a, blk["wo"].astype(h.dtype))
+        if mixer == "gated_deltanet":
+            a = _gated_deltanet(cfg, h, blk)
+        else:
+            a = _attention(cfg, q, k, v, positions=positions, window=window)
+            with jax.named_scope("attn_wo"):
+                if gate is not None:
+                    a = (a.astype(jnp.float32) * jax.nn.sigmoid(
+                        gate.astype(jnp.float32))).astype(a.dtype)
+                a = jnp.einsum("bth,hd->btd", a, blk["wo"].astype(h.dtype))
         with jax.named_scope("resid_norm"):
             x = _residual(cfg, x, a, blk, 0)
-            h2 = _rmsnorm(x, blk["ln2"], cfg.norm_eps)
+            h2 = _norm(cfg, x, blk["ln2"])
         told = None
         if cfg.experts_held:
             if cfg.router == "mlp":
@@ -1321,12 +1575,17 @@ def _run_blocks(blocks, cfg: TransformerConfig, x, return_kv=False):
                     chosen, weight = _route_top_k(cfg, routed)
             m, load, moved = _moe_share(cfg, h2, chosen, weight,
                                         blk["we_in"], blk["we_out"])
-            told = {"load": load, "moved": moved}
-            if cfg.router != "linear":  # an argmax: see make_train_step
-                told["choices"] = chosen
+            # a choice is an argmax: see make_train_step
+            told = {"load": load, "moved": moved, "choices": chosen}
             if cfg.shared_experts:      # every token's, once on every chip
                 with jax.named_scope("moe_shared"):
-                    m = m + _dense_mlp(cfg, h2, blk["ws_in"], blk["ws_out"])
+                    s = _dense_mlp(cfg, h2, blk["ws_in"], blk["ws_out"])
+                    if cfg.shared_expert_gate:
+                        s = (s.astype(jnp.float32) * jax.nn.sigmoid(jnp.einsum(
+                            "btd,do->bto", h2, blk["ws_gate"].astype(h2.dtype),
+                            preferred_element_type=jnp.float32))
+                             ).astype(s.dtype)
+                    m = m + s
             aux = 0.0
         elif cfg.n_experts:
             with jax.named_scope("moe_capacity"):
@@ -1344,26 +1603,38 @@ def _run_blocks(blocks, cfg: TransformerConfig, x, return_kv=False):
                   v.reshape(b, t, cfg.kv_heads, cfg.head_dim))
         return (x, state), (aux, kv, told)
 
-    def of_kind(positions, window):
-        fn = lambda c, blk: block(c, blk, positions, window)   # noqa: E731
+    def of_kind(positions, window, mixer):
+        fn = lambda c, blk: block(c, blk, positions, window, mixer)  # noqa: E731
         return fn if (return_kv or not cfg.remat) \
             else _remat_wrap(fn, cfg.remat_policy)
 
-    blk_fns = [of_kind(*kind) for kind in cfg.layer_kinds]
+    kinds = cfg.layer_kinds
+    blk_fns = [of_kind(*kind) for kind in kinds]
     period = len(blk_fns)
     if period == 1:     # a uniform stack keeps the scan it always had
         scan_body = blk_fns[0]
     else:
-        # (L, ...) → (L / period, period, ...): one scan step is one period
+        # (L, ...) → (L / period, period, ...): one scan step is one period;
+        # a leaf of one mixer's layers has as many rows a period as the
+        # period has such layers
+        periods = cfg.n_layers // period
         blocks = jax.tree_util.tree_map(
-            lambda w: w.reshape(w.shape[0] // period, period, *w.shape[1:]),
+            lambda w: w.reshape(periods, w.shape[0] // periods, *w.shape[1:]),
             blocks)
+
+        def layer(blks, i):
+            """Layer i of the period: its mixer's leaves at its place among
+            that mixer's layers, every layer's at i."""
+            mixer = kinds[i][2]
+            j = sum(kind[2] == mixer for kind in kinds[:i])
+            return {name: w[i if _mixer_of(name) is None else j]
+                    for name, w in blks.items()
+                    if _mixer_of(name) in (None, mixer)}
 
         def scan_body(carry, blks):
             ys = []
             for i, blk_fn in enumerate(blk_fns):
-                carry, y = blk_fn(carry,
-                                  jax.tree_util.tree_map(lambda w: w[i], blks))
+                carry, y = blk_fn(carry, layer(blks, i))
                 ys.append(y)
             return carry, jax.tree_util.tree_map(lambda *l: jnp.stack(l), *ys)
 
@@ -1540,7 +1811,7 @@ def _lm_loss_stats(params, cfg: TransformerConfig, ids, targets, *,
                     else (nll * weights).sum() / rows)
 
     with jax.named_scope("resid_norm"):
-        z = _rmsnorm(x, params["ln_f"], cfg.norm_eps)
+        z = _norm(cfg, x, params["ln_f"])
     main = mean_nll(z, targets)
     loss = main + aux_weight * aux
     if not cfg.predict_ahead:
@@ -1580,7 +1851,7 @@ def make_train_step(cfg: TransformerConfig, optimizer):
     device beside the loss: ``{"load": the per-layer expert-load stats (L, 4
     or 5) float32 of :func:`_moe_share`, "moved": the rows its backward's
     row movement crossed, (L,) float32}`` (``obs.moe.record_expert_load``
-    counts them) and, under the mlp and the sigmoid router, ``"choices"``:
+    counts them) and ``"choices"``:
     the experts every token took in every layer, (L, K, B·T) int32. A choice
     is an argmax, and a tie within the compute dtype's rounding falls the
     other way in another precision: whoever compares the step with another

@@ -119,7 +119,8 @@ def test_flash_fwd_bwd_compiles(one_chip, no_persistent_cache, shape,
     (16, 1024, 16, 16, 64, None),           # gpt2m-train-b16: heads paired
     (2, 8192, 7, 1, 128, 4096),             # smallthinker's window layers
     (1, 8192, 20, 20, 256, None),           # glm47flash-train-b1-t8192
-], ids=["gpt2_pairs", "moe_window", "glm_d256"])
+    (1, 8192, 16, 2, 256, None),            # qwen3next-train-b1-t8192
+], ids=["gpt2_pairs", "moe_window", "glm_d256", "qwen3next_d256_grouped"])
 def test_flash_ntc_compiles_with_no_layout_work(one_chip, no_persistent_cache,
                                                 b, t, h, hkv, d, window):
     """The kernels on the cells' (B, T, H·D) projections, at the race's
@@ -292,7 +293,7 @@ def test_kernel_program_is_the_same_from_any_call_site(one_chip, tmp_path,
 
 #: the expert cells' routed layers: tokens N, choices K, width d
 WAY_BACK_SHAPES = {"smallthinker": (16384, 6, 2560), "glm": (8192, 4, 2048),
-                   "zaya": (32768, 1, 2048)}
+                   "zaya": (32768, 1, 2048), "qwen3next": (8192, 10, 2048)}
 
 
 @pytest.mark.parametrize("cell", sorted(WAY_BACK_SHAPES))

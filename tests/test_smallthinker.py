@@ -86,7 +86,8 @@ def test_loss_and_every_gradient_leaf_match_the_reference(case):
     for name, w in g_want.items():
         gap = float(jnp.max(jnp.abs(g_got[name] - w)) / jnp.max(jnp.abs(w)))
         assert gap <= 1e-5, (name, gap)
-    assert sorted(stats) == ["load", "moved"]   # no choices from the linear router
+    assert sorted(stats) == ["choices", "load", "moved"]
+    assert stats["choices"].shape == (sz["layers"], sz["top_k"], ids.size)
     stats = np.asarray(stats["load"])
     assert stats.shape == (sz["layers"], 4)
     assert (stats[:, 0] == ids.size * sz["top_k"]).all()
@@ -104,7 +105,7 @@ def test_train_step_hands_back_the_expert_load_beside_the_loss():
     opt = optax.adamw(3e-4)
     out = jax.jit(tfm.make_train_step(cfg, opt))(params, opt.init(params),
                                                  ids, tgt)
-    assert len(out) == 4 and sorted(out[3]) == ["load", "moved"]
+    assert len(out) == 4 and sorted(out[3]) == ["choices", "load", "moved"]
     assert out[3]["load"].shape == (4, 4)
     assert out[3]["load"].dtype == jnp.float32
     dense = tfm.TransformerConfig(vocab_size=50, d_model=32, n_heads=4,
