@@ -37,6 +37,8 @@ from jax.extend import core as jex_core
 from jax.interpreters import mlir
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..kernels import gated_delta
+
 #: The training step's TOP-LEVEL ``jax.named_scope``s, name -> what it holds.
 #: Each is opened only where no other of them is open (at the call sites in
 #: ``block``, ``_lm_loss_stats``, ``embed`` and ``step``), so a device event
@@ -104,8 +106,9 @@ class TransformerConfig:
     #           small contraction results, not the big batched ones)
     # "save_attn" — keep what attention produced (checkpoint_name
     #           "attn_out"; on the flash path also the kernel's
-    #           log-sum-exp, "attn_lse"), recompute the rest: remat-full's
-    #           HBM saving with attention run once a layer
+    #           log-sum-exp, "attn_lse"; of the gated delta rule its
+    #           chunk-start states, "gdn_states"), recompute the rest:
+    #           remat-full's HBM saving with attention run once a layer
     remat_policy: str = "full"
     use_ring_attention: bool = False
     # True = always pallas flash kernel (TPU single-chip); False = XLA fused
@@ -933,12 +936,15 @@ def _remat_wrap(fn, policy: str):
         # reads), and the (B·H, 1, T) f32 log-sum-exp, "attn_lse". With
         # both saved the backward scan runs the two backward kernels only:
         # three Pallas calls a layer and step where "full" runs four (the
-        # forward twice); q, k and v are recomputed
+        # forward twice). The gated delta rule's kernel names its output
+        # "attn_out" too and its chunk-start states "gdn_states"
+        # (kernels/gated_delta.py::_rule_fwd): its backward kernel alone
+        # runs in the backward pass. q, k and v are recomputed
         # from the block's input either way. B*T*D bf16 + B*H*T f32 a
         # layer (32 + 1 MiB at b16 T=1024 d1024).
         "save_attn":
-            jax.checkpoint_policies.save_only_these_names("attn_out",
-                                                          "attn_lse"),
+            jax.checkpoint_policies.save_only_these_names(
+                "attn_out", "attn_lse", "gdn_states"),
     }
     if policy not in policies:
         raise ValueError(f"Unknown remat_policy {policy!r}; "
@@ -961,91 +967,17 @@ def _norm(cfg, x, w):
     return _rmsnorm(x, w, cfg.norm_eps)
 
 
-#: positions a chunk of :func:`_delta_rule` takes in a gated DeltaNet layer
-GDN_CHUNK = 64
-
-
-def _delta_rule(q, k, v, g, beta, chunk):
-    """The gated delta rule over a sequence, chunk by chunk (the WY form of
-    arXiv:2412.06464 §3): per value head ``S <- exp(g_t) S; S <- S + k_t
-    beta_t (v_t - S^T k_t)^T; o_t = S^T q_t`` from a zero state, q and k
-    L2-normed per head here (q then over sqrt(dk)). q, k (B, T, Hk, dk); v
-    (B, T, Hv, dv); g (log decay, <= 0) and beta (B, T, Hv); value head j
-    reads key head j // (Hv / Hk), which is never written out: q and k stay at
-    their Hk heads, the products with them broadcast over a key head's value
-    heads. q, k and v may come in the compute dtype and are taken to float32
-    after their layout changes; the norms, every product, the decays and the
-    state are float32. Returns o (B, T, Hv, dv).
-
-    Inside a chunk every product that needs no state is formed at once, the
-    decays only as differences of cumulative sums of g, masked where they
-    would grow before they are exponentiated; the inverse of the chunk's
-    unit-triangular (I + a) gives its corrections u (values) and w (keys) as
-    products; one scan over the chunks carries the (dk, dv) state. A
-    sequence that is no multiple of ``chunk`` is padded with beta 0 and g 0,
-    which write nothing and decay nothing."""
-    b, t, hv, dv = v.shape
-    hk = q.shape[2]
-    pad = (-t) % chunk
-    n = (t + pad) // chunk
-    f32 = jnp.float32
-
-    def chunks(x, heads):   # (B, T, *heads, ...) -> (N, B, *heads, C, ...)
-        x = jnp.pad(x, [(0, 0), (0, pad)] + [(0, 0)] * (x.ndim - 2))
-        x = x.reshape(b, n, chunk, *x.shape[2:])
-        # the layout change in the dtype it came in, then float32: the
-        # barrier keeps the compiler from widening before it moves the data
-        x = lax.optimization_barrier(jnp.moveaxis(x, (1, 2), (0, 2 + heads)))
-        return x.astype(f32)
-
-    def grouped(x):         # value heads as (key head G, its R value heads)
-        return x.reshape(b, t, hk, hv // hk, *x.shape[3:])
-
-    def unit(x):            # L2 norm per head
-        return x * lax.rsqrt(jnp.sum(jnp.square(x), -1, keepdims=True) + 1e-6)
-
-    q = unit(chunks(q, 1)) / math.sqrt(q.shape[-1])     # (N, B, G, C, dk)
-    k = unit(chunks(k, 1))
-    v = chunks(grouped(v), 2)                           # (N, B, G, R, C, dv)
-    g, beta = (chunks(grouped(a), 2) for a in (g, beta))   # (N, B, G, R, C)
-    cum = jnp.cumsum(g, axis=-1)
-    i = jnp.arange(chunk)
-    decay = jnp.exp(jnp.where(i[:, None] >= i[None, :],
-                              cum[..., :, None] - cum[..., None, :], -jnp.inf))
-    kk = jnp.einsum("nbgid,nbgjd->nbgij", k, k)[:, :, :, None]
-    a = jnp.where(i[:, None] > i[None, :], beta[..., None] * kk * decay, 0.0)
-    # (I + a)^-1 once, by a unit-triangular solve against the identity,
-    # then the corrected values u and keys w as products with it
-    inv = lax.linalg.triangular_solve(
-        a, jnp.broadcast_to(jnp.eye(chunk, dtype=f32), a.shape),
-        left_side=True, lower=True, unit_diagonal=True)
-    u = jnp.einsum("nbgrij,nbgrjv->nbgriv", inv, v * beta[..., None])
-    w = jnp.einsum("nbgrij,nbgjk->nbgrik", inv * (beta * jnp.exp(cum))[
-        ..., None, :], k)
-    last = cum[..., -1]                                 # (N, B, G, R)
-    k_end = k[:, :, :, None] * jnp.exp(last[..., None] - cum)[..., None]
-
-    def step(s, xs):
-        u_c, w_c, k_c, last_c = xs
-        new = u_c - jnp.einsum("bgrck,bgrkv->bgrcv", w_c, s)
-        s_next = s * jnp.exp(last_c)[..., None, None] \
-            + jnp.einsum("bgrck,bgrcv->bgrkv", k_c, new)
-        return s_next, (s, new)
-
-    s0 = jnp.zeros((b, hk, hv // hk, q.shape[-1], dv), f32)
-    _, (states, new) = lax.scan(step, s0, (u, w, k_end, last))
-    qk = jnp.einsum("nbgid,nbgjd->nbgij", q, k)[:, :, :, None] * decay
-    o = jnp.exp(cum)[..., None] * jnp.einsum("nbgck,nbgrkv->nbgrcv", q, states) \
-        + jnp.einsum("nbgrij,nbgrjv->nbgriv", qk, new)
-    o = jnp.moveaxis(o, (0, 4), (1, 2))                 # (B, N, C, G, R, dv)
-    return o.reshape(b, n * chunk, hv, dv)[:, :t]
+#: the scopes round the gated delta rule's call in :func:`_gated_deltanet`,
+#: which its kernels' events carry (``gated_delta_rule``'s ``scopes``)
+_GDN_RULE_SCOPES = ("attn_core", "gdn_rule")
 
 
 def _gated_deltanet(cfg, h, blk):
     """A gated DeltaNet layer's mixer (``layer_mixers``) on the normed input
     h (B, T, d): its output projection's result (B, T, d). The convolution,
-    beta, the decays, the rule's arithmetic and the gated norm in float32; q,
-    k and v cross into the rule's chunked layout in the compute dtype."""
+    beta, the decays and the gated norm in float32; the delta rule is
+    ``kernels.gated_delta``'s, which reads q, k and v in the compute dtype
+    from the convolution's (B, T, 2 Hk dk + Hv dv) output."""
     b, t, _ = h.shape
     Hk, Hv = cfg.gdn_key_heads, cfg.gdn_value_heads
     dk, dv = cfg.gdn_key_size, cfg.gdn_value_size
@@ -1061,15 +993,14 @@ def _gated_deltanet(cfg, h, blk):
             taps = cfg.gdn_conv_taps
             c = jax.nn.silu(sum(_shift(c, taps - 1 - a) * wc[a]
                                 for a in range(taps)))
-            c = c.astype(h.dtype)   # the rule's layout changes in bf16
-            q = c[..., :nk].reshape(b, t, Hk, dk)
-            k = c[..., nk:2 * nk].reshape(b, t, Hk, dk)
-            v = c[..., 2 * nk:].reshape(b, t, Hv, dv)
+            c = c.astype(h.dtype)   # [q | k | v], read so by the rule
             beta = jax.nn.sigmoid(ba[..., :Hv])
             g = -jnp.exp(blk["gdn_a_log"].astype(f32)) * jax.nn.softplus(
                 ba[..., Hv:] + blk["gdn_dt_bias"].astype(f32))
         with jax.named_scope("gdn_rule"):
-            o = _delta_rule(q, k, v, g, beta, GDN_CHUNK)
+            o = gated_delta.gated_delta_rule(c, g, beta, Hk, dk, dv,
+                                             scopes=_GDN_RULE_SCOPES)
+            o = o.reshape(b, t, Hv, dv)
         with jax.named_scope("gdn_norm"):
             o = o * lax.rsqrt(jnp.mean(jnp.square(o), -1, keepdims=True)
                               + cfg.norm_eps) * blk["gdn_norm"].astype(f32)

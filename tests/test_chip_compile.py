@@ -366,6 +366,69 @@ def test_row_mover_is_lowered_once_however_many_layers_call_it():
     assert len(bodies) == 1 and len(calls) == 2 and set(calls) == set(bodies)
 
 
+#: qwen3next-train-b1-t8192's gated DeltaNet: B, T, key heads, value heads,
+#: head size
+GDN_SHAPE = (1, 8192, 16, 32, 128)
+
+
+def _gdn_operands(one_chip, b, t, hk, hv, d):
+    return (_sds(one_chip, (b, t, 2 * hk * d + hv * d), jnp.bfloat16),
+            _sds(one_chip, (b, t, hv), jnp.float32),
+            _sds(one_chip, (b, t, hv), jnp.float32))
+
+
+def test_gated_delta_kernels_compile_at_the_qwen3next_cell_shape(
+        one_chip, no_persistent_cache):
+    """``kernels.gated_delta`` forward and backward at the cell's shapes:
+    two custom calls, every one under ``attn_core/gdn_rule`` (the scope
+    ``gdn_roofline_pct.qwen3next`` and ``gdn_time_share_pct.qwen3next``
+    read), and nothing of a chunk's size in HBM: the temporaries are the
+    chunk-start states and little else."""
+    from deeplearning4j_tpu.kernels import gated_delta
+    from deeplearning4j_tpu.zoo import transformer as tfm
+    b, t, hk, hv, d = GDN_SHAPE
+
+    def loss(x, g, beta, w):
+        return jnp.sum(gated_delta.gated_delta_rule(
+            x, g, beta, hk, d, d, scopes=tfm._GDN_RULE_SCOPES,
+            interpret=False) * w)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        *_gdn_operands(one_chip, *GDN_SHAPE),
+        _sds(one_chip, (b, t, hv * d), jnp.float32)).compile()
+    calls = [ln for ln in compiled.as_text().splitlines()
+             if "tpu_custom_call" in ln and " = " in ln]
+    assert sorted(re.match(r"\s*%(\w+?)(\.\d+)? =", ln).group(1)
+                  for ln in calls) == ["gdn_bwd", "gdn_fwd"]
+    for ln in calls:
+        assert re.search(r'op_name="[^"]*attn_core/gdn_rule/', ln), ln
+    states = t // 64 * hv * d * d * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.1 * states
+
+
+def test_gated_delta_kernels_are_lowered_once_for_every_layer(one_chip):
+    """The regression that refused the rule's first kernel (set-up that grew
+    with its call sites), held without a chip: three layers under
+    ``jax.checkpoint`` call the forward six times (each layer's forward and
+    its recomputation) and the backward three times, and the module holds
+    each kernel's body ONCE."""
+    from deeplearning4j_tpu.kernels import gated_delta
+    b, t, hk, hv, d = 1, 256, 2, 4, 128
+
+    def loss(x, g, beta):
+        rule = jax.checkpoint(lambda x: gated_delta.gated_delta_rule(
+            x, g, beta, hk, d, d, interpret=False))
+        return sum(jnp.sum(jnp.square(rule(x * (i + 1)))) for i in range(3))
+
+    text = jax.jit(jax.grad(loss)).lower(
+        *_gdn_operands(one_chip, b, t, hk, hv, d)).as_text()
+    for name, n_calls in (("gdn_fwd", 6), ("gdn_bwd", 3)):
+        bodies = re.findall(rf"func\.func private @({name}[^(]*)\(", text)
+        calls = re.findall(rf"call @({name}[^(]*)\(", text)
+        assert len(bodies) == 1 and len(calls) == n_calls, (name, bodies)
+        assert text.count(f'name = "{name}"') <= 1   # one Mosaic module
+
+
 @pytest.mark.parametrize("batch", [1, 128], ids=["b1", "b128"])
 @pytest.mark.parametrize("side,channels", RESNET_BN_ACT,
                          ids=[f"{s}x{s}x{c}" for s, c in RESNET_BN_ACT])
